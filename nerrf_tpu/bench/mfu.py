@@ -28,8 +28,6 @@ def flops_per_step(jit_fn, *args, **kwargs) -> Optional[float]:
     try:
         compiled = jit_fn.lower(*args, **kwargs).compile()
         cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):  # older jax returns [dict]
-            cost = cost[0]
         f = float(cost.get("flops", 0.0))
         return f if f > 0 else None
     except Exception:

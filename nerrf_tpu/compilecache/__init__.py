@@ -5,7 +5,7 @@ XLA executables (`cache.CompileCache`), an export pipeline that ships the
 serve ladder's executables as a checkpoint sidecar at publish time
 (`aot.export_executables`), and a per-signature step resolver
 (`StepCache`) so repeat runs on an unchanged config start stepping
-without paying the 130 s flagship compile again.
+without paying the flagship compile again.
 """
 
 from nerrf_tpu.compilecache.aot import (

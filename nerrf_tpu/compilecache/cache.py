@@ -1,10 +1,11 @@
 """Persistent, content-addressed compilation cache for AOT executables.
 
-BENCH_r04 measured 130 s to compile ``train_step`` and ~57 s for
-``stream_step``; the serve path gates readiness on compiling the whole
-bucket ladder at boot.  Compile cost is the central systems problem for
-this workload class (TpuGraphs, arXiv:2308.13490), and the fix is the
-graph-reuse discipline PyGraph applies to CUDA graphs (arXiv:2503.19779):
+The flagship ``train_step`` and ``stream_step`` each take the better part
+of a minute or more to compile, and the serve path gates readiness on
+compiling the whole bucket ladder at boot.  Compile cost is the central
+systems problem for this workload class (TpuGraphs, arXiv:2308.13490), and
+the fix is the graph-reuse discipline PyGraph applies to CUDA graphs
+(arXiv:2503.19779):
 key every lowered program by WHAT it computes, persist the compiled
 artifact, and never compile the same program twice on the same platform.
 
@@ -14,10 +15,11 @@ artifact, and never compile the same program twice on the same platform.
   * **content-addressed** — an entry's directory name IS the canonical
     fingerprint of (program name, argument avals + pytree layout, caller
     ``extra`` material such as model architecture and donation spec,
-    jax/jaxlib/libtpu versions, backend platform + device kind + device
-    count, host ISA fingerprint on CPU).  Any drift along any axis is a
-    different fingerprint, so a stale executable can never be reused — the
-    worst a corrupt cache can do is cost one fresh compile;
+    jax/jaxlib/libtpu versions, backend platform + device kind, the ids of
+    the devices the program runs on, host ISA fingerprint on CPU).  Any
+    drift along any axis is a different fingerprint, so a stale executable
+    can never be reused — the worst a corrupt cache can do is cost one
+    fresh compile;
   * **atomic** — entries are written to a tmp directory and renamed into
     place (rename(2) is atomic on one filesystem), so concurrent
     processes sharing a cache volume see whole entries or nothing;
@@ -52,7 +54,7 @@ TREES = "trees.pkl"
 META = "meta.json"
 
 # compile-seconds histogram ladder: sub-second deserialize hits up to the
-# measured 130 s flagship compile
+# flagship compile (minutes on a CPU, tens of seconds on a chip)
 COMPILE_SECONDS_BUCKETS = (0.05, 0.25, 1.0, 5.0, 15.0, 60.0, 180.0, 600.0)
 
 # default disk bound for a cache root (override per instance / `nerrf
@@ -115,10 +117,27 @@ def _host_isa_fingerprint() -> str:
         f"{platform.machine()}|{model}|{flags}".encode()).hexdigest()[:12]
 
 
+def call_devices(args: tuple, kwargs: dict) -> list:
+    """Ids of the devices a call with these arguments runs on: those its
+    committed array arguments live on (a mesh-sharded batch, a replica's
+    pinned params), else the default device.  An executable is compiled
+    FOR a device assignment, so this is key material — on a four-chip
+    host the program for chip 0 must not answer a lookup from chip 2."""
+    import jax
+
+    for leaf in jax.tree_util.tree_leaves((args, kwargs)):
+        if isinstance(leaf, jax.Array) and leaf.committed:
+            return sorted(d.id for d in leaf.sharding.device_set)
+    return [(jax.config.jax_default_device or jax.devices()[0]).id]
+
+
 def environment_key() -> dict:
     """The environment axes that invalidate an executable: jax/jaxlib (and
-    libtpu when present) versions, backend platform, device kind + count,
-    and — on CPU, where the artifact is ISA-specific — the host ISA."""
+    libtpu when present) versions, backend platform, device kind, the ids
+    of the devices the program was compiled for (here the default device;
+    `load_or_compile` puts each call's own — `call_devices` — in its
+    place), and — on CPU, where the artifact is ISA-specific — the host
+    ISA."""
     import jax
     import jaxlib
 
@@ -128,7 +147,7 @@ def environment_key() -> dict:
         "jaxlib": jaxlib.__version__,
         "platform": dev.platform,
         "device_kind": dev.device_kind,
-        "device_count": jax.device_count(),
+        "devices": [dev.id],
     }
     try:  # pragma: no cover — only present on real TPU hosts
         import libtpu  # type: ignore
@@ -158,12 +177,15 @@ def compute_fingerprint(program: str, avals: dict, extra: Optional[dict],
 
 
 def default_cache_dir() -> str:
-    """The standard on-host cache root (the serve manifest mounts a volume
-    here): $NERRF_AOT_CACHE_DIR, else ~/.cache/nerrf_tpu/aot.  No host
-    subdirectory — the key material carries the ISA axis instead, so one
-    volume can serve heterogeneous hosts without ever cross-loading."""
-    return os.environ.get("NERRF_AOT_CACHE_DIR") or os.path.join(
-        os.path.expanduser("~"), ".cache", "nerrf_tpu", "aot")
+    """The standard cache root: ``aot/`` under `utils.compile_cache_dir`
+    ($JAX_COMPILATION_CACHE_DIR when set — the serve manifest mounts a
+    volume there — else the fixed in-checkout directory), beside JAX's own
+    persistent cache.  No host subdirectory — the key material carries the
+    ISA axis instead, so one volume can serve heterogeneous hosts without
+    ever cross-loading."""
+    from nerrf_tpu.utils import compile_cache_dir
+
+    return os.path.join(compile_cache_dir(), "aot")
 
 
 class CompileCache:
@@ -257,6 +279,7 @@ class CompileCache:
         if entry is None:
             return None
         try:
+            import jax
             from jax.experimental import serialize_executable as se
 
             from nerrf_tpu import chaos
@@ -268,8 +291,16 @@ class CompileCache:
             payload = chaos.mangle(
                 "compilecache.corrupt_payload",
                 (entry / PAYLOAD).read_bytes(), key=fingerprint)
-            in_tree, out_tree = pickle.loads((entry / TREES).read_bytes())
-            compiled = se.deserialize_and_load(payload, in_tree, out_tree)
+            in_tree, out_tree, device_ids = pickle.loads(
+                (entry / TREES).read_bytes())
+            # the entry's own device assignment, in order: left to its
+            # default, jax 0.9 reloads every executable over ALL devices of
+            # the backend, and a single-device program then fails at call
+            # time on any host with more than one
+            by_id = {d.id: d for d in jax.devices()}
+            compiled = se.deserialize_and_load(
+                payload, in_tree, out_tree,
+                execution_devices=[by_id[i] for i in device_ids])
         except Exception as e:  # noqa: BLE001 — fail-open by contract
             self._log(f"compile cache: entry {fingerprint} unreadable "
                       f"({type(e).__name__}: {e}); compiling live")
@@ -339,13 +370,15 @@ class CompileCache:
             from jax.experimental import serialize_executable as se
 
             payload, in_tree, out_tree = se.serialize(compiled)
-            trees = pickle.dumps((in_tree, out_tree))
+            device_ids = [d.id for d in
+                          compiled.runtime_executable().local_devices()]
+            trees = pickle.dumps((in_tree, out_tree, device_ids))
         except Exception as e:  # noqa: BLE001 — fail-open by contract
             self._log(f"compile cache: cannot serialize {program} "
                       f"({type(e).__name__}: {e}); running uncached")
             return "unserializable"
         meta = {
-            "schema_version": 1,
+            "schema_version": 2,
             "program": program,
             "fingerprint": fingerprint,
             "key": material,
@@ -400,8 +433,9 @@ class CompileCache:
         kwargs = kwargs or {}
         try:
             avals = aval_signature(args, kwargs)
+            env = {**self.env(), "devices": call_devices(args, kwargs)}
             fp, material = compute_fingerprint(program, avals, extra,
-                                               env=self.env())
+                                               env=env)
         except Exception as e:  # noqa: BLE001 — fail-open by contract
             info = CompileInfo(program=program, fingerprint="",
                                source="live", seconds=0.0,
@@ -450,33 +484,28 @@ class CompileCache:
         result, so it is paid at most once per program.
 
         Suspension has to go through the ``jax_enable_compilation_cache``
-        flag AND ``compilation_cache.reset_cache()``: jax memoizes its
-        is-the-cache-used verdict process-wide on first compile, so just
-        clearing ``jax_compilation_cache_dir`` is a silent no-op once
-        anything has compiled (measured live: the e2e pre-flight caught
-        poisoned payloads written exactly that way)."""
+        flag AND ``compilation_cache.reset_cache()``: the installed jax
+        (0.9) still memoizes its is-the-cache-used verdict process-wide on
+        first compile (``compilation_cache.is_cache_used``), so flipping
+        the flag alone is a silent no-op once anything has compiled
+        (measured live: the e2e pre-flight caught poisoned payloads
+        written exactly that way)."""
         import jax
+        from jax.experimental.compilation_cache.compilation_cache import (
+            reset_cache,
+        )
 
-        prev_dir = getattr(jax.config, "jax_compilation_cache_dir", None)
-        prev_on = getattr(jax.config, "jax_enable_compilation_cache", True)
-        reset = lambda: None  # noqa: E731 — default when cc is private/absent
-        if prev_dir and prev_on:
-            try:
-                from jax._src import compilation_cache as _cc
-
-                reset = _cc.reset_cache
-            except Exception:  # noqa: BLE001 — older/newer jax layouts
-                pass
+        suspend = bool(jax.config.jax_compilation_cache_dir
+                       and jax.config.jax_enable_compilation_cache)
+        if suspend:
             jax.config.update("jax_enable_compilation_cache", False)
-            reset()  # drop the memoized verdict so the flag is re-read
+            reset_cache()  # drop the memoized verdict so the flag is re-read
         try:
             return jit_fn.lower(*args, **kwargs).compile()
         finally:
-            if prev_dir and prev_on:
-                # restore the OPERATOR'S value, never a hardcoded True —
-                # and only when we flipped it (prev_on)
-                jax.config.update("jax_enable_compilation_cache", prev_on)
-                reset()  # re-arm jax's cache for everyone else
+            if suspend:
+                jax.config.update("jax_enable_compilation_cache", True)
+                reset_cache()  # re-arm jax's cache for everyone else
 
     # -- maintenance (the `nerrf cache` surface) ------------------------------
 

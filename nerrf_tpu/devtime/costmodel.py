@@ -84,8 +84,6 @@ def xla_cost(fn, *args) -> tuple:
     try:
         compiled = fn.lower(*args).compile()
         cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):  # older jax returns [dict]
-            cost = cost[0]
         flops = float(cost.get("flops", 0.0)) or None
         byts = float(cost.get("bytes accessed", 0.0)) or None
         return flops, byts

@@ -1,17 +1,14 @@
 """Per-platform chip peaks: the one table every chip-relative gauge reads.
 
-`bench/mfu.py` carried a substring-ordered peak list whose correctness
-depended on tuple order ("v5 lite" had to sit above "v5" or every v5e
-read as a v5p-class part) — fine for one offline consumer, fragile the
-moment live gauges start dividing by it.  This module is the proper
-per-platform table: **exact device_kind match first** (the strings the
-TPU runtime actually publishes), then a longest-substring fallback for
-kinds the runtime decorates (e.g. a topology suffix), and bandwidth next
-to compute so the roofline gauge has a ridge point.
+Keyed by the **exact** ``device_kind`` string the TPU runtime publishes,
+with bandwidth next to compute so the roofline gauge has a ridge point, and
+the source of every row beside it.
 
-Null-not-fake: anything unrecognized — CPU, GPU, a future TPU — resolves
-to ``None``, never a guessed peak.  A fabricated MFU is worse than no
-MFU (the 195%-MFU lesson in `bench/mfu.py`).
+Null-not-fake: anything not in the table — CPU, GPU, a TPU this repository
+has not run on — resolves to ``None``, never a peak guessed from a similar
+name.  A fabricated MFU is worse than no MFU (the 195%-MFU lesson in
+`bench/mfu.py`); on the chip path an unknown kind is an error
+(`chip_smoke.py`), not a default.
 """
 
 from __future__ import annotations
@@ -35,41 +32,20 @@ class ChipPeaks:
         return self.tflops_bf16 * 1e12 / (self.hbm_gbps * 1e9)
 
 
-# Exact device_kind strings as the TPU runtime publishes them (lowercased
-# for lookup).  Sources: public Google Cloud TPU spec sheets.
+# device_kind as the TPU runtime publishes it (lowercased for lookup) →
+# peaks, one row per part this repository has run on.  A run on another
+# part adds its row, with its source, first.
 CHIP_TABLE = {
-    "tpu v2": ChipPeaks("tpu v2", 45.0, 700.0),
-    "tpu v3": ChipPeaks("tpu v3", 123.0, 900.0),
-    "tpu v4": ChipPeaks("tpu v4", 275.0, 1228.0),
-    "tpu v4i": ChipPeaks("tpu v4i", 138.0, 614.0),
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16 and 16 GB of
+    # HBM at 819 GB/s per chip; the runtime reports the part as
+    # "TPU v5 lite" (chip_smoke.py prints it).
     "tpu v5 lite": ChipPeaks("tpu v5 lite", 197.0, 819.0),
-    "tpu v5e": ChipPeaks("tpu v5 lite", 197.0, 819.0),
-    "tpu v5": ChipPeaks("tpu v5", 197.0, 819.0),
-    "tpu v5p": ChipPeaks("tpu v5p", 459.0, 2765.0),
-    "tpu v6 lite": ChipPeaks("tpu v6 lite", 918.0, 1640.0),
-    "tpu v6e": ChipPeaks("tpu v6 lite", 918.0, 1640.0),
 }
 
 
 def resolve_kind(device_kind: str) -> Optional[ChipPeaks]:
-    """Exact-match-first resolution of a device_kind string.
-
-    1. exact match on the lowercased kind ("TPU v5 lite" → v5e row);
-    2. else the LONGEST table key contained in the kind — so a decorated
-       kind like "TPU v5 lite podslice" still lands on "tpu v5 lite",
-       never the shorter "tpu v5", regardless of dict order.
-    """
-    kind = (device_kind or "").strip().lower()
-    if not kind:
-        return None
-    hit = CHIP_TABLE.get(kind)
-    if hit is not None:
-        return hit
-    best = None
-    for key, peaks in CHIP_TABLE.items():
-        if key in kind and (best is None or len(key) > len(best[0])):
-            best = (key, peaks)
-    return best[1] if best else None
+    """The table row whose key IS the lowercased kind, else None."""
+    return CHIP_TABLE.get((device_kind or "").strip().lower())
 
 
 def chip_peaks(device) -> Optional[ChipPeaks]:
@@ -85,7 +61,7 @@ def chip_peaks(device) -> Optional[ChipPeaks]:
 
 
 def chip_peak_tflops(device) -> Optional[float]:
-    """bf16 peak for a jax device, or None when unknown (the exact-match
-    successor of bench/mfu.py's substring walk — mfu.py delegates here)."""
+    """bf16 peak for a jax device, or None when unknown (bench/mfu.py
+    delegates here)."""
     peaks = chip_peaks(device)
     return peaks.tflops_bf16 if peaks else None

@@ -68,10 +68,15 @@ class _Columns(ctypes.Structure):
 
 
 def load_native_lib(lib_filename: str, build: bool = True) -> Optional[ctypes.CDLL]:
-    """Load native/build/<lib_filename>, building it on demand (best-effort;
-    callers fall back to their Python engines on None)."""
+    """Load native/build/<lib_filename>, bringing it up to date first
+    (best-effort; callers fall back to their Python engines on None).
+
+    `make` decides whether to build: it is incremental, so an up-to-date
+    library costs one no-op subprocess, and one older than its sources —
+    native/build/ is git-ignored and outlives checkouts — is rebuilt
+    instead of being loaded forever."""
     lib_path = os.path.abspath(os.path.join(_NATIVE_DIR, "build", lib_filename))
-    if not os.path.exists(lib_path) and build:
+    if build:
         try:
             subprocess.run(
                 ["make", "-s", f"build/{lib_filename}"],

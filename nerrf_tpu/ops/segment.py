@@ -2,8 +2,8 @@
 
 The graph builder emits edges sorted by destination node, so aggregation is a
 segment reduction over a monotone id vector — the memory-friendly layout for
-TPU.  This module is the single switchboard for those primitives.  The
-fallback path is XLA's fused scatter-add (`jax.ops.segment_sum`);
+TPU.  This module is the single switchboard for those primitives.  Off TPU
+they are XLA's scatter-add and gather (`jax.ops.segment_sum`, `jnp.take`);
 `nerrf_tpu.ops.pallas_segment` provides hand-tiled Pallas kernels for the hot
 TPU path and registers itself here.  ``sorted_ids=True`` is a **contract**
 (ids really are nondecreasing — it routes to a banded kernel that drops
@@ -17,7 +17,8 @@ sparse aggregation be written as Pallas kernels.)
 
 from __future__ import annotations
 
-import os
+import contextlib
+import threading
 from typing import Callable, Optional
 
 import jax
@@ -29,6 +30,30 @@ _SEGMENT_SUM_SORTED_IMPL: Optional[Callable] = None
 _GATHER_IMPL: Optional[Callable] = None
 _SAGE_FUSED_IMPL: Optional[Callable] = None
 _AUTO_TRIED = False
+# per-thread: a sharded trace in one thread must not change what a serve
+# program traced concurrently in another is made of
+_TRACING = threading.local()
+
+
+@contextlib.contextmanager
+def xla_only():
+    """Serve every op from its XLA composition while the enclosed code
+    traces.  GSPMD cannot partition a Mosaic kernel ("Mosaic kernels cannot
+    be automatically partitioned. Please wrap the call in a shard_map"), so
+    a program jitted over more than one device traces its ops under this
+    context (`parallel.train.mesh_ops`); :func:`active_impls` inside it
+    reports ``xla``, which is what the program's `kernel_path` and
+    compile-cache key then say."""
+    prev = getattr(_TRACING, "xla_only", False)
+    _TRACING.xla_only = True
+    try:
+        yield
+    finally:
+        _TRACING.xla_only = prev
+
+
+def _impl(fn: Optional[Callable]) -> Optional[Callable]:
+    return None if getattr(_TRACING, "xla_only", False) else fn
 
 
 def use_pallas(sum_fn: Optional[Callable], gather_fn: Optional[Callable] = None,
@@ -43,7 +68,7 @@ def use_pallas(sum_fn: Optional[Callable], gather_fn: Optional[Callable] = None,
     bidirectional aggregation.
 
     An explicit call — including clearing — is a deliberate choice, so it also
-    disables the one-shot TPU auto-probe in :func:`_maybe_auto_register`.
+    disables the one-shot TPU registration in :func:`_maybe_auto_register`.
     """
     global _SEGMENT_SUM_IMPL, _SEGMENT_SUM_SORTED_IMPL, _GATHER_IMPL, \
         _SAGE_FUSED_IMPL, _AUTO_TRIED
@@ -55,41 +80,31 @@ def use_pallas(sum_fn: Optional[Callable], gather_fn: Optional[Callable] = None,
 
 
 def active_impls() -> dict:
-    """Which implementation serves each op on this backend, after the
-    auto-probe — benchmark artifacts record this (`kernel_path`) so a chip
-    number can be attributed to the kernel that actually ran (r2 verdict
-    weak #5: the probe's silent dense fallback meant nobody knew)."""
+    """Which implementation serves each op on this backend (and under
+    :func:`xla_only`, if active) — benchmark artifacts and the training log
+    record this (`kernel_path`) so a chip number can be attributed to the
+    kernel that actually ran."""
     _maybe_auto_register()
+    dense, banded = _impl(_SEGMENT_SUM_IMPL), _impl(_SEGMENT_SUM_SORTED_IMPL)
     return {
-        "segment_sum": "pallas_dense" if _SEGMENT_SUM_IMPL else "xla",
+        "segment_sum": "pallas_dense" if dense else "xla",
         "segment_sum_sorted": (
-            "pallas_banded" if _SEGMENT_SUM_SORTED_IMPL
-            else "pallas_dense" if _SEGMENT_SUM_IMPL else "xla"),
-        "gather_rows": "pallas_blocked" if _GATHER_IMPL else "xla",
-        "sage_aggregate": "pallas_fused" if _SAGE_FUSED_IMPL else "xla",
+            "pallas_banded" if banded else "pallas_dense" if dense else "xla"),
+        "gather_rows": "pallas_blocked" if _impl(_GATHER_IMPL) else "xla",
+        "sage_aggregate": (
+            "pallas_fused" if _impl(_SAGE_FUSED_IMPL) else "xla"),
     }
 
 
 def _maybe_auto_register() -> None:
-    """On the first aggregation call, swap in the Pallas kernels iff we are
-    actually on a TPU backend (opt out with NERRF_NO_PALLAS=1).  Deferred to
-    call time so importing the library never forces backend initialization."""
+    """On the first aggregation call — traced or eager — install the Pallas
+    kernels iff the backend is a TPU.  At call time, not import time, so
+    importing the library never forces backend initialization.  Nothing is
+    probed: a kernel Mosaic refuses raises where it is compiled."""
     global _AUTO_TRIED
-    if _AUTO_TRIED or _SEGMENT_SUM_IMPL is not None:
-        return
-    from jax._src import core as _core  # trace_state_clean left jax.core in 0.9
-
-    if not _core.trace_state_clean():
-        # First use is inside a jit trace: the probe must execute its smoke
-        # kernels for real (fetch-synced), which a tracing context cannot do
-        # — defer without setting _AUTO_TRIED so the next EAGER call probes.
-        # This trace's program uses the XLA fallback ops; steady-state
-        # processes (bench, training, pipeline warmup) all touch the ops
-        # eagerly first, so this only affects a cold jit-first flow.
+    if _AUTO_TRIED:
         return
     _AUTO_TRIED = True
-    if os.environ.get("NERRF_NO_PALLAS") == "1":
-        return
     if jax.default_backend() == "tpu":
         from nerrf_tpu.ops import pallas_segment
 
@@ -116,10 +131,12 @@ def segment_sum(
     # sorted-by-dst edges) get the banded kernel — linear MXU work; the
     # dense one-hot contraction is order-independent and serves the rest.
     if data.ndim == 2 and jnp.issubdtype(data.dtype, jnp.floating):
-        if sorted_ids and _SEGMENT_SUM_SORTED_IMPL is not None:
-            return _SEGMENT_SUM_SORTED_IMPL(data, segment_ids, num_segments)
-        if _SEGMENT_SUM_IMPL is not None:
-            return _SEGMENT_SUM_IMPL(data, segment_ids, num_segments)
+        banded = _impl(_SEGMENT_SUM_SORTED_IMPL)
+        dense = _impl(_SEGMENT_SUM_IMPL)
+        if sorted_ids and banded is not None:
+            return banded(data, segment_ids, num_segments)
+        if dense is not None:
+            return dense(data, segment_ids, num_segments)
     return jax.ops.segment_sum(
         data, segment_ids, num_segments=num_segments, indices_are_sorted=sorted_ids
     )
@@ -189,14 +206,14 @@ def sage_aggregate(
     # named scope mirrors the host tracing spine's stage names, so the op's
     # rows in an XLA trace line up with the host spans in Perfetto
     with jax.named_scope("sage_aggregate"):
+        fused = _impl(_SAGE_FUSED_IMPL)
         if (
-            _SAGE_FUSED_IMPL is not None
+            fused is not None
             and msg.ndim == 2
             and jnp.issubdtype(msg.dtype, jnp.floating)
         ):
-            return _SAGE_FUSED_IMPL(msg, dst_ids, src_by_dst, src_ids,
-                                    dst_by_src, wf_d, wf_s, wr_s, wr_d,
-                                    num_nodes)
+            return fused(msg, dst_ids, src_by_dst, src_ids, dst_by_src,
+                         wf_d, wf_s, wr_s, wr_d, num_nodes)
         return sage_aggregate_xla(msg, dst_ids, src_by_dst, src_ids,
                                   dst_by_src, wf_d, wf_s, wr_s, wr_d,
                                   num_nodes)
@@ -226,11 +243,12 @@ def gather_rows(table: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
     """Row gather ``table[idx]`` — kept as a named op so the Pallas blocked
     gather can swap in on TPU without touching call sites."""
     _maybe_auto_register()
+    gather = _impl(_GATHER_IMPL)
     if (
-        _GATHER_IMPL is not None
+        gather is not None
         and table.ndim == 2
         and idx.ndim == 1
         and jnp.issubdtype(table.dtype, jnp.floating)
     ):
-        return _GATHER_IMPL(table, idx)
+        return gather(table, idx)
     return jnp.take(table, idx, axis=0)

@@ -7,6 +7,7 @@ from nerrf_tpu.parallel.mesh import (
 )
 from nerrf_tpu.parallel.train import (
     make_sharded_train_step,
+    mesh_ops,
     shard_batch,
     init_sharded_state,
     make_stream_train_step,
@@ -21,6 +22,7 @@ __all__ = [
     "param_sharding",
     "init_distributed",
     "make_sharded_train_step",
+    "mesh_ops",
     "shard_batch",
     "init_sharded_state",
     "make_stream_train_step",

@@ -28,12 +28,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # top-level export is newer jax; 0.4.x keeps it in experimental
-    from jax import shard_map as _shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 _NEG = -1e30
 
@@ -160,12 +156,8 @@ def _ring_shard(q, k, v, *, axis_name: str, manual_axes: tuple, causal: bool) ->
     q_pos = my * c + jax.lax.broadcasted_iota(jnp.int32, (c, 1), 0)  # [C,1] global
 
     # fresh zeros are axis-invariant; mark them varying over the manual axes
-    # so the fori_loop carry type matches its (varying) outputs (pcast is
-    # newer jax; 0.4.x has no varying-ness type to reconcile — identity)
-    if hasattr(jax.lax, "pcast"):
-        pv = lambda x: jax.lax.pcast(x, manual_axes, to="varying")
-    else:
-        pv = lambda x: x
+    # so the fori_loop carry type matches its (varying) outputs
+    pv = lambda x: jax.lax.pcast(x, manual_axes, to="varying")
     o0 = pv(jnp.zeros((b, c, h, d), jnp.float32))
     m0 = pv(jnp.full((b, h, c), _NEG, jnp.float32))
     l0 = pv(jnp.zeros((b, h, c), jnp.float32))
@@ -235,11 +227,7 @@ def ring_self_attention(
         return _attention_local(q, k, v, causal)
 
     spec = P(batch_axis, seq_axis, None, None)
-    # without pcast (jax 0.4.x) the causal-skip cond's branches disagree on
-    # replication types under the checker — disable the check there; newer
-    # jax reconciles the carry via the pcast marking in _ring_shard
-    compat = {} if hasattr(jax.lax, "pcast") else {"check_rep": False}
-    fn = _shard_map(
+    fn = shard_map(
         partial(
             _ring_shard,
             axis_name=seq_axis,
@@ -249,6 +237,5 @@ def ring_self_attention(
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
-        **compat,
     )
     return fn(q, k, v)

@@ -26,10 +26,10 @@ from nerrf_tpu.tracing import span as trace_span
 class MCTSConfig:
     num_simulations: int = 800          # spec band: 500–1000
     # Frontier leaves per device dispatch.  Each dispatch pays a fixed
-    # host→device round trip (large over a remote tunnel); bigger batches
-    # amortize it, and since r2 the dispatch is double-buffered — the host
-    # selects/expands frontier i+1 while batch i's values are in flight —
-    # so the round trip overlaps host work instead of serializing with it.
+    # host→device round trip; bigger batches amortize it, and the dispatch
+    # is double-buffered — the host selects/expands frontier i+1 while
+    # batch i's values are in flight — so the round trip overlaps host work
+    # instead of serializing with it.
     # 64 stays the default to stay conservative on small action spaces;
     # bench.py uses 128 (the benchmark of record tracks rollouts/s there).
     batch_size: int = 64

@@ -1,5 +1,5 @@
 #!/usr/bin/env python3
-"""Standalone nerrflint entry point (the chip-queue pre-flight surface).
+"""Standalone nerrflint entry point (the pre-flight surface).
 
 Thin shim over ``nerrf_tpu.analysis.engine`` — same flags, same exit
 codes (0 clean, 1 unbaselined findings, 2 usage/baseline errors):
@@ -8,8 +8,8 @@ codes (0 clean, 1 unbaselined findings, 2 usage/baseline errors):
     python scripts/nerrflint.py --deep      # + jaxpr-level contracts
 
 Runs the full AST ruleset over ``nerrf_tpu/`` in seconds on CPU (no jax
-import), so ``scripts/e2e.sh`` and ``scripts/tpu_queue.sh`` fail fast on
-analysis errors instead of burning chip time.  ``--deep`` adds the
+import), so ``scripts/e2e.sh`` fails fast on analysis errors instead of
+burning chip time.  ``--deep`` adds the
 program-contract tier (``nerrf_tpu/analysis/programs/``): abstract
 tracing of the real serve/train/parallel entry points on a virtual CPU
 backend — signature closure, donation, collectives, Pallas budgets,

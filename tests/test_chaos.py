@@ -558,8 +558,7 @@ def test_serve_detect_bad_chaos_plan_is_one_line_refusal(tmp_path,
     bad = tmp_path / "bad.json"
     bad.write_text('{"faults": [{"site": "not.a.site", "at": 1}]}')
     rc = cli.main(["serve-detect", "--trace", str(bad),  # never reached
-                   "--chaos-plan", str(bad), "--no-probe",
-                   "--metrics-port", "-1"])
+                   "--chaos-plan", str(bad), "--metrics-port", "-1"])
     assert rc == 2
     assert "INVALID" in capsys.readouterr().err
 

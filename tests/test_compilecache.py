@@ -63,12 +63,12 @@ def _compile_records(journal):
 
 def test_fingerprint_invalidates_on_every_axis():
     """Changing ANY of (program, arg shapes/dtypes/tree, architecture,
-    donation spec, jax version, jaxlib version, device kind, device count,
-    platform) produces a different fingerprint — the no-stale-reuse
+    donation spec, jax version, jaxlib version, device kind, the devices
+    compiled for, platform) produces a different fingerprint — the no-stale-reuse
     guarantee is structural, not probabilistic."""
     avals = aval_signature(_args(), {})
     env = {"jax": "0.4.30", "jaxlib": "0.4.30", "platform": "cpu",
-           "device_kind": "cpu", "device_count": 1}
+           "device_kind": "cpu", "devices": [0]}
     extra = {"model": "JointConfig(hidden=32)", "donate": "(params,)"}
     base, _ = compute_fingerprint("train_step", avals, extra, env=env)
 
@@ -97,8 +97,8 @@ def test_fingerprint_invalidates_on_every_axis():
         ("device kind", compute_fingerprint(
             "train_step", avals, extra,
             env={**env, "device_kind": "TPU v4"})[0]),
-        ("device count", compute_fingerprint(
-            "train_step", avals, extra, env={**env, "device_count": 8})[0]),
+        ("devices", compute_fingerprint(
+            "train_step", avals, extra, env={**env, "devices": [2]})[0]),
         ("platform", compute_fingerprint(
             "train_step", avals, extra, env={**env, "platform": "tpu"})[0]),
     ]
@@ -115,7 +115,7 @@ def test_environment_key_carries_live_identity():
     env = environment_key()
     assert env["jax"] and env["jaxlib"]
     assert env["platform"] == jax.devices()[0].platform
-    assert env["device_count"] == jax.device_count()
+    assert env["devices"] == [jax.devices()[0].id]
     if env["platform"] == "cpu":
         # CPU AOT artifacts are ISA-specific — the key must say whose
         assert env["host_isa"]
@@ -165,6 +165,39 @@ def test_hit_roundtrip_metrics_and_journal(tmp_path):
     assert meta["fingerprint"] == i1.fingerprint
     assert meta["key"]["program"] == "tiny"
     assert meta["key"]["env"]["jax"]
+
+
+def test_reload_keeps_the_device_assignment(tmp_path):
+    """jax 0.9's ``deserialize_and_load`` defaults ``execution_devices`` to
+    every device of the backend; the entry carries its own assignment so a
+    program pinned to one device, and one sharded over a permuted 2x2 mesh,
+    each reload onto exactly the devices they were compiled for (conftest's
+    8 virtual CPU devices stand in for a multi-chip host)."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    fn = _tiny_jit()
+    devs = jax.devices()
+    assert len(devs) >= 4
+
+    x0 = _args()[0]
+    x3 = jax.device_put(x0, devs[3])
+    _, on0 = _cache(tmp_path).load_or_compile(fn, (x0,), program="tiny")
+    _, on3 = _cache(tmp_path).load_or_compile(fn, (x3,), program="tiny")
+    assert on3.source == "fresh" and on3.fingerprint != on0.fingerprint, (
+        "the device compiled for must ride the key")
+    g3, hit3 = _cache(tmp_path).load_or_compile(fn, (x3,), program="tiny")
+    assert hit3.source == "cache"
+    assert g3(x3).sharding.device_set == {devs[3]}
+
+    mesh = Mesh(np.array(devs[:4])[[2, 0, 3, 1]].reshape(2, 2), ("a", "b"))
+    xs = jax.device_put(np.arange(8, dtype=np.float32).reshape(4, 2),
+                        NamedSharding(mesh, P("a", "b")))
+    _, fresh = _cache(tmp_path).load_or_compile(fn, (xs,), program="tiny")
+    gs, hit = _cache(tmp_path).load_or_compile(fn, (xs,), program="tiny")
+    assert (fresh.source, hit.source) == ("fresh", "cache")
+    out = gs(xs)
+    assert out.sharding.device_set == set(devs[:4])
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(fn(xs)))
 
 
 def test_distinct_signatures_distinct_entries(tmp_path):
@@ -565,7 +598,6 @@ def test_payload_self_contained_when_jax_cache_warm(tmp_path):
 
     def warm(aot):
         env = dict(os.environ,
-                   NERRF_AOT_CACHE_DIR=str(tmp_path / aot),
                    JAX_COMPILATION_CACHE_DIR=str(tmp_path / "xla"),
                    JAX_PLATFORMS="cpu",
                    # persist even sub-second CPU compiles so the shared
@@ -573,7 +605,7 @@ def test_payload_self_contained_when_jax_cache_warm(tmp_path):
                    JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0")
         r = subprocess.run(
             [sys.executable, "-m", "nerrf_tpu.cli", "cache", "warm",
-             "--no-probe", "--buckets", "64x128x32"],
+             "--cache-dir", str(tmp_path / aot), "--buckets", "64x128x32"],
             env=env, capture_output=True, text=True, timeout=300)
         assert r.returncode == 0, r.stderr
         return json.loads(r.stdout)["source"]["64n/128e/32s"]

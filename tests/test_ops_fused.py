@@ -1,8 +1,9 @@
 """Fused bidirectional SAGE-aggregation kernel vs the XLA composition.
 
 Interpret mode on the CPU mesh (tests/conftest.py), like test_pallas_ops.py;
-the compiled Mosaic path is exercised on real TPU by the queue's chip-gated
-test leg.  The reference semantics throughout:
+the compiled Mosaic path is exercised on a real TPU by chip_smoke.py and by
+test_pallas_ops.py::test_fused_sage_kernel_compiled_on_tpu.  The reference
+semantics throughout:
 
     out[n] = Σ_{e: dst(e)=n} ŵf(e)·msg[src(e)] + Σ_{e: src(e)=n} ŵr(e)·msg[dst(e)]
 

@@ -1,7 +1,8 @@
 """Pallas sparse-aggregation kernels vs the XLA reference path.
 
 Runs in interpreter mode on the CPU mesh (tests/conftest.py); the compiled
-path is exercised on real TPU by bench.py.
+path is exercised on a real TPU by chip_smoke.py and by the chip-gated tests
+at the end (NERRF_TEST_REAL_BACKEND=1).
 """
 
 import jax
@@ -241,11 +242,52 @@ def test_zero_row_inputs_return_zeros():
     assert g.shape == (0, 4)
 
 
+def test_first_use_inside_a_trace_installs_the_kernels(monkeypatch):
+    """A jit-first process: the very first switchboard call happens while
+    tracing.  Registration must happen right there, so that program is
+    built from the Pallas kernels — not deferred to some later eager call
+    while this trace quietly gets the XLA ops."""
+    monkeypatch.setattr(segment, "_AUTO_TRIED", False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    real_register = pallas_segment.register
+    monkeypatch.setattr(pallas_segment, "register",
+                        lambda: real_register(interpret=True))
+    data = _rand((20, 7), 40)
+    ids = jnp.asarray(np.sort(np.random.default_rng(41).integers(0, 9, 20)),
+                      jnp.int32)
+
+    traced = jax.make_jaxpr(
+        lambda d: segment.segment_sum(d, ids, 9, sorted_ids=True))(data)
+    assert "pallas_call" in str(traced)
+    assert set(segment.active_impls().values()) == {
+        "pallas_dense", "pallas_banded", "pallas_blocked", "pallas_fused"}
+
+
+def test_xla_only_scope_bypasses_registered_kernels():
+    """What a GSPMD-partitioned program traces under (parallel.mesh_ops):
+    every op from its XLA composition, reported as such, and the
+    registration back in force when the scope ends."""
+    pallas_segment.register(interpret=True)
+    data = _rand((20, 7), 42)
+    ids = jnp.asarray(np.sort(np.random.default_rng(43).integers(0, 9, 20)),
+                      jnp.int32)
+
+    def trace():  # a fresh function each time: jax caches traces by identity
+        return str(jax.make_jaxpr(lambda d: segment.gather_rows(
+            segment.segment_sum(d, ids, 9, sorted_ids=True), ids))(data))
+
+    with segment.xla_only():
+        assert set(segment.active_impls().values()) == {"xla"}
+        assert "pallas_call" not in trace()
+    assert segment.active_impls()["gather_rows"] == "pallas_blocked"
+    assert "pallas_call" in trace()
+
+
 def test_sorted_kernels_compiled_on_tpu():
-    """Chip-gated (r2 advisor #2): the COMPILED Mosaic lowering of the
-    banded kernels — not interpret mode — must match XLA at flagship-like
-    shapes, forward and backward.  Runs only where a TPU is attached (the
-    queue's bench leg), skips everywhere else."""
+    """Chip-gated: the COMPILED Mosaic lowering of the banded kernels — not
+    interpret mode — must match XLA at flagship-like shapes, forward and
+    backward.  Runs only where a TPU is attached
+    (NERRF_TEST_REAL_BACKEND=1), skips everywhere else."""
     if jax.default_backend() != "tpu":
         pytest.skip("needs a real TPU backend (compiled Mosaic path)")
     E, N, F = 2048, 1024, 160
@@ -269,5 +311,35 @@ def test_sorted_kernels_compiled_on_tpu():
 
     gp = jax.jit(jax.grad(loss_pallas))(data)
     gx = jax.jit(jax.grad(loss_xla))(data)
+    np.testing.assert_allclose(np.asarray(gp), np.asarray(gx),
+                               rtol=2e-4, atol=2e-4)
+
+
+def test_fused_sage_kernel_compiled_on_tpu():
+    """Chip-gated twin of the test above for the fused SAGE kernel — the
+    one `auto` gives the deployed 4096 bucket: compiled Mosaic, forward and
+    VJP under vmap, against the XLA composition that serves off-TPU."""
+    if jax.default_backend() != "tpu":
+        pytest.skip("needs a real TPU backend (compiled Mosaic path)")
+    B, E, N, F = 2, 8192, 4096, 160
+    rng = np.random.default_rng(9)
+    dst = np.sort(rng.integers(0, N, (B, E))).astype(np.int32)
+    src = rng.integers(0, N, (B, E)).astype(np.int32)
+    order = np.argsort(src, axis=1)
+    take = lambda a: np.take_along_axis(a, order, 1)
+    wf = rng.uniform(0.1, 1.0, (B, E)).astype(np.float32)
+    wr = rng.uniform(0.1, 1.0, (B, E)).astype(np.float32)
+    edges = tuple(jnp.asarray(a) for a in (
+        dst, src, take(src), take(dst), wf, take(wf), take(wr), wr))
+    msg = jnp.asarray(rng.normal(size=(B, N, F)), jnp.float32)
+
+    fused = jax.vmap(lambda m, *e: pallas_segment.sage_aggregate_fused(
+        m, *e, N, False))
+    xla = jax.vmap(lambda m, *e: segment.sage_aggregate_xla(m, *e, N))
+    np.testing.assert_allclose(
+        np.asarray(jax.jit(fused)(msg, *edges)),
+        np.asarray(jax.jit(xla)(msg, *edges)), rtol=2e-4, atol=2e-4)
+    gp = jax.jit(jax.grad(lambda m: jnp.sum(fused(m, *edges) ** 2)))(msg)
+    gx = jax.jit(jax.grad(lambda m: jnp.sum(xla(m, *edges) ** 2)))(msg)
     np.testing.assert_allclose(np.asarray(gp), np.asarray(gx),
                                rtol=2e-4, atol=2e-4)
