@@ -1749,7 +1749,7 @@ def main(argv=None) -> int:
                         "resume from the latest on restart (0 = off)")
     p.add_argument("--trace-out", default=None, metavar="FILE",
                    help="write a Chrome-trace JSON of the run's host spans "
-                        "(enables per-step synced attribution spans)")
+                        "on exit (the loop runs the same either way)")
     p.add_argument("--publish", default=None, metavar="REGISTRY",
                    help="also publish the calibrated checkpoint into this "
                         "model registry (immutable version; promotion is "
@@ -2390,22 +2390,16 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     trace_out = getattr(args, "trace_out", None)
     if trace_out:
-        # enable BEFORE the command body: hot loops opt into per-step
-        # synced attribution spans only when the tracer is enabled.  Clear
-        # first so the file holds THIS command's spans (embedded callers
-        # may run several commands in one process), and restore the
-        # previous enabled state after — --trace-out on one command must
-        # not leave later commands paying the per-step sync.
+        # clear first so the file holds THIS command's spans (embedded
+        # callers may run several commands in one process); the spans
+        # record either way, --trace-out only writes the ring at exit
         from nerrf_tpu import tracing
 
-        prev_enabled = tracing.DEFAULT_TRACER.enabled
         tracing.DEFAULT_TRACER.clear()
-        tracing.set_enabled(True)
     try:
         return args.fn(args)
     finally:
         if trace_out:
-            tracing.set_enabled(prev_enabled)
             try:
                 path = tracing.DEFAULT_TRACER.write(trace_out)
             except OSError as e:

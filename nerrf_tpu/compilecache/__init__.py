@@ -22,6 +22,7 @@ from nerrf_tpu.compilecache.cache import (
     default_cache_dir,
     environment_key,
 )
+from nerrf_tpu.tracing import span
 
 
 class StepCache:
@@ -80,7 +81,11 @@ class StepCache:
         return self._resolve(args)[1]
 
     def __call__(self, *args):
-        return self._resolve(args)[0](*args, *self.tail)
+        fn = self._resolve(args)[0]
+        # the runtime's call alone; `train_step_call` less this span is the
+        # Python the program adds to each step (signature, lookup, re-wrap)
+        with span("train_step_execute", device=True):
+            return fn(*args, *self.tail)
 
 
 __all__ = [

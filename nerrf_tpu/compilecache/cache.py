@@ -49,6 +49,8 @@ import time
 from pathlib import Path
 from typing import Dict, List, Optional, Tuple
 
+from nerrf_tpu.tracing import span
+
 PAYLOAD = "executable.bin"
 TREES = "trees.pkl"
 META = "meta.json"
@@ -117,6 +119,23 @@ def _host_isa_fingerprint() -> str:
         f"{platform.machine()}|{model}|{flags}".encode()).hexdigest()[:12]
 
 
+def source_digest(root: str | Path | None = None) -> str:
+    """Digest of every ``.py`` file under ``root`` (this package): the
+    identity of the source a program was lowered from.  The other key axes
+    describe the call and the environment; none sees an edit to the model
+    or to an op, and an executable compiled before the edit would answer
+    the same lookup after it (measured: a step whose only change was its
+    `jax.named_scope`s was served the old, scope-less executable from a
+    warm cache).  Conservative like the rest of the key: an edit that would
+    not have changed the HLO costs one fresh compile."""
+    root = Path(root) if root is not None else Path(__file__).parents[1]
+    h = hashlib.blake2s(digest_size=8)
+    for path in sorted(root.rglob("*.py")):
+        h.update(path.relative_to(root).as_posix().encode() + b"\0"
+                 + path.read_bytes() + b"\0")
+    return h.hexdigest()
+
+
 def call_devices(args: tuple, kwargs: dict) -> list:
     """Ids of the devices a call with these arguments runs on: those its
     committed array arguments live on (a mesh-sharded batch, a replica's
@@ -133,11 +152,11 @@ def call_devices(args: tuple, kwargs: dict) -> list:
 
 def environment_key() -> dict:
     """The environment axes that invalidate an executable: jax/jaxlib (and
-    libtpu when present) versions, backend platform, device kind, the ids
-    of the devices the program was compiled for (here the default device;
-    `load_or_compile` puts each call's own — `call_devices` — in its
-    place), and — on CPU, where the artifact is ISA-specific — the host
-    ISA."""
+    libtpu when present) versions, this package's source (`source_digest`),
+    backend platform, device kind, the ids of the devices the program was
+    compiled for (here the default device; `load_or_compile` puts each
+    call's own — `call_devices` — in its place), and — on CPU, where the
+    artifact is ISA-specific — the host ISA."""
     import jax
     import jaxlib
 
@@ -145,6 +164,7 @@ def environment_key() -> dict:
     key = {
         "jax": jax.__version__,
         "jaxlib": jaxlib.__version__,
+        "source": source_digest(),
         "platform": dev.platform,
         "device_kind": dev.device_kind,
         "devices": [dev.id],
@@ -429,8 +449,19 @@ class CompileCache:
         ``jit_fn.lower(*args, **kwargs).compile()``, persisted for next
         time.  Total failure (lower/compile/serialize machinery broken):
         the live ``jit_fn`` itself, source="live" — serving always works.
+
+        The whole resolution (fingerprint, read, deserialize or compile,
+        persist) is one ``compile_resolve`` span; ``CompileInfo.seconds``
+        stays the read's or the compile's time alone.
         """
-        kwargs = kwargs or {}
+        with span("compile_resolve", program=program) as sp:
+            fn, info = self._load_or_compile(jit_fn, args, kwargs or {},
+                                             program, extra)
+            sp.args.update(source=info.source, reason=info.reason)
+        return fn, info
+
+    def _load_or_compile(self, jit_fn, args: tuple, kwargs: dict,
+                         program: str, extra: Optional[dict]):
         try:
             avals = aval_signature(args, kwargs)
             env = {**self.env(), "devices": call_devices(args, kwargs)}

@@ -25,6 +25,7 @@ import numpy as np
 
 from nerrf_tpu.data.loaders import GroundTruth, Trace
 from nerrf_tpu.schema.events import EventArrays, InodeTable, OpenFlags, StringTable, Syscall
+from nerrf_tpu.tracing import span as trace_span
 
 _NS = 1_000_000_000
 
@@ -843,5 +844,7 @@ def make_corpus(
             seed=base_seed + i,
             scenario=scenario,
         )
-        out.append(simulate_trace(cfg, name=f"corpus-{i}-{'atk' if attack else 'benign'}"))
+        with trace_span("corpus_simulate", trace=i) as sp:
+            out.append(simulate_trace(cfg, name=f"corpus-{i}-{'atk' if attack else 'benign'}"))
+            sp.args["events"] = len(out[-1].events)
     return out

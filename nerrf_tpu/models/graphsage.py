@@ -206,7 +206,11 @@ class SageBlock(nn.Module):
             # c_sum, and s_f/s_r carry the empty-segment zeroing the
             # segment path gets from its max(denom, eps) guard)
             adj, c_sum, s_f, s_r = dense_view
-            agg = (adj @ msg + c_sum
+            # the scope the fused route's op carries: the same work under
+            # the same name in a device trace, whichever route served it
+            with jax.named_scope("sage_aggregate"):
+                agg = adj @ msg
+            agg = (agg + c_sum
                    + dir_bias[0] * s_f[:, None] + dir_bias[1] * s_r[:, None])
             upd = nn.Dense(self.hidden, dtype=self.dtype, name="w_self")(
                 jnp.concatenate([hn, agg], axis=-1)
@@ -262,69 +266,73 @@ class GraphSAGET(nn.Module):
         n = node_feat.shape[0]
         dt = cfg.dtype
 
-        type_emb = nn.Embed(4, cfg.hidden, dtype=dt, name="type_emb")(node_type)
-        aux_emb = nn.Embed(AUX_VOCAB, cfg.hidden, dtype=dt, name="aux_emb")(node_aux)
-        h = nn.Dense(cfg.hidden, dtype=dt, name="node_enc")(node_feat.astype(dt))
-        h = nn.gelu(h + type_emb + aux_emb)
-        h = h * node_mask[:, None].astype(dt)
+        with jax.named_scope("encoders"):
+            type_emb = nn.Embed(4, cfg.hidden, dtype=dt, name="type_emb")(node_type)
+            aux_emb = nn.Embed(AUX_VOCAB, cfg.hidden, dtype=dt, name="aux_emb")(node_aux)
+            h = nn.Dense(cfg.hidden, dtype=dt, name="node_enc")(node_feat.astype(dt))
+            h = nn.gelu(h + type_emb + aux_emb)
+            h = h * node_mask[:, None].astype(dt)
 
-        e_emb = nn.Dense(cfg.hidden, dtype=dt, name="edge_enc")(edge_feat.astype(dt))
-        e_emb = nn.gelu(e_emb)
+            e_emb = nn.Dense(cfg.hidden, dtype=dt, name="edge_enc")(edge_feat.astype(dt))
+            e_emb = nn.gelu(e_emb)
         # causality weight (edge_feat[:, 12]) gates messages; masked edges → 0
         w32 = (edge_feat[:, 12] + 0.1) * edge_mask.astype(jnp.float32)
         edge_w = w32.astype(dt)
 
         rev_view = dense_view = fused_view = None
         agg_mode = cfg.resolved_aggregation(n)
-        if agg_mode in ("dense_adj", "fused"):
-            # Per-forward aggregation state shared by all layers, so each
-            # of the 28 layers costs ONE kernel (a matmul or the fused
-            # Pallas scatter) — no gather/scatter/normalize on the layer
-            # critical path at all.  fused_edge_views is the shared
-            # precompute (normalizations + both sorted pre-weighted edge
-            # orders; the fused kernel's forward rides one pair, its
-            # adjoint the exchanged pair, so fwd AND bwd stay at one
-            # kernel per layer); the (layer-invariant) e_emb term folds
-            # into c_sum, and s_f/s_r carry the empty-segment zeroing the
-            # segment path gets from its max(denom, eps) guard.
-            edges, d_fwd, d_rev, inv_f, inv_r = fused_edge_views(
-                edge_src, edge_dst, w32, n)
-            we = w32[:, None] * e_emb.astype(jnp.float32)
-            c_f = jax.ops.segment_sum(we, edge_dst, num_segments=n,
-                                      indices_are_sorted=True)
-            c_r = jax.ops.segment_sum(we, edge_src, num_segments=n)
-            c_sum = (c_f * inv_f[:, None] + c_r * inv_r[:, None]).astype(dt)
-            s_f = (d_fwd * inv_f).astype(dt)
-            s_r = (d_rev * inv_r).astype(dt)
-        if agg_mode == "dense_adj":
-            # One [E]→[N·N] scatter builds the raw weighted adjacency whose
-            # normalized form serves every layer as one [N,N]@[N,H] matmul.
-            flat = edge_dst.astype(jnp.int32) * n + edge_src.astype(jnp.int32)
-            w_raw = jax.ops.segment_sum(
-                w32, flat, num_segments=n * n).reshape(n, n)
-            adj = (w_raw * inv_f[:, None]
-                   + w_raw.T * inv_r[:, None]).astype(dt)
-            dense_view = (adj, c_sum, s_f, s_r)
-        elif agg_mode == "fused":
-            fused_view = (edges, c_sum, s_f, s_r)
-        elif agg_mode == "segment":
-            # src-sorted edge view, computed once and shared by every layer:
-            # with it the reverse aggregation also declares sorted ids and
-            # the banded Pallas kernel serves both directions (one [E]
-            # argsort per window vs 28 dense one-hot contractions)
-            src_order = jnp.argsort(edge_src)
-            rev_view = (
-                jnp.take(edge_src, src_order),   # nondecreasing segment ids
-                jnp.take(edge_dst, src_order),   # message source per edge
-                jnp.take(e_emb, src_order, axis=0),
-                jnp.take(edge_w, src_order),
-            )
-        else:
-            raise ValueError(f"unknown aggregation mode {agg_mode!r}")
+        # everything the layers share is computed once per forward, under
+        # one scope (the layers' own scopes hold the per-layer work alone)
+        with jax.named_scope("agg_views"):
+            if agg_mode in ("dense_adj", "fused"):
+                # Per-forward aggregation state shared by all layers, so each
+                # of the 28 layers costs ONE kernel (a matmul or the fused
+                # Pallas scatter) — no gather/scatter/normalize on the layer
+                # critical path at all.  fused_edge_views is the shared
+                # precompute (normalizations + both sorted pre-weighted edge
+                # orders; the fused kernel's forward rides one pair, its
+                # adjoint the exchanged pair, so fwd AND bwd stay at one
+                # kernel per layer); the (layer-invariant) e_emb term folds
+                # into c_sum, and s_f/s_r carry the empty-segment zeroing the
+                # segment path gets from its max(denom, eps) guard.
+                edges, d_fwd, d_rev, inv_f, inv_r = fused_edge_views(
+                    edge_src, edge_dst, w32, n)
+                we = w32[:, None] * e_emb.astype(jnp.float32)
+                c_f = jax.ops.segment_sum(we, edge_dst, num_segments=n,
+                                          indices_are_sorted=True)
+                c_r = jax.ops.segment_sum(we, edge_src, num_segments=n)
+                c_sum = (c_f * inv_f[:, None] + c_r * inv_r[:, None]).astype(dt)
+                s_f = (d_fwd * inv_f).astype(dt)
+                s_r = (d_rev * inv_r).astype(dt)
+            if agg_mode == "dense_adj":
+                # One [E]→[N·N] scatter builds the raw weighted adjacency whose
+                # normalized form serves every layer as one [N,N]@[N,H] matmul.
+                flat = edge_dst.astype(jnp.int32) * n + edge_src.astype(jnp.int32)
+                w_raw = jax.ops.segment_sum(
+                    w32, flat, num_segments=n * n).reshape(n, n)
+                adj = (w_raw * inv_f[:, None]
+                       + w_raw.T * inv_r[:, None]).astype(dt)
+                dense_view = (adj, c_sum, s_f, s_r)
+            elif agg_mode == "fused":
+                fused_view = (edges, c_sum, s_f, s_r)
+            elif agg_mode == "segment":
+                # src-sorted edge view, computed once and shared by every layer:
+                # with it the reverse aggregation also declares sorted ids and
+                # the banded Pallas kernel serves both directions (one [E]
+                # argsort per window vs 28 dense one-hot contractions)
+                src_order = jnp.argsort(edge_src)
+                rev_view = (
+                    jnp.take(edge_src, src_order),   # nondecreasing segment ids
+                    jnp.take(edge_dst, src_order),   # message source per edge
+                    jnp.take(e_emb, src_order, axis=0),
+                    jnp.take(edge_w, src_order),
+                )
+            else:
+                raise ValueError(f"unknown aggregation mode {agg_mode!r}")
 
         # named scopes mirror the host tracing spine: XLA trace rows for
         # each layer show up as gnn_layer_<i> in Perfetto, next to the
-        # device_step host span that dispatched them
+        # train_step_call host span that dispatched them
         for i in range(cfg.num_layers):
             with jax.named_scope(f"gnn_layer_{i}"):
                 h = SageBlock(cfg.hidden, dtype=dt, name=f"block_{i}")(

@@ -156,7 +156,7 @@ class ImpactLSTM(nn.Module):
 
         # named scope mirrors the host tracing spine: the recurrence's XLA
         # trace rows appear as lstm_scan in Perfetto next to the
-        # device_step host span
+        # train_step_call host span
         with jax.named_scope("lstm_scan"):
             (_, _), hs = jax.lax.scan(step, (h0, c0), xs)   # [T,2,B,H]
         hs = jnp.moveaxis(hs, 0, -2)                        # [2,B,T,H]

@@ -17,10 +17,15 @@ Exports are Chrome trace-event JSON (`chrome://tracing` / Perfetto
 loadable: ``{"traceEvents": [{"ph": "X", ...}]}``), so a host trace drops
 into the same UI as an XLA device trace taken with
 `observability.trace_profile`.  Device-side mirroring: model code wraps the
-GNN layers / LSTM scan / fused aggregation in `jax.named_scope` with the
-same stage names, and `device_annotation` adds a
-`jax.profiler.TraceAnnotation` around host regions — so host spans and XLA
-trace rows line up by name in Perfetto.
+GNN layers / LSTM scan / the aggregate in `jax.named_scope`, and
+``span(..., device=True)`` opens a `jax.profiler.TraceAnnotation` of the
+span's name — inert without a profiler session, and with one the span sits
+on the ``/host:CPU`` plane on the device events' clock.
+
+Every span has an ``id`` and the ``parent`` that was the innermost open span
+of its thread when it started (None at the top of a thread), so a span's
+**self time** — its duration less what its children cover — can be read
+(`self_time`, the ``self_ms`` column of `nerrf trace`).
 
 Span naming scheme (dot-separated, coarse → fine):
 
@@ -31,7 +36,16 @@ Span naming scheme (dot-separated, coarse → fine):
     bucket_pad         trace → capacity-bucketed padded window samples
     calibrate          held-out file-threshold calibration
     data_wait          host blocked waiting for input data
-    device_step        one train step, fetch-synced (dispatch + blocked)
+    corpus_simulate    one synthetic trace simulated (data.make_corpus)
+    dataset_upload     host arrays → device, chunked (device_put_chunked)
+    compile_resolve    one executable obtained: fingerprint, cache read,
+                       deserialize or compile, persist (CompileCache)
+    train_step_call    one call of a train step: the program's Python
+                       plus the runtime's call; args: call (0-based)
+    train_step_execute the resolved executable's call alone (child of
+                       train_step_call)
+    train_step_wait    the loop blocked on a device result at a sync it
+                       has anyway (step-0 barrier, logged step, end)
     eval               held-out evaluation pass
     checkpoint         full-state checkpoint save
     mcts_plan          one planner search; mcts_leaf_eval = device batch
@@ -40,17 +54,16 @@ Span naming scheme (dot-separated, coarse → fine):
     serve_device_score one shared padded batch through the eval program
     serve_demux        scored batch fanned back to streams + alert sink
 
-The ring buffer records unconditionally (bounded memory, ~µs overhead);
-``DEFAULT_TRACER.enabled`` additionally opts hot loops into per-step
-*synced* spans (`train/loop.py` fetches the loss inside the span so
-``device_step`` measures the device, not the dispatch queue) — off by
-default because the sync defeats step pipelining.  Enable via
-``NERRF_TRACE=1`` or the CLI's ``--trace-out``.
+The ring buffer records unconditionally (bounded memory, ~µs overhead)
+and there is no switch: no span syncs with the device, so recording never
+changes how a loop runs.  "Tracing off" is "no profiler session and no
+``--trace-out``"; the device's side of a step is the profiler's to give.
 """
 
 from __future__ import annotations
 
 import contextlib
+import itertools
 import json
 import os
 import sys
@@ -70,18 +83,23 @@ STAGE_BUCKETS = (0.0005, 0.002, 0.01, 0.05, 0.25, 1.0, 5.0, 30.0, 120.0)
 
 class Span:
     """One recorded host-side region.  ``t0``/``dur`` are perf-counter
-    seconds relative to the owning tracer's epoch; ``args`` is the mutable
-    attribute dict the ``with`` body may extend (exported verbatim into the
-    Chrome event's ``args``)."""
+    seconds relative to the owning tracer's epoch; ``id`` is unique within
+    the tracer and ``parent`` is the id of the innermost span open on the
+    same thread when this one started (None at the top); ``args`` is the
+    mutable attribute dict the ``with`` body may extend (exported verbatim
+    into the Chrome event's ``args``)."""
 
-    __slots__ = ("name", "t0", "dur", "tid", "args")
+    __slots__ = ("name", "t0", "dur", "tid", "args", "id", "parent")
 
-    def __init__(self, name: str, args: Dict) -> None:
+    def __init__(self, name: str, args: Dict, id: int,
+                 parent: Optional[int]) -> None:
         self.name = name
         self.t0 = 0.0
         self.dur = 0.0
         self.tid = threading.get_ident()
         self.args = args
+        self.id = id
+        self.parent = parent
 
 
 class Tracer:
@@ -97,7 +115,8 @@ class Tracer:
         # aligned offline
         self._t0_perf = time.perf_counter()
         self._t0_epoch = time.time()
-        self.enabled = os.environ.get("NERRF_TRACE") == "1"
+        self._ids = itertools.count(1)
+        self._open = threading.local()  # .stack: ids of this thread's open spans
 
     # -- recording -----------------------------------------------------------
 
@@ -117,25 +136,32 @@ class Tracer:
         learns mid-flight.  ``device=True`` additionally opens a
         `jax.profiler.TraceAnnotation` of the same name (only when jax is
         already imported — this module must not force backend init), so the
-        region shows up host-side in an XLA profiler trace under the same
-        label as the device ops it dispatched.
+        region shows up host-side in an XLA profiler trace, on the device
+        events' clock; a ``call`` argument travels with it as a stat, so a
+        host call and the execution it started can be matched there.
         """
-        sp = Span(stage, args)
+        stack = getattr(self._open, "stack", None)
+        if stack is None:
+            stack = self._open.stack = []
+        sp = Span(stage, args, next(self._ids), stack[-1] if stack else None)
         ann = None
         if device:
             jax = sys.modules.get("jax")
             if jax is not None:
                 try:
-                    ann = jax.profiler.TraceAnnotation(stage)
+                    stats = {"call": args["call"]} if "call" in args else {}
+                    ann = jax.profiler.TraceAnnotation(stage, **stats)
                     ann.__enter__()
                 except Exception:
                     ann = None
+        stack.append(sp.id)
         t0 = time.perf_counter()
         sp.t0 = t0 - self._t0_perf
         try:
             yield sp
         finally:
             sp.dur = time.perf_counter() - t0
+            stack.pop()
             if ann is not None:
                 with contextlib.suppress(Exception):
                     ann.__exit__(None, None, None)
@@ -176,6 +202,7 @@ class Tracer:
                 "name": s.name, "ph": "X", "pid": pid, "tid": s.tid,
                 "ts": round(s.t0 * 1e6, 3),       # µs, tracer-epoch origin
                 "dur": round(s.dur * 1e6, 3),
+                "id": s.id, "parent": s.parent,
             }
             if s.args:
                 ev["args"] = dict(s.args)
@@ -210,25 +237,6 @@ def span(stage: str, device: bool = False, **args):
     return DEFAULT_TRACER.span(stage, device=device, **args)
 
 
-def set_enabled(on: bool = True) -> None:
-    """Opt hot loops into per-step synced attribution spans (see module
-    docstring); the CLI's ``--trace-out`` calls this before the command."""
-    DEFAULT_TRACER.enabled = bool(on)
-
-
-@contextlib.contextmanager
-def device_annotation(name: str):
-    """`jax.profiler.TraceAnnotation` + `jax.named_scope` of one name, when
-    jax is importable — a no-op otherwise.  For host regions that dispatch
-    device work outside a recorded span."""
-    jax = sys.modules.get("jax")
-    if jax is None:
-        yield
-        return
-    with jax.profiler.TraceAnnotation(name), jax.named_scope(name):
-        yield
-
-
 # -- trace-file analysis (the `nerrf trace` subcommand's engine) -------------
 
 
@@ -243,11 +251,34 @@ def load_chrome_trace(path) -> List[dict]:
     return [e for e in events if isinstance(e, dict) and e.get("ph") == "X"]
 
 
-def stage_summary(events: Iterable[dict]) -> Dict[str, dict]:
-    """Per-stage latency stats from "X" events: count, total/mean/p50/max ms."""
-    by_name: Dict[str, List[float]] = {}
+def self_time(events: Iterable[dict]) -> List[float]:
+    """Self time of each "X" event, in µs and in the events' order: its
+    duration less the part of its interval that its children (the events
+    whose ``parent`` is its ``id``) cover — overlapping children count
+    once, a child is clipped to its parent.  An event from a file written
+    before spans had ids has no children and keeps its whole duration."""
+    events = list(events)
+    children: Dict[int, List[dict]] = {}
     for e in events:
+        if e.get("parent") is not None:
+            children.setdefault(e["parent"], []).append(e)
+    out = []
+    for e in events:
+        lo, dur = float(e["ts"]), float(e.get("dur", 0.0))
+        kids = children.get(e.get("id"), ())
+        out.append(dur * (1.0 - coverage(kids, lo, lo + dur)) if kids else dur)
+    return out
+
+
+def stage_summary(events: Iterable[dict]) -> Dict[str, dict]:
+    """Per-stage latency stats from "X" events: count, total/mean/p50/max
+    ms, and ``self_ms``, the stage's total `self_time`."""
+    events = list(events)
+    by_name: Dict[str, List[float]] = {}
+    self_us: Dict[str, float] = {}
+    for e, own in zip(events, self_time(events)):
         by_name.setdefault(e["name"], []).append(float(e.get("dur", 0.0)))
+        self_us[e["name"]] = self_us.get(e["name"], 0.0) + own
     out: Dict[str, dict] = {}
     for name, durs in by_name.items():
         durs.sort()
@@ -255,6 +286,7 @@ def stage_summary(events: Iterable[dict]) -> Dict[str, dict]:
         out[name] = {
             "count": n,
             "total_ms": sum(durs) / 1e3,
+            "self_ms": self_us[name] / 1e3,
             "mean_ms": sum(durs) / n / 1e3,
             "p50_ms": durs[n // 2] / 1e3,
             "max_ms": durs[-1] / 1e3,
@@ -313,14 +345,14 @@ def format_stage_table(events: Iterable[dict]) -> str:
     events = list(events)
     summary = stage_summary(events)
     wall_ms = wall_clock_us(events) / 1e3
-    header = (f"{'stage':<24} {'count':>7} {'total_ms':>10} {'mean_ms':>9} "
-              f"{'p50_ms':>9} {'max_ms':>9} {'%wall':>6}")
+    header = (f"{'stage':<24} {'count':>7} {'total_ms':>10} {'self_ms':>10} "
+              f"{'mean_ms':>9} {'p50_ms':>9} {'max_ms':>9} {'%wall':>6}")
     lines = [header, "-" * len(header)]
     for name, s in sorted(summary.items(), key=lambda kv: -kv[1]["total_ms"]):
         pct = 100.0 * s["total_ms"] / wall_ms if wall_ms > 0 else 0.0
         lines.append(
             f"{name:<24} {s['count']:>7} {s['total_ms']:>10.2f} "
-            f"{s['mean_ms']:>9.3f} {s['p50_ms']:>9.3f} {s['max_ms']:>9.2f} "
+            f"{s['self_ms']:>10.2f} {s['mean_ms']:>9.3f} {s['p50_ms']:>9.3f} {s['max_ms']:>9.2f} "
             f"{pct:>5.1f}%")
     lines.append(f"wall: {wall_ms:.2f} ms, span coverage: "
                  f"{100.0 * coverage(events):.1f}%")

@@ -14,8 +14,8 @@ edge-anomaly task, `architecture.mdx:49-53`) + node BCE (aux) + sequence BCE
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
+import itertools
 import time
 from collections import deque
 from functools import partial
@@ -93,17 +93,18 @@ def make_loss_fn(model: NerrfNet, cfg: TrainConfig):
                 rngs={"dropout": dropout_rng},
             )
         )(*model_inputs(batch))
-        e_mask = batch["edge_mask"].astype(jnp.float32)
-        n_mask = batch["node_mask"].astype(jnp.float32)
-        s_mask = batch["seq_valid"].astype(jnp.float32)
-        edge_loss = _weighted_bce(out["edge_logit"], batch["edge_label"], e_mask, cfg.pos_weight)
-        node_loss = _weighted_bce(out["node_logit"], batch["node_label"], n_mask, cfg.pos_weight)
-        seq_loss = _weighted_bce(out["seq_logit"], batch["seq_label"], s_mask, cfg.pos_weight)
-        total = (
-            cfg.edge_loss_weight * edge_loss
-            + cfg.node_loss_weight * node_loss
-            + cfg.seq_loss_weight * seq_loss
-        )
+        with jax.named_scope("loss"):
+            e_mask = batch["edge_mask"].astype(jnp.float32)
+            n_mask = batch["node_mask"].astype(jnp.float32)
+            s_mask = batch["seq_valid"].astype(jnp.float32)
+            edge_loss = _weighted_bce(out["edge_logit"], batch["edge_label"], e_mask, cfg.pos_weight)
+            node_loss = _weighted_bce(out["node_logit"], batch["node_label"], n_mask, cfg.pos_weight)
+            seq_loss = _weighted_bce(out["seq_logit"], batch["seq_label"], s_mask, cfg.pos_weight)
+            total = (
+                cfg.edge_loss_weight * edge_loss
+                + cfg.node_loss_weight * node_loss
+                + cfg.seq_loss_weight * seq_loss
+            )
         return total, {"edge_loss": edge_loss, "node_loss": node_loss, "seq_loss": seq_loss}
 
     return loss_fn
@@ -122,7 +123,8 @@ def _step_body(loss_fn, state: train_state.TrainState, batch, rng,
     (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(
         state.params, batch, dropout_rng
     )
-    new_state = state.apply_gradients(grads=grads)
+    with jax.named_scope("optimizer_update"):
+        new_state = state.apply_gradients(grads=grads)
     # nerrflint: ok[recompile-hazard] telemetry is STATIC configuration (a Python bool bound by partial/closure from TrainConfig.telemetry, never a traced value) and the axis rides the compile-cache key (step_key_extra)
     if telemetry:
         from nerrf_tpu.trainwatch.telemetry import step_telemetry
@@ -175,6 +177,23 @@ def make_flat_step(model: NerrfNet, cfg: TrainConfig, body, **jit_kwargs):
     return flat_step
 
 
+def traced_step(step_fn):
+    """``step_fn`` behind the ``train_step_call`` span: the one place every
+    train step's host call is timed, cached (`CachedTrainStep`) or not.
+    The span holds the program's own Python and the runtime's call, never a
+    wait for the device; ``call`` counts this object's calls from 0 and
+    travels into a profiler session with the annotation (``device=True``),
+    where it names the execution the call started."""
+    calls = itertools.count()
+
+    def call(*args):
+        with DEFAULT_TRACER.span("train_step_call", device=True,
+                                 call=next(calls)):
+            return step_fn(*args)
+
+    return call
+
+
 class CachedTrainStep:
     """A TrainState-in/TrainState-out train step resolved through the
     persistent compile cache.
@@ -194,6 +213,7 @@ class CachedTrainStep:
 
         self._sc = StepCache(cache, flat_fn, program=program, extra=extra,
                              tail=tail)
+        self._call = traced_step(self._step)
 
     @property
     def infos(self):
@@ -201,6 +221,9 @@ class CachedTrainStep:
         return self._sc.infos
 
     def __call__(self, state, *rest):
+        return self._call(state, *rest)
+
+    def _step(self, state, *rest):
         # a fresh TrainState carries step as a Python int; the program's
         # output carries it as an int32 array — pin the boundary dtype so
         # step 0 and step N resolve to the SAME executable signature
@@ -266,22 +289,26 @@ def device_put_chunked(arrays, max_bytes: int = 64 << 20, block: bool = False,
     out = {}
     t0 = time.perf_counter()
     total = 0
-    for k, v in arrays.items():
-        v = np.asarray(v)
-        nbytes = v.nbytes
-        total += nbytes
-        if nbytes <= max_bytes or v.shape[0] < 2:
-            out[k] = jax.device_put(v)
-        else:
-            rows = max(1, int(v.shape[0] * max_bytes // nbytes))
-            if log and rows == 1 and nbytes > max_bytes * v.shape[0]:
-                log(f"upload warning: single rows of '{k}' exceed the "
-                    f"{max_bytes >> 20} MB chunk bound "
-                    f"({nbytes // v.shape[0] >> 20} MB/row) — transfers "
-                    "stay monolithic per row")
-            pieces = [jax.device_put(v[i:i + rows])
-                      for i in range(0, v.shape[0], rows)]
-            out[k] = jnp.concatenate(pieces, axis=0)
+    # the span is the host's part of the upload (the transfers are async
+    # unless block=True, whose barrier lies outside it)
+    with DEFAULT_TRACER.span("dataset_upload") as sp:
+        for k, v in arrays.items():
+            v = np.asarray(v)
+            nbytes = v.nbytes
+            total += nbytes
+            if nbytes <= max_bytes or v.shape[0] < 2:
+                out[k] = jax.device_put(v)
+            else:
+                rows = max(1, int(v.shape[0] * max_bytes // nbytes))
+                if log and rows == 1 and nbytes > max_bytes * v.shape[0]:
+                    log(f"upload warning: single rows of '{k}' exceed the "
+                        f"{max_bytes >> 20} MB chunk bound "
+                        f"({nbytes // v.shape[0] >> 20} MB/row) — transfers "
+                        "stay monolithic per row")
+                pieces = [jax.device_put(v[i:i + rows])
+                          for i in range(0, v.shape[0], rows)]
+                out[k] = jnp.concatenate(pieces, axis=0)
+        sp.args["bytes"] = total
     if block:
         # per-array barrier: the uploads are independent transfers, so
         # syncing one leaf would not prove the others landed — fetch a
@@ -325,7 +352,8 @@ def _make_resident_steps(model: NerrfNet, cfg: TrainConfig, arrays):
     dev = device_put_chunked(arrays)
 
     def gathered_step(state, idx, rng, data):
-        batch = {k: jnp.take(v, idx, axis=0) for k, v in data.items()}
+        with jax.named_scope("batch_gather"):
+            batch = {k: jnp.take(v, idx, axis=0) for k, v in data.items()}
         return _step_body(loss_fn, state, batch, rng,
                           telemetry=cfg.telemetry)
 
@@ -675,15 +703,16 @@ def train_nerrfnet(
     if compile_cache is not None:
         train_step = cache_train_step(compile_cache, train_step, model, cfg,
                                       "train_step_scheduled")
+    else:
+        train_step = traced_step(train_step)
 
     order_rng = np.random.default_rng(cfg.seed)
     history = _history(full_history)
     # step-time attribution: padding waste is knowable before the first
-    # step (static shapes make padded slots cost real compute), the
-    # host-blocked / data-wait split only when per-step spans sync — so
-    # the fractions below accumulate only under DEFAULT_TRACER.enabled
+    # step (static shapes make padded slots cost real compute); the
+    # host-blocked / data-wait split comes from spans around waits the loop
+    # has anyway — no span adds a sync
     tracer = DEFAULT_TRACER
-    trace_steps = tracer.enabled
     bucket_tag = (f"{train_ds.arrays['node_feat'].shape[1]}n/"
                   f"{train_ds.arrays['edge_src'].shape[1]}e")
     for kind, frac in padding_waste_fractions(train_ds.arrays).items():
@@ -702,20 +731,16 @@ def train_nerrfnet(
                      bucket=bucket_tag):
         for step in range(cfg.num_steps):
             if not resident:
-                dw_cm = tracer.span("data_wait", step=step) if trace_steps \
-                    else contextlib.nullcontext()
-                t_dw = time.perf_counter() if monitor is not None else None
-                with dw_cm as dw:
+                with tracer.span("data_wait", step=step) as dw:
                     idx = order_rng.choice(
                         n, size=min(cfg.batch_size, n), replace=False)
                     batch = {k: jnp.asarray(v[idx])
                              for k, v in train_ds.arrays.items()}
                 # step 0 excluded: the attribution fractions share the
                 # steps/s convention of measuring steady state only
-                if dw is not None and step > 0:
+                if step > 0:
                     data_wait_s += dw.dur
-                if t_dw is not None and step > 0:
-                    dw_accum += time.perf_counter() - t_dw
+                    dw_accum += dw.dur
                 # chaos fault point (disarmed = one global None read):
                 # poison this step's input with NaN — the non-finite
                 # value propagates through loss and gradients, so the
@@ -730,28 +755,19 @@ def train_nerrfnet(
                         batch,
                         node_feat=batch["node_feat"] * jnp.float32(np.nan))
             step_args = (state, rng) if resident else (state, batch, rng)
-            if trace_steps:
-                # synced step: the span measures until the loss exists on
-                # host, so dur − dispatch_s IS the host-blocked time
-                with tracer.span("device_step", device=True,
-                                 step=step) as sp:
-                    t_d = time.perf_counter()
-                    state, loss, aux, rng = train_step(*step_args)
-                    dispatch_s = time.perf_counter() - t_d
-                    # nerrflint: ok[sync-in-hot-loop] the sync IS the
-                    sync_result(loss)  # measurement (host-blocked time)
-                    sp.args["dispatch_s"] = round(dispatch_s, 6)
-                if step > 0:  # step 0 is the compile; see data_wait note
-                    blocked_s += max(sp.dur - dispatch_s, 0.0)
-            else:
-                state, loss, aux, rng = train_step(*step_args)
+            state, loss, aux, rng = train_step(*step_args)
             if step == 0:
-                # nerrflint: ok[sync-in-hot-loop] step-0 compile barrier
-                sync_result(loss)
+                with tracer.span("train_step_wait", step=step):
+                    # nerrflint: ok[sync-in-hot-loop] step-0 compile barrier
+                    sync_result(loss)
                 t_start = time.perf_counter()
             steps_done = step + 1
             if step % cfg.eval_every == 0 or step == cfg.num_steps - 1:
-                entry = _history_entry(step, loss, aux)
+                # the logged step's fetch: the loop's one steady-state sync
+                with tracer.span("train_step_wait", step=step) as wait:
+                    entry = _history_entry(step, loss, aux)
+                if step > 0:
+                    blocked_s += wait.dur
                 history.append(entry)
                 DEFAULT_REGISTRY.gauge_set("train_step", step,
                                            help="last completed train step")
@@ -777,7 +793,10 @@ def train_nerrfnet(
                                 f"{halted[1]} (bundle dumped; resume from "
                                 f"the last good checkpoint)")
                         break
-        sync_result(state.params)
+        with tracer.span("train_step_wait", step=steps_done) as wait:
+            sync_result(state.params)
+        if t_start is not None:
+            blocked_s += wait.dur
     if monitor is not None:
         # stepping is over: post-training eval/calibration can run for
         # minutes and must not read as a train_stall
@@ -785,14 +804,15 @@ def train_nerrfnet(
     elapsed = time.perf_counter() - (t_start or time.perf_counter())
     steps_per_sec = ((steps_done - 1) / elapsed
                      if elapsed > 0 and steps_done > 1 else 0.0)
-    if trace_steps and elapsed > 0 and cfg.num_steps > 1:
+    if elapsed > 0 and cfg.num_steps > 1:
         # same denominator as steps_per_sec (post-step-0 steady state), so
         # the fractions attribute the time the headline number measures —
         # dividing by the whole loop would dilute them with compile time
         DEFAULT_REGISTRY.gauge_set(
             "train_host_blocked_fraction", blocked_s / elapsed,
             help="fraction of steady-state train wall spent blocked on "
-                 "device results (fetch-synced device_step spans)")
+                 "device results (train_step_wait spans: the syncs the "
+                 "loop has anyway)")
         DEFAULT_REGISTRY.gauge_set(
             "train_data_wait_fraction", data_wait_s / elapsed,
             help="fraction of steady-state train wall spent assembling or "
@@ -895,8 +915,6 @@ def train_sharded_stream(
         return _step_body(loss_fn, state, batch, rng,
                           telemetry=cfg.telemetry)
 
-    step_by_idx = jax.jit(stream_body, donate_argnums=(0,))
-
     if compile_cache is not None:
         # persistent AOT cache: each distinct shard shape resolves once
         # (deserialize on a repeat run — the stream_step compile drops to a
@@ -905,6 +923,9 @@ def train_sharded_stream(
             compile_cache, make_flat_step(model, cfg, stream_body),
             program="stream_step",
             extra=step_key_extra(cfg, "stream_step"))
+    else:
+        step_by_idx = traced_step(
+            jax.jit(stream_body, donate_argnums=(0,)))
 
     # -- shard pipeline: disk → host queue → async device upload -------------
     host_q: "queue_mod.Queue" = queue_mod.Queue(maxsize=1)

@@ -34,7 +34,13 @@ from nerrf_tpu.compilecache import (
     export_executables,
     read_manifest,
 )
-from nerrf_tpu.compilecache.cache import META, PAYLOAD, TREES, aval_signature
+from nerrf_tpu.compilecache.cache import (
+    META,
+    PAYLOAD,
+    TREES,
+    aval_signature,
+    source_digest,
+)
 from nerrf_tpu.flight.journal import EventJournal
 from nerrf_tpu.observability import MetricsRegistry
 
@@ -119,6 +125,26 @@ def test_environment_key_carries_live_identity():
     if env["platform"] == "cpu":
         # CPU AOT artifacts are ISA-specific — the key must say whose
         assert env["host_isa"]
+    assert env["source"] == source_digest()
+
+
+def test_source_digest_follows_every_edit_under_the_root(tmp_path):
+    """An executable compiled before an edit to the program must not answer
+    a lookup after it: the digest moves with any .py file's content or
+    name, and with nothing else."""
+    (tmp_path / "models").mkdir()
+    (tmp_path / "models" / "a.py").write_text("x = 1\n")
+    (tmp_path / "b.py").write_text("y = 2\n")
+    (tmp_path / "notes.txt").write_text("not source")
+    base = source_digest(tmp_path)
+    assert base == source_digest(tmp_path)
+    (tmp_path / "notes.txt").write_text("still not source")
+    assert source_digest(tmp_path) == base
+    (tmp_path / "models" / "a.py").write_text("x = 2\n")
+    edited = source_digest(tmp_path)
+    assert edited != base
+    (tmp_path / "models" / "a.py").rename(tmp_path / "models" / "c.py")
+    assert source_digest(tmp_path) not in (base, edited)
 
 
 def test_train_step_key_extra_tracks_config():

@@ -263,3 +263,50 @@ def test_lstm_impl_paths_parity():
     for k in ("seq_logit", "seq_emb"):
         err = np.max(np.abs(np.asarray(of[k]) - np.asarray(orr[k])))
         assert err < 1e-4, (k, err)
+
+
+def test_dense_adj_aggregate_is_scoped_like_the_fused_route():
+    """On `dense_adj` the aggregate is ``adj @ msg``: that product and its
+    transpose carry the `sage_aggregate` scope the fused route's op carries
+    (one name for the same work in a device trace), and nothing else does —
+    the `c_sum` / `dir_bias` terms lie outside it on both routes."""
+    import re
+
+    from nerrf_tpu.graph import EDGE_FEATURE_DIM, NODE_FEATURE_DIM
+
+    n, e, layers = 16, 32, 2
+    rng = np.random.default_rng(0)
+    args = (rng.normal(size=(n, NODE_FEATURE_DIM)).astype(np.float32),
+            rng.integers(0, 4, n).astype(np.int32),
+            rng.integers(0, 8, n).astype(np.int32), np.ones(n, bool),
+            rng.integers(0, n, e).astype(np.int32),
+            np.sort(rng.integers(0, n, e)).astype(np.int32),
+            rng.normal(size=(e, EDGE_FEATURE_DIM)).astype(np.float32),
+            np.ones(e, bool))
+    model = GraphSAGET(GraphSAGEConfig(hidden=8, num_layers=layers,
+                                       dropout=0.0, aggregation="dense_adj"))
+    params = model.init(jax.random.PRNGKey(0), *args)["params"]
+
+    def loss(p):
+        out = model.apply({"params": p}, *args)
+        return out["edge_logit"].sum() + out["node_logit"].sum()
+
+    text = jax.jit(jax.grad(loss)).lower(params).as_text(debug_info=True)
+    paths = re.findall(r'^#loc\d+ = loc\("([^"]+)"', text, flags=re.M)
+    scoped = [p for p in paths if "sage_aggregate" in p]
+    for i in range(layers):
+        here = f"/gnn_layer_{i}/block_{i}/sage_aggregate/dot_general"
+        assert any(p.startswith("jit(loss)/jvp(") and p.endswith(here)
+                   for p in scoped), (i, scoped)
+        assert any(p.startswith("jit(loss)/transpose(jvp(")
+                   and p.endswith(here) for p in scoped), (i, scoped)
+    # the product and the layout change of its transpose, nothing else: no
+    # add (c_sum, dir_bias), no multiply, no broadcast
+    assert {p.rsplit("/", 1)[1] for p in scoped} <= {"dot_general",
+                                                     "transpose"}
+    # the per-forward precompute and the encoders have scopes of their own
+    # that no roofline group matches
+    for name in ("agg_views", "encoders"):
+        own = [p for p in paths if f"/{name}/" in p]
+        assert own and not any("sage_aggregate" in p or "gnn_layer_" in p
+                               for p in own), name

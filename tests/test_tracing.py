@@ -13,22 +13,81 @@ from nerrf_tpu.observability import MetricsRegistry
 def test_span_records_and_dual_writes():
     reg = MetricsRegistry(namespace="t")
     tr = tracing.Tracer(registry=reg)
-    with tr.span("device_step", step=3) as sp:
+    with tr.span("train_step_wait", step=3) as sp:
         time.sleep(0.002)
-        sp.args["dispatch_s"] = 0.001
+        sp.args["reason"] = "logged"
     recs = tr.records()
-    assert len(recs) == 1 and recs[0].name == "device_step"
+    assert len(recs) == 1 and recs[0].name == "train_step_wait"
     assert recs[0].dur >= 0.002
-    assert recs[0].args == {"step": 3, "dispatch_s": 0.001}
+    assert recs[0].args == {"step": 3, "reason": "logged"}
     # dual-write: the same span landed in the per-stage histogram, so
     # Prometheus and the trace agree from one instrumentation point
     assert reg.value(tracing.STAGE_HISTOGRAM,
-                     labels={"stage": "device_step"}, stat="count") == 1
+                     labels={"stage": "train_step_wait"}, stat="count") == 1
     assert reg.value(tracing.STAGE_HISTOGRAM,
-                     labels={"stage": "device_step"}, stat="sum") >= 0.002
+                     labels={"stage": "train_step_wait"}, stat="sum") >= 0.002
     text = reg.render()
     assert "# TYPE t_stage_latency_seconds histogram" in text
-    assert 'stage="device_step"' in text
+    assert 'stage="train_step_wait"' in text
+    # recording has no switch: nothing can opt a loop into another mode
+    assert not hasattr(tr, "enabled") and not hasattr(tracing, "set_enabled")
+
+
+def test_spans_carry_id_and_parent_per_thread():
+    """``parent`` is the innermost span open on the SAME thread: a span on
+    another thread started while this thread's span is open is a root."""
+    tr = tracing.Tracer(registry=MetricsRegistry())
+    with tr.span("outer") as outer:
+        with tr.span("mid") as mid:
+            with tr.span("leaf") as leaf:
+                pass
+        with tr.span("sibling") as sibling:
+            def other():
+                with tr.span("other_thread"):
+                    with tr.span("other_child"):
+                        pass
+            t = threading.Thread(target=other)
+            t.start()
+            t.join(timeout=10)
+            assert not t.is_alive()
+    with tr.span("after") as after:
+        pass
+    by_name = {r.name: r for r in tr.records()}
+    assert len(by_name) == 7
+    assert len({r.id for r in by_name.values()}) == 7
+    assert outer.parent is None and after.parent is None
+    assert mid.parent == outer.id and leaf.parent == mid.id
+    assert sibling.parent == outer.id
+    assert by_name["other_thread"].parent is None
+    assert by_name["other_child"].parent == by_name["other_thread"].id
+    # a body that raises still closes its span: the next one is no child
+    with pytest.raises(RuntimeError):
+        with tr.span("fails"):
+            raise RuntimeError("boom")
+    with tr.span("next") as nxt:
+        pass
+    assert nxt.parent is None
+
+
+def test_self_time_is_duration_less_what_children_cover():
+    # parent [0, 100]; children [10, 30] and [20, 50] overlap (40 covered),
+    # a gap, then [80, 120] sticks out and is clipped to 20; the grandchild
+    # is its parent's business alone
+    events = [
+        {"name": "p", "ph": "X", "ts": 0.0, "dur": 100.0, "id": 1, "parent": None},
+        {"name": "c", "ph": "X", "ts": 10.0, "dur": 20.0, "id": 2, "parent": 1},
+        {"name": "c", "ph": "X", "ts": 20.0, "dur": 30.0, "id": 3, "parent": 1},
+        {"name": "g", "ph": "X", "ts": 22.0, "dur": 5.0, "id": 4, "parent": 3},
+        {"name": "c", "ph": "X", "ts": 80.0, "dur": 40.0, "id": 5, "parent": 1},
+        {"name": "old", "ph": "X", "ts": 200.0, "dur": 7.0},  # pre-id file
+    ]
+    assert tracing.self_time(events) == pytest.approx(
+        [40.0, 20.0, 25.0, 5.0, 40.0, 7.0])
+    summary = tracing.stage_summary(events)
+    assert summary["p"]["self_ms"] == pytest.approx(0.040)
+    assert summary["c"]["self_ms"] == pytest.approx(0.085)
+    assert summary["c"]["total_ms"] == pytest.approx(0.090)
+    assert "self_ms" in tracing.format_stage_table(events)
 
 
 def test_chrome_trace_export_round_trips(tmp_path):
@@ -41,12 +100,18 @@ def test_chrome_trace_export_round_trips(tmp_path):
     xs = [e for e in data["traceEvents"] if e.get("ph") == "X"]
     assert {e["name"] for e in xs} == {"graph_lower", "inner"}
     assert all("ts" in e and "dur" in e and "tid" in e for e in xs)
+    # id and parent survive the file: self time can be read offline
+    outer, inner = (next(e for e in xs if e["name"] == n)
+                    for n in ("graph_lower", "inner"))
+    assert outer["parent"] is None and inner["parent"] == outer["id"]
     # thread metadata present so Perfetto names the rows
     assert any(e.get("name") == "thread_name" for e in data["traceEvents"])
 
     events = tracing.load_chrome_trace(path)
     summary = tracing.stage_summary(events)
     assert summary["graph_lower"]["count"] == 1
+    assert summary["graph_lower"]["self_ms"] == pytest.approx(
+        (outer["dur"] - inner["dur"]) / 1e3, abs=1e-3)
     table = tracing.format_stage_table(events)
     assert "graph_lower" in table and "%wall" in table
 
@@ -157,19 +222,21 @@ def test_coverage_clamps_spans_to_the_requested_interval():
         == pytest.approx(20.0 / 25.0)
 
 
-def test_train_loop_emits_covering_trace(tmp_path):
+def test_train_loop_emits_covering_trace(tmp_path, monkeypatch):
     """Acceptance: a 20-step synthetic-corpus run emits a Chrome trace whose
-    spans cover ≥95% of the run's wall-clock, and the registry carries the
-    stage histograms plus the attribution gauges."""
+    spans cover ≥95% of the run's wall-clock with no switch thrown and no
+    sync added: one `train_step_call` per step, `train_step_wait` around
+    the waits the loop has anyway, and both attribution gauges."""
     from nerrf_tpu.data import make_corpus
     from nerrf_tpu.graph import GraphConfig
     from nerrf_tpu.models import JointConfig
     from nerrf_tpu.observability import DEFAULT_REGISTRY
     from nerrf_tpu.tracing import DEFAULT_TRACER
     from nerrf_tpu.train import TrainConfig, build_dataset
+    from nerrf_tpu.train import loop
     from nerrf_tpu.train.data import DatasetConfig
-    from nerrf_tpu.train.loop import train_nerrfnet
 
+    DEFAULT_TRACER.clear()
     corpus = make_corpus(2, attack_fraction=0.5, base_seed=5,
                          duration_sec=60.0, num_target_files=4,
                          benign_rate_hz=10.0)
@@ -177,43 +244,143 @@ def test_train_loop_emits_covering_trace(tmp_path):
         graph=GraphConfig(window_sec=45.0, stride_sec=25.0,
                           max_nodes=64, max_edges=128),
         seq_len=16, max_seqs=16))
+    simulated = [r for r in DEFAULT_TRACER.records()
+                 if r.name == "corpus_simulate"]
+    assert [r.args["trace"] for r in simulated] == [0, 1]
+    assert all(r.args["events"] > 0 for r in simulated)
     DEFAULT_TRACER.clear()
-    was_enabled = DEFAULT_TRACER.enabled
-    DEFAULT_TRACER.enabled = True
-    try:
-        res = train_nerrfnet(ds, None, TrainConfig(
-            model=JointConfig().small, batch_size=4, num_steps=20,
-            eval_every=10, warmup_steps=2))
-    finally:
-        DEFAULT_TRACER.enabled = was_enabled
+    syncs = []
+    real_sync = loop.sync_result
+    monkeypatch.setattr(loop, "sync_result",
+                        lambda x: (syncs.append(1), real_sync(x))[1])
+    res = loop.train_nerrfnet(ds, None, TrainConfig(
+        model=JointConfig().small, batch_size=4, num_steps=20,
+        eval_every=10, warmup_steps=2))
     assert res.steps_per_sec > 0
+    # the step-0 barrier and the final one: the loop syncs where it did
+    assert len(syncs) == 2
 
     path = DEFAULT_TRACER.write(tmp_path / "train_trace.json")
     events = tracing.load_chrome_trace(path)
     names = {e["name"] for e in events}
-    assert {"train_setup", "train_loop", "device_step", "eval"} <= names
-    assert sum(1 for e in events if e["name"] == "device_step") == 20
+    assert {"train_setup", "train_loop", "train_step_call", "train_step_wait",
+            "dataset_upload", "eval"} <= names
+    assert "device_step" not in names
+    calls = [e for e in events if e["name"] == "train_step_call"]
+    assert [e["args"]["call"] for e in calls] == list(range(20))
+    loop_ev = next(e for e in events if e["name"] == "train_loop")
+    assert all(e["parent"] == loop_ev["id"] for e in calls)
+    waits = [e for e in events if e["name"] == "train_step_wait"]
+    # step-0 barrier, the logged steps 0 / 10 / 19, the end of the loop
+    assert [e["args"]["step"] for e in waits] == [0, 0, 10, 19, 20]
+    assert all(e["parent"] == loop_ev["id"] for e in waits)
+    upload = next(e for e in events if e["name"] == "dataset_upload")
+    assert upload["args"]["bytes"] == sum(
+        v.nbytes for v in ds.arrays.values())
     assert tracing.coverage(events) >= 0.95, tracing.format_stage_table(events)
     # non-vacuous attribution: the per-step LEAF spans alone must cover the
     # train_loop interval — the enclosing wrapper spans cannot satisfy this,
     # so silently dropping the per-step instrumentation fails here
-    loop = next(e for e in events if e["name"] == "train_loop")
-    leaves = [e for e in events if e["name"] in ("device_step", "data_wait")]
+    leaves = calls + waits
     leaf_cov = tracing.coverage(
-        leaves, lo_us=loop["ts"], hi_us=loop["ts"] + loop["dur"])
+        leaves, lo_us=loop_ev["ts"], hi_us=loop_ev["ts"] + loop_ev["dur"])
     assert leaf_cov >= 0.9, tracing.format_stage_table(events)
 
     text = DEFAULT_REGISTRY.render()
-    for stage in ("device_step", "eval", "train_loop", "graph_lower"):
+    for stage in ("train_step_call", "train_step_wait", "eval", "train_loop",
+                  "graph_lower", "corpus_simulate"):
         assert f'stage="{stage}"' in text, stage
-    assert "nerrf_train_host_blocked_fraction" in text
-    assert "nerrf_train_data_wait_fraction" in text
+    assert 0.0 < DEFAULT_REGISTRY.value("train_host_blocked_fraction") <= 1.0
+    assert DEFAULT_REGISTRY.value("train_data_wait_fraction") == 0.0
     assert 'nerrf_train_padding_waste_fraction{bucket="64n/128e",kind="node"}' \
         in text
-    # the synced device_step spans carry the dispatch split the
-    # host-blocked fraction is derived from
-    steps = [e for e in events if e["name"] == "device_step"]
-    assert all("dispatch_s" in e.get("args", {}) for e in steps)
+
+
+def _toy_cached_step(tmp_path):
+    """A `CachedTrainStep` around a one-line flat program: the real step
+    classes without the model's compile."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+    from flax.training import train_state
+
+    from nerrf_tpu.compilecache import CompileCache
+    from nerrf_tpu.train.loop import CachedTrainStep
+
+    @jax.jit
+    def flat(params, opt_state, step_no, x):
+        params = jax.tree_util.tree_map(lambda p: p + x.sum(), params)
+        return (params, opt_state, step_no + 1), x.sum(), {}, x
+
+    state = train_state.TrainState.create(
+        apply_fn=None, params={"w": jnp.ones((4,))}, tx=optax.sgd(0.1))
+    step = CachedTrainStep(CompileCache(root=tmp_path / "aot"), flat,
+                           program="toy_step")
+    return state, step, jnp.ones((3,))
+
+
+def test_cached_step_spans_nest_and_count_calls(tmp_path):
+    """`train_step_call` holds `compile_resolve` (first call only) and
+    `train_step_execute`; its self time is what is left."""
+    state, step, x = _toy_cached_step(tmp_path)
+    tr = tracing.DEFAULT_TRACER
+    n0 = len(tr.records())
+    for _ in range(3):
+        state, _loss, _aux, x = step(state, x)
+    assert int(state.step) == 3
+    recs = tr.records()[n0:]
+    calls = [r for r in recs if r.name == "train_step_call"]
+    assert [r.args["call"] for r in calls] == [0, 1, 2]
+    executes = [r for r in recs if r.name == "train_step_execute"]
+    assert [r.parent for r in executes] == [r.id for r in calls]
+    (resolve,) = [r for r in recs if r.name == "compile_resolve"]
+    assert resolve.parent == calls[0].id
+    assert resolve.args == {"program": "toy_step", "source": "fresh",
+                            "reason": "absent"}
+    assert resolve.dur >= step.infos[0].seconds
+    for call, ex in zip(calls, executes):
+        assert call.t0 <= ex.t0 and ex.t0 + ex.dur <= call.t0 + call.dur
+
+
+def test_execute_annotation_lies_inside_its_call_on_the_host_plane(tmp_path):
+    """Inside a `jax.profiler` session the two step spans are on the
+    ``/host:CPU`` plane, on the clock the device events share, each
+    `train_step_execute` inside its `train_step_call`, the call's number
+    beside it as a stat."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    state, step, x = _toy_cached_step(tmp_path)
+    state, _loss, _aux, x = step(state, x)        # resolved outside
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path / "prof"), profiler_options=options)
+    try:
+        for _ in range(4):
+            state, _loss, _aux, x = step(state, x)
+        jax.block_until_ready(state.params)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "prof" / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    found = {"train_step_call": [], "train_step_execute": []}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:CPU"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name in found:
+                    found[e.name].append(
+                        (e.start_ns, e.start_ns + e.duration_ns,
+                         dict(e.stats)))
+    calls = sorted(found["train_step_call"])
+    executes = sorted(found["train_step_execute"])
+    assert len(calls) == len(executes) == 4
+    assert [c[2]["call"] for c in calls] == [1, 2, 3, 4]
+    for (c0, c1, _), (e0, e1, _) in zip(calls, executes):
+        assert c0 <= e0 and e1 <= c1
 
 
 def test_cli_trace_subcommand(tmp_path, capsys):
