@@ -249,9 +249,8 @@ def main() -> None:
     big_bucket = {
         "shape": "4096n/8192e/128seq", "batch": big_cfg.batch_size,
         "padding_waste": padding_waste_fractions(big_ds.arrays),
-        # the 4096 bucket routes `auto` differently from the
-        # flagship shape (fused past DENSE_ADJ_MAX_NODES) — stamp
-        # the mode this leg's numbers belong to
+        # `auto` routes by node bucket (fused past DENSE_ADJ_MAX_NODES)
+        # — stamp the mode this leg's numbers belong to
         "gnn_aggregation": big_cfg.model.gnn.resolved_aggregation(
             big_ds_cfg.graph.max_nodes),
         "steps_per_sec": round(big_sps, 3),

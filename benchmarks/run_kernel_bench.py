@@ -19,6 +19,16 @@ records, per (mode, bucket):
     implementation that actually served it (TpuGraphs' lesson, arXiv:
     2308.13490: a runtime number without its kernel config is unusable).
 
+`--stack` runs the leg the `auto` crossover is read from, measured where
+the rule is used, in place of the single call (which on a chip is over
+before the host's round trip is: 1.2-3.3 ms at every bucket from 1024 to
+16384, whatever the mode): per bucket and mode, one stack of `--layers`
+aggregates (a GraphSAGE-T's worth), forward AND backward, under `vmap` over
+a batch of `--batch` windows, the per-forward precompute (for dense_adj the
+[N,N] scatter, transpose and cast) inside the timed program, with the
+compiled program's planned bytes beside the times and a mode that does not
+compile (out of memory) recorded as not fitting.
+
 Off-TPU the wall-clock columns are degraded (XLA-CPU serves all modes; the
 artifact says so) but the kernel-count attribution and the O(N²)-vs-O(E)
 work ratio still hold; an `interpret_parity` leg additionally runs the fused
@@ -30,6 +40,9 @@ cites the artifact this script writes.
 Usage:
   python benchmarks/run_kernel_bench.py --platform cpu \
       --out benchmarks/results/kernel_bench_cpu.json
+  python benchmarks/run_kernel_bench.py --stack \
+      --buckets 1024,2048,4096,8192,16384 \
+      --out benchmarks/results/kernel_bench_v5e.json     # on the chip
 """
 
 from __future__ import annotations
@@ -81,7 +94,8 @@ def bench_bucket(n, e, hidden, iters, dtype, fetch, report_rows):
     import jax
     import jax.numpy as jnp
 
-    from nerrf_tpu.models.graphsage import GraphSAGEConfig, fused_edge_views
+    from nerrf_tpu.models.graphsage import (GraphSAGEConfig, dense_adjacency,
+                                            fused_edge_views)
     from nerrf_tpu.ops import gather_rows, sage_aggregate, segment_mean
 
     src_np, dst_np, w_np = _graph(n, e, seed=n)
@@ -115,14 +129,9 @@ def bench_bucket(n, e, hidden, iters, dtype, fetch, report_rows):
     edges, _d_f, _d_r, inv_f, inv_r = _views(w32)
 
     # --- dense_adj: one [N,N]@[N,H] matmul per layer ------------------------
-    @jax.jit
-    def _build_adj(w):
-        flat = dst.astype(jnp.int32) * n + src.astype(jnp.int32)
-        w_raw = jax.ops.segment_sum(w, flat, num_segments=n * n
-                                    ).reshape(n, n)
-        return (w_raw * inv_f[:, None] + w_raw.T * inv_r[:, None]
-                ).astype(dtype)
-
+    # (the model's own build too: graphsage.py dense_adjacency)
+    _build_adj = jax.jit(
+        lambda w: dense_adjacency(src, dst, w, inv_f, inv_r, n, dtype))
     dense_build_ms, _ = _time_fn(_build_adj, w32, iters, fetch)
     adj = _build_adj(w32)
     agg_dense = jax.jit(lambda m: adj @ m)
@@ -153,6 +162,103 @@ def bench_bucket(n, e, hidden, iters, dtype, fetch, report_rows):
     })
 
 
+def bench_stack(n, e, hidden, layers, batch, iters, dtype):
+    """-> {mode: {...}}: one stack of ``layers`` aggregates, forward and
+    backward, over ``batch`` windows under `vmap` -- the GNN's aggregate as
+    a train step runs it, and nothing else of the step.  Each mode's program
+    builds its own per-forward views from (src, dst, w) inside the timed
+    call, once a forward, so the adjacency's scatter and its bytes count on
+    dense_adj's side; ``build_ms`` is that part alone, and ``ms_per_layer``
+    what is left, a layer.  Timed to `block_until_ready`: fetching an
+    [8, N, N] adjacency to the host would time the transfer.  A mode whose
+    program the compiler refuses is recorded with ``fits: false`` and the
+    refusal's first line."""
+    import jax
+    import jax.numpy as jnp
+
+    from nerrf_tpu.models.graphsage import dense_adjacency, fused_edge_views
+    from nerrf_tpu.ops import gather_rows, sage_aggregate, segment_mean
+
+    graphs = [_graph(n, e, seed=n + 7 * b) for b in range(batch)]
+    src, dst, w32 = (jnp.asarray(np.stack(col)) for col in zip(*graphs))
+    rng = np.random.default_rng(n + 1)
+    msg = jnp.asarray(rng.normal(size=(batch, n, hidden)), dtype)
+    cot = jnp.asarray(rng.normal(size=(batch, n, hidden)), dtype)
+
+    def views_dense(s, d, w):
+        _, _, _, inv_f, inv_r = fused_edge_views(s, d, w, n)
+        return dense_adjacency(s, d, w, inv_f, inv_r, n, dtype)
+
+    def views_fused(s, d, w):
+        return fused_edge_views(s, d, w, n)[0]
+
+    def views_segment(s, d, w):
+        order = jnp.argsort(s)
+        return (s, d, w.astype(dtype), jnp.take(s, order),
+                jnp.take(d, order), jnp.take(w, order).astype(dtype))
+
+    def layer_segment(view, m):
+        s, d, w, s_sorted, d_srcorder, w_s = view
+        return (segment_mean(gather_rows(m, s), d, n, weights=w,
+                             sorted_ids=True)
+                + segment_mean(gather_rows(m, d_srcorder), s_sorted, n,
+                               weights=w_s, sorted_ids=True))
+
+    routes = {
+        "segment": (views_segment, layer_segment),
+        "dense_adj": (views_dense, lambda adj, m: adj @ m),
+        "fused": (views_fused, lambda edges, m: sage_aggregate(m, *edges, n)),
+    }
+
+    def stack_of(views, layer):
+        def one_window(s, d, w, m, c):
+            view = views(s, d, w)
+
+            def loss(m0):
+                out = m0
+                for _ in range(layers):
+                    out = layer(view, out)
+                return jnp.sum(out.astype(jnp.float32)
+                               * c.astype(jnp.float32))
+
+            return jax.value_and_grad(loss)(m)
+
+        return jax.jit(jax.vmap(one_window))
+
+    out = {}
+    for name, (views, layer) in routes.items():
+        try:
+            compiled = stack_of(views, layer).lower(
+                src, dst, w32, msg, cot).compile()
+            mem = compiled.memory_analysis()
+            stack_ms, _ = _time_fn(
+                lambda m: compiled(src, dst, w32, m, cot), msg, iters,
+                jax.block_until_ready)
+            build = jax.jit(jax.vmap(views))
+            build_ms, _ = _time_fn(lambda w: build(src, dst, w), w32, iters,
+                                   jax.block_until_ready)
+        except Exception as err:  # noqa: BLE001 - a sweep records and goes on
+            line = (str(err).strip().splitlines() or [repr(err)])[0]
+            out[name] = {"fits": False, "error": line[:300]}
+            _log(f"  stack n={n} {name}: does not run: {line[:160]}")
+            continue
+        out[name] = {
+            "fits": True,
+            "stack_ms": round(stack_ms, 3),
+            "build_ms": round(build_ms, 3),
+            "ms_per_layer": round((stack_ms - build_ms) / layers, 4),
+            "peak_bytes": int(mem.temp_size_in_bytes
+                              + mem.argument_size_in_bytes
+                              + mem.output_size_in_bytes),
+            "temp_bytes": int(mem.temp_size_in_bytes),
+        }
+        _log(f"  stack n={n} {name}: {stack_ms:.3f} ms fwd+bwd "
+             f"({build_ms:.3f} build), "
+             f"{out[name]['peak_bytes'] / 1e9:.2f} GB")
+    ran = {m: r["stack_ms"] for m, r in out.items() if r["fits"]}
+    return {"modes": out, "wins": min(ran, key=ran.get) if ran else None}
+
+
 def interpret_parity(hidden):
     """Run the fused Pallas kernel in interpreter mode at the smallest
     bucket against the XLA composition that serves production off-TPU
@@ -180,16 +286,30 @@ def interpret_parity(hidden):
             "pallas_calls_per_layer": 1, "ok": bool(err < 1e-4)}
 
 
+def _dense_minus_fused(row):
+    """dense_adj's time less fused's at one bucket, in ms: of the batched
+    forward + backward stack where the sweep ran it (`--stack`: the rule's
+    own use), else of the single forward call; None where either mode did
+    not run there."""
+    stack = (row.get("stack") or {}).get("modes")
+    if stack:
+        if not (stack["dense_adj"]["fits"] and stack["fused"]["fits"]):
+            return None
+        return stack["dense_adj"]["stack_ms"] - stack["fused"]["stack_ms"]
+    return (row["modes"]["dense_adj"]["ms_per_layer"]
+            - row["modes"]["fused"]["ms_per_layer"])
+
+
 def measured_crossover(rows):
-    """The smallest node count where the fused kernel's per-layer time
-    matches dense_adj's, log-interpolated between swept buckets — the
-    number the `nerrf tune` kernel-routing prior cites.  None when one
-    mode dominates the whole sweep (no crossing to cite)."""
+    """The smallest node count where the fused kernel's time matches
+    dense_adj's, log-interpolated between swept buckets — the number the
+    `nerrf tune` kernel-routing prior cites.  None when one mode dominates
+    every bucket both ran at (no crossing to cite: the prior then falls
+    back on the authored constant)."""
     import math
 
-    pts = sorted((r["nodes"],
-                  r["modes"]["dense_adj"]["ms_per_layer"]
-                  - r["modes"]["fused"]["ms_per_layer"]) for r in rows)
+    pts = sorted((r["nodes"], d) for r in rows
+                 if (d := _dense_minus_fused(r)) is not None)
     prev = None
     for n, diff in pts:
         if prev is None and diff >= 0:
@@ -216,6 +336,15 @@ def main(argv=None) -> int:
     ap.add_argument("--hidden", type=int, default=160,
                     help="message width (flagship hidden=160)")
     ap.add_argument("--iters", type=int, default=3)
+    ap.add_argument("--stack", action="store_true",
+                    help="time a whole stack of aggregates, forward and "
+                         "backward, batched (the leg the auto crossover is "
+                         "read from) in place of the single forward call, "
+                         "which on a chip times the host's round trip")
+    ap.add_argument("--layers", type=int, default=28,
+                    help="aggregates in the stack (flagship depth 28)")
+    ap.add_argument("--batch", type=int, default=8,
+                    help="windows under vmap in the stack (train batch 8)")
     args = ap.parse_args(argv)
 
     from nerrf_tpu.utils import enable_compilation_cache, fetch_value
@@ -227,6 +356,8 @@ def main(argv=None) -> int:
         jax.config.update("jax_platforms", args.platform)
     import jax.numpy as jnp
 
+    from nerrf_tpu.models.graphsage import (DENSE_ADJ_MAX_NODES,
+                                            GraphSAGEConfig)
     from nerrf_tpu.ops.segment import active_impls
 
     t0 = time.time()
@@ -236,8 +367,17 @@ def main(argv=None) -> int:
 
     rows = []
     for n in [int(b) for b in args.buckets.split(",")]:
-        bench_bucket(n, 2 * n, args.hidden, args.iters, dtype,
-                     fetch_value, rows)
+        if args.stack:
+            rows.append({
+                "nodes": n, "edges": 2 * n, "hidden": args.hidden,
+                "auto_resolves_to": GraphSAGEConfig().resolved_aggregation(n),
+                "stack": dict(layers=args.layers, batch=args.batch,
+                              **bench_stack(n, 2 * n, args.hidden,
+                                            args.layers, args.batch,
+                                            args.iters, dtype))})
+        else:
+            bench_bucket(n, 2 * n, args.hidden, args.iters, dtype,
+                         fetch_value, rows)
 
     report = {
         "backend": backend,
@@ -254,19 +394,24 @@ def main(argv=None) -> int:
         "routing": {
             "auto_rule": "tpu: dense_adj if nodes <= dense_adj_max_nodes "
                          "else fused; off-tpu: segment",
+            "dense_adj_max_nodes": DENSE_ADJ_MAX_NODES,
             "dense_adj_max_nodes_consumer":
-                "nerrf_tpu/models/graphsage.py DENSE_ADJ_MAX_NODES "
-                "(cites this artifact)",
+                "nerrf_tpu/models/graphsage.py DENSE_ADJ_MAX_NODES (cites "
+                "the chip's sweep, kernel_bench_v5e.json)",
             # the stamped crossover `nerrf tune` calibrates its routing
             # prior from (tune.costmodel.load_kernel_bench_crossover);
             # off-TPU it ranks XLA-CPU lowerings — directionally right
             # (O(N²) vs O(E)), degraded as evidence, superseded by a
             # chip re-run
             "measured_crossover_nodes": measured_crossover(rows),
-            "crossover_basis": "dense_adj vs fused ms_per_layer, "
-                               "log-interpolated between swept buckets",
+            "crossover_basis": (
+                "dense_adj vs fused stack_ms (forward + backward, batched, "
+                "per-forward build included)" if args.stack else
+                "dense_adj vs fused ms_per_layer") + ", log-interpolated "
+                "between swept buckets",
         },
-        "provenance": "python benchmarks/run_kernel_bench.py",
+        "provenance": "python benchmarks/run_kernel_bench.py "
+                      + " ".join(sys.argv[1:] if argv is None else argv),
         "wall_seconds": round(time.time() - t0, 1),
     }
     out = Path(args.out)
@@ -274,8 +419,9 @@ def main(argv=None) -> int:
     out.write_text(json.dumps(report, indent=2) + "\n")
     _log(f"wrote {out}")
     print(json.dumps({
-        "buckets": {r["nodes"]: {m: r["modes"][m]["ms_per_layer"]
-                                 for m in r["modes"]} for r in rows},
+        "buckets": {r["nodes"]: {m: v.get("ms_per_layer") for m, v in
+                                 (r.get("stack") or r)["modes"].items()}
+                    for r in rows},
         "degraded": report["degraded"],
     }))
     return 0

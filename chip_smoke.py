@@ -14,8 +14,9 @@ the entry points a user calls, at the full width of the flagship model
             1024n/2048e/160 and 4096n/8192e/160: forward and VJP, under vmap
   train     `nerrf_tpu.train.run` on an experiment that copies
             configs/joint-100h.json's `dataset` and `train.model` and shrinks
-            only the corpus and the step count: at 1024n/2048e (dense
-            adjacency) and, re-padded, at 4096n/8192e (fused Pallas)
+            only the corpus and the step count: at 1024n/2048e and,
+            re-padded, at 4096n/8192e (`auto` takes the dense adjacency at
+            both; the fused kernel is checked by the kernels phase)
   serve     `nerrf serve-detect` on the checkpoint the trainer wrote, a
             sparse and a dense seeded trace, over both buckets
   four      `train.run` on configs/multihost-online.json's dp x tp mesh —
@@ -355,7 +356,7 @@ def _train(exp: dict, work: Path, aot) -> dict:
 def train_phase(cfg: dict, work: Path, aot, idx: int) -> Path:
     """A few tens of steps at one bucket; returns the checkpoint dir."""
     bucket = cfg["train_buckets"][idx]
-    want_mode = ("dense_adj", "fused")[idx]
+    want_mode = "dense_adj"     # `auto` on a TPU, at both buckets
     exp = _experiment(cfg, "joint-100h", f"smoke-{bucket[0]}n", bucket,
                       mode=want_mode)
     report = _train(exp, work, aot)

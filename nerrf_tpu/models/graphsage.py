@@ -33,19 +33,23 @@ import jax.numpy as jnp
 from nerrf_tpu.graph.builder import AUX_VOCAB
 from nerrf_tpu.ops import gather_rows, sage_aggregate, segment_mean
 
-# Where `auto` stops paying for the dense adjacency on TPU.  At N ≤ this the
-# whole per-layer aggregate is one [N,N]@[N,H] MXU matmul and the O(N²·H)
-# work is cheap enough to win on launch overhead alone; past it the [N,N]
-# materialization (64 MB f32 at 4096) and the quadratic FLOPs lose to the
-# fused O(E) kernel, which also issues one kernel per layer.  Threshold from
-# benchmarks/results/kernel_bench_cpu.json (`python
-# benchmarks/run_kernel_bench.py` sweeps {segment, dense_adj, fused} ×
-# bucket ∈ {256, 1024, 4096}): dense_adj work grows 16× per bucket step
-# while fused grows ~2× (O(N²) vs O(E), measured per-layer times in the
-# artifact), crossing between the 1024 corpus bucket and the 4096 deployed
-# bucket.  Re-run the sweep on chip and move this if the measured crossover
-# disagrees.
-DENSE_ADJ_MAX_NODES = 1024
+# The largest node bucket `auto` sends to the dense adjacency on a TPU: the
+# largest at which it wins the whole train step and fits, as measured on a
+# v5e in benchmarks/results/kernel_bench_v5e.json (`python
+# benchmarks/run_kernel_bench.py --stack`: a stack of 28 aggregates, forward
+# and backward, vmap batch 8, H = 160, bf16, e = 2n, the [N,N] build inside).
+# No crossover in time was found: dense_adj wins by 30x / 25x / 12x / 10x
+# at 1024 / 2048 / 4096 / 8192 (34 ms against 408 for the stack at 4096; the
+# whole step, PERF.md §6 PR 27: 160 ms against 549 at 4096, 459 against
+# 1,522 at 8192).  The fused kernel's grid is 2 x N/128 x E/128 steps, mostly
+# empty, so its time too grows faster than its O(E) work (2.3-2.9x a
+# doubling of N against the matmul's 2.9-5.2x): the gap narrows and does
+# not close.  Memory sets the bound: at 8192 the step holds 9.1 GB of 16 (an
+# [8,N,N] f32 scatter, its transpose and the bf16 adjacency kept for the
+# backward); at 16384 the compiler refuses the batch-8 stack (20.0 GB), and
+# refuses the fused kernel too (VMEM), so only `segment` runs there.  Move
+# this only with a new sweep.
+DENSE_ADJ_MAX_NODES = 8192
 
 
 def fused_edge_views(edge_src, edge_dst, w32, num_nodes):
@@ -82,6 +86,19 @@ def fused_edge_views(edge_src, edge_dst, w32, num_nodes):
     return edges, d_fwd, d_rev, inv_f, inv_r
 
 
+def dense_adjacency(edge_src, edge_dst, w32, inv_f, inv_r, num_nodes, dtype):
+    """The `dense_adj` route's per-forward build (shared, like
+    `fused_edge_views`, with benchmarks/run_kernel_bench.py, whose sweep
+    sets DENSE_ADJ_MAX_NODES): one [E]→[N·N] scatter builds the raw
+    weighted adjacency, whose form normalized in f32 by both directions'
+    inverses and cast to ``dtype`` serves every layer as one
+    [N,N]@[N,H] matmul."""
+    n = num_nodes
+    flat = edge_dst.astype(jnp.int32) * n + edge_src.astype(jnp.int32)
+    w_raw = jax.ops.segment_sum(w32, flat, num_segments=n * n).reshape(n, n)
+    return (w_raw * inv_f[:, None] + w_raw.T * inv_r[:, None]).astype(dtype)
+
+
 @dataclasses.dataclass(frozen=True)
 class GraphSAGEConfig:
     hidden: int = 160
@@ -89,18 +106,19 @@ class GraphSAGEConfig:
     dropout: float = 0.1
     dtype: Any = jnp.bfloat16
     # Three parity-tested aggregation shapes (docs/kernel-paths.md):
-    # "fused": ONE Pallas kernel per layer, O(E) work — blocked-CSR bands
-    # over the builder's dst-sorted edges plus the per-window src-sorted
-    # view, gather + weight + scatter-accumulate fused in VMEM
+    # "fused": ONE Pallas kernel per layer, O(E) useful work — blocked-CSR
+    # bands over the builder's dst-sorted edges plus the per-window
+    # src-sorted view, gather + weight + scatter-accumulate fused in VMEM
     # (ops.sage_aggregate; XLA composition with identical semantics
     # off-TPU).  "dense_adj": ONE [N,N]@[N,H] matmul per layer against a
-    # normalized adjacency built once per forward — pure MXU work, O(N²·H);
-    # r5 measured ~0.27 ms fixed cost per sequential kernel on the chip
-    # runtime, and replacing the segment path's ~6 kernels/layer with 1
-    # took 163→50 ms/step flagship.  "segment": per-layer gather +
-    # banded-segment-mean — the portable parity oracle.  "auto" (default):
-    # on TPU, dense_adj up to DENSE_ADJ_MAX_NODES and fused above it;
-    # segment elsewhere.
+    # normalized adjacency built once per forward — pure MXU work, O(N²·H),
+    # and on a v5e the fastest of the three at every bucket it fits
+    # (0.04-2.7 ms a layer, forward + backward, batch 8, from 1024 to 8192
+    # nodes, against 2.5-42 for "fused" and 4.7-280 for "segment":
+    # benchmarks/results/kernel_bench_v5e.json).  "segment": per-layer
+    # gather + banded-segment-mean — the portable parity oracle.  "auto"
+    # (default): on TPU, dense_adj up to DENSE_ADJ_MAX_NODES and fused above
+    # it; segment elsewhere.
     aggregation: str = "auto"
     # Per-rung kernel routing table fitted by `nerrf tune` (docs/tuning.md):
     # sorted ((max_nodes, mode), ...) pairs consulted BEFORE the auto
@@ -142,11 +160,11 @@ class GraphSAGEConfig:
         rule (the model and the bench's kernel_path attribution both call
         this, so the artifact cannot drift from the compute).  ``num_nodes``
         is the padded node bucket: a tuned per-rung routing table (see
-        ``routing``) wins first; otherwise on TPU, `auto` keeps the dense
-        adjacency where O(N²) MXU work still wins (≤ DENSE_ADJ_MAX_NODES,
-        measured — see the constant) and routes bigger buckets to the
-        fused O(E) kernel; with no bucket given it assumes the
-        large-bucket answer."""
+        ``routing``) wins first; otherwise on TPU, `auto` takes the dense
+        adjacency wherever it was measured to win the step and fit
+        (≤ DENSE_ADJ_MAX_NODES — see the constant) and routes bigger
+        buckets to the fused O(E) kernel; with no bucket given it assumes
+        the large-bucket answer."""
         if self.aggregation != "auto":
             if self.aggregation not in ("fused", "dense_adj", "segment"):
                 raise ValueError(
@@ -305,13 +323,8 @@ class GraphSAGET(nn.Module):
                 s_f = (d_fwd * inv_f).astype(dt)
                 s_r = (d_rev * inv_r).astype(dt)
             if agg_mode == "dense_adj":
-                # One [E]→[N·N] scatter builds the raw weighted adjacency whose
-                # normalized form serves every layer as one [N,N]@[N,H] matmul.
-                flat = edge_dst.astype(jnp.int32) * n + edge_src.astype(jnp.int32)
-                w_raw = jax.ops.segment_sum(
-                    w32, flat, num_segments=n * n).reshape(n, n)
-                adj = (w_raw * inv_f[:, None]
-                       + w_raw.T * inv_r[:, None]).astype(dt)
+                adj = dense_adjacency(edge_src, edge_dst, w32, inv_f, inv_r,
+                                      n, dt)
                 dense_view = (adj, c_sum, s_f, s_r)
             elif agg_mode == "fused":
                 fused_view = (edges, c_sum, s_f, s_r)

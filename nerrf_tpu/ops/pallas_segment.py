@@ -374,10 +374,24 @@ def _gather_call(
 # window, and gather/scatter launch overhead
 # is exactly what dominates TPU GNN runtimes in the accelerator benchmarking
 # literature (arXiv:2210.12247).  The dense_adj alternative is one matmul
-# per layer but materializes an [N, N] adjacency: 64 MB and O(N²·H) MXU work
-# at the deployed 4096-node bucket, for graphs with E ≪ N².
+# per layer against an [N, N] adjacency, O(N²·H) MXU work for graphs with
+# E ≪ N².
 #
-# This kernel is the third shape: ONE `pallas_call` per layer, O(E·H) work.
+# Where it stands on the chip (benchmarks/results/kernel_bench_v5e.json, a
+# v5e: 28 layers forward + backward, vmap batch 8, H = 160, e = 2n): this
+# kernel is 1.8-6.7x faster than the segment path and 10-30x SLOWER than
+# dense_adj at every bucket from 1024 to 8192 nodes (2.5 / 5.8 / 14.4 / 41.7
+# ms a layer against 0.04 / 0.13 / 0.70 / 2.7), so `auto` takes the matmul
+# wherever its adjacency fits (models/graphsage.py DENSE_ADJ_MAX_NODES).
+# The grid below is (f_tiles, n_tiles, e_tiles): with e = 2n its step count
+# grows as N² and the band test lets about 3 of every 64 steps at 4096 do
+# work; the measured time grows 2.3-2.9x a doubling of N, between the O(E)
+# useful work and the O(N²) grid.  At 16384 nodes Mosaic refuses the kernel
+# (16.44 MB of scoped VMEM against 16), where dense_adj at batch 8 no longer
+# fits HBM either.
+#
+# This kernel is the third shape: ONE `pallas_call` per layer, O(E·H) useful
+# work.
 # Both directions of the bidirectional weighted-mean aggregate
 #
 #     out[n] = Σ_{e: dst(e)=n} ŵf(e)·msg[src(e)] + Σ_{e: src(e)=n} ŵr(e)·msg[dst(e)]

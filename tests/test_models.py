@@ -310,3 +310,105 @@ def test_dense_adj_aggregate_is_scoped_like_the_fused_route():
         own = [p for p in paths if f"/{name}/" in p]
         assert own and not any("sage_aggregate" in p or "gnn_layer_" in p
                                for p in own), name
+
+
+# -- the `auto` rule: a function of the backend and the padded node bucket ----
+
+
+def _past_crossover():
+    from nerrf_tpu.models.graphsage import DENSE_ADJ_MAX_NODES
+
+    return 2 * DENSE_ADJ_MAX_NODES  # the ladder's next power-of-two rung
+
+
+@pytest.mark.parametrize("nodes, want", [
+    (256, "dense_adj"), (1024, "dense_adj"), (2048, "dense_adj"),
+    (4096, "dense_adj"), ("past", "fused"), (None, "fused")])
+def test_auto_routes_every_shipped_bucket_to_the_matmul_on_a_tpu(
+        monkeypatch, nodes, want):
+    """Every rung the repo ships (`pipeline._GRAPH_WARMUP_RUNGS`, both
+    experiment files) lies under the crossover measured on the chip
+    (benchmarks/results/kernel_bench_v5e.json); the first bucket past it,
+    and a caller that names no bucket, get the fused kernel."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    if nodes == "past":
+        nodes = _past_crossover()
+    assert GraphSAGEConfig().resolved_aggregation(nodes) == want
+
+
+@pytest.mark.parametrize("nodes", [256, 4096, "past"])
+def test_auto_is_segment_off_a_tpu(monkeypatch, nodes):
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    if nodes == "past":
+        nodes = _past_crossover()
+    assert GraphSAGEConfig().resolved_aggregation(nodes) == "segment"
+
+
+@pytest.mark.parametrize("backend", ["tpu", "cpu"])
+def test_routing_table_and_explicit_aggregation_outrank_the_auto_rule(
+        monkeypatch, backend):
+    """Precedence is unchanged: an explicit `aggregation` first, then the
+    `nerrf tune` table (smallest covering rung), then the constant."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    table = ((1024, "segment"), (4096, "fused"))
+    tuned = GraphSAGEConfig(routing=table)
+    assert tuned.resolved_aggregation(512) == "segment"
+    assert tuned.resolved_aggregation(4096) == "fused"
+    past_table = {"tpu": "dense_adj", "cpu": "segment"}[backend]
+    assert GraphSAGEConfig(routing=((1024, "segment"),)
+                           ).resolved_aggregation(2048) == past_table
+    for mode in ("fused", "dense_adj", "segment"):
+        cfg = GraphSAGEConfig(aggregation=mode, routing=table)
+        for n in (256, 4096, _past_crossover(), None):
+            assert cfg.resolved_aggregation(n) == mode
+
+
+def test_dense_adj_matches_segment_at_the_deployed_bucket():
+    """The route `auto` now takes at 4096n / 8192e against the portable
+    oracle (XLA on this CPU), in f32, with about half of the edge slots
+    masked as a padded window has them: the logits, and the loss's gradient
+    with respect to every parameter and to the node features every layer's
+    `msg` is made from."""
+    import dataclasses
+
+    from nerrf_tpu.graph import EDGE_FEATURE_DIM, NODE_FEATURE_DIM
+
+    n, e = 4096, 8192
+    rng = np.random.default_rng(27)
+    edge_feat = rng.normal(size=(e, EDGE_FEATURE_DIM)).astype(np.float32)
+    edge_feat[:, 12] = rng.uniform(0.0, 1.0, e)  # the causality weight
+    edge_mask = np.arange(e) < e // 2 + 37
+    node_mask = np.arange(n) < 3020  # joint-dense's mean of real nodes
+    args = (rng.normal(size=(n, NODE_FEATURE_DIM)).astype(np.float32),
+            rng.integers(0, 4, n).astype(np.int32),
+            rng.integers(0, 8, n).astype(np.int32), node_mask,
+            rng.integers(0, 3020, e).astype(np.int32),
+            np.sort(rng.integers(0, 3020, e)).astype(np.int32),
+            edge_feat, edge_mask)
+    cfg = GraphSAGEConfig(hidden=16, num_layers=2, dropout=0.0,
+                          dtype=jnp.float32, aggregation="segment")
+    m_s = GraphSAGET(cfg)
+    m_d = GraphSAGET(dataclasses.replace(cfg, aggregation="dense_adj"))
+    params = m_s.init(jax.random.PRNGKey(2), *args)["params"]
+
+    def run(model):
+        def loss(p, node_feat):
+            out = model.apply({"params": p}, node_feat, *args[1:])
+            live = (jnp.sum(jnp.where(node_mask, out["node_logit"], 0.0) ** 2)
+                    + jnp.sum(jnp.where(edge_mask, out["edge_logit"], 0.0)
+                              ** 2))
+            return live / n, out
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1),
+                                          has_aux=True))(params, args[0])
+
+    (_, out_s), grads_s = run(m_s)
+    (_, out_d), grads_d = run(m_d)
+    for k in ("edge_logit", "node_logit"):
+        err = np.max(np.abs(np.asarray(out_d[k]) - np.asarray(out_s[k])))
+        assert err < 1e-3, (k, err)
+    errs = jax.tree_util.tree_map(
+        lambda a, b: float(jnp.max(jnp.abs(a - b))), grads_s, grads_d)
+    assert max(jax.tree_util.tree_leaves(errs)) < 1e-3, errs
+    norms = [float(jnp.max(jnp.abs(g)))
+             for g in jax.tree_util.tree_leaves(grads_s)]
+    assert min(norms) > 0.0  # every leaf and the features get a gradient

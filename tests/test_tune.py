@@ -83,7 +83,13 @@ def test_golden_corpus_deterministic_artifact():
     exp = art["expected"]
     assert (exp["tuned_device_seconds_per_window"]
             < exp["static_device_seconds_per_window"])
-    assert exp["improvement"] == pytest.approx(0.2478, abs=2e-3)
+    # no kernel-bench artifact here: the crossover prior is the authored
+    # constant, and the pinned improvement moves with it (0.2478 when it
+    # was 1024)
+    from nerrf_tpu.models.graphsage import DENSE_ADJ_MAX_NODES
+    assert art["fit"]["provenance"]["kernel_bench"]["nodes"] == float(
+        DENSE_ADJ_MAX_NODES) == 8192.0
+    assert exp["improvement"] == pytest.approx(0.2517, abs=2e-3)
     # the measured rung stays evidence-tier "measured"; extrapolated
     # rungs say so
     assert art["fit"]["rung_sources"]["1024n/2048e/128s"] == "measured"
