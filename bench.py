@@ -292,25 +292,29 @@ def main() -> None:
     # --- long-context leg: StreamNet over raw 4096-event streams ------------
     from nerrf_tpu.data import build_streams
     from nerrf_tpu.models import StreamConfig, StreamNet
-    from nerrf_tpu.parallel import MeshConfig, make_mesh, make_stream_train_step
+    from nerrf_tpu.train.stream import init_stream_state, make_stream_step
 
-    mesh1 = make_mesh(MeshConfig(dp=1, tp=1, sp=1), devices=jax.devices()[:1])
+    # the trainer's own stream step (`train/stream.py`: make_tx, the
+    # resident scheduled step), every segment in each step's batch
     sb = build_streams(corpus[:6], max_len=4096)
-    smodel = StreamNet(StreamConfig(), mesh=mesh1)
-    init_fn, step_fn, place = make_stream_train_step(smodel, mesh1)
-    with mesh1:
-        placed = place(sb.arrays())
-        sstate = init_fn(jax.random.PRNGKey(2), placed)
-        t0 = time.perf_counter()
-        sstate, sloss, srng = step_fn(sstate, placed, jax.random.PRNGKey(3))
-        fetch(sloss)
-        compile_seconds["stream_step"] = round(time.perf_counter() - t0, 1)
-        t0 = time.perf_counter()
-        s_steps = min(50, max(3, bench_steps // 4))
-        for _ in range(s_steps):
-            sstate, sloss, srng = step_fn(sstate, placed, srng)
-        fetch(sloss)
-        dt = time.perf_counter() - t0
+    smodel = StreamNet(StreamConfig())
+    s_steps = min(50, max(3, bench_steps // 4))
+    scfg = TrainConfig(batch_size=len(sb), num_steps=s_steps + 1,
+                       learning_rate=1e-3, warmup_steps=2, seed=0)
+    placed = sb.arrays()
+    sstate = init_stream_state(smodel, scfg, placed, jax.random.PRNGKey(2))
+    step_fn = make_stream_step(
+        smodel, scfg, placed,
+        np.tile(np.arange(len(sb), dtype=np.int32), (s_steps + 1, 1)))
+    t0 = time.perf_counter()
+    sstate, sloss, _, srng = step_fn(sstate, jax.random.PRNGKey(3))
+    fetch(sloss)
+    compile_seconds["stream_step"] = round(time.perf_counter() - t0, 1)
+    t0 = time.perf_counter()
+    for _ in range(s_steps):
+        sstate, sloss, _, srng = step_fn(sstate, srng)
+    fetch(sloss)
+    dt = time.perf_counter() - t0
     ev = placed["feat"].shape[0] * placed["feat"].shape[1]
     stream_events_per_sec = ev * s_steps / dt
     log(f"[bench] stream: {placed['feat'].shape[0]}x{placed['feat'].shape[1]} "

@@ -113,7 +113,7 @@ def main(argv=None) -> int:
 
     from nerrf_tpu.data import build_streams
     from nerrf_tpu.models import StreamConfig, StreamNet
-    from nerrf_tpu.parallel import MeshConfig, make_mesh, make_stream_train_step
+    from nerrf_tpu.parallel import MeshConfig, make_mesh
     from nerrf_tpu.train.metrics import best_f1, f1_at_threshold, roc_auc
 
     t0 = time.time()
@@ -131,25 +131,24 @@ def main(argv=None) -> int:
          f"{len(eval_sb)} eval segments of "
          f"{args.max_len} events (train positive rate {pos:.3f})")
 
+    # the trainer's own stream entry (`train.stream.train_stream`: make_tx,
+    # the resident scheduled step, the train_step_call span): the same one
+    # `python -m nerrf_tpu.train.run` takes for a stream experiment
+    from nerrf_tpu.train.loop import TrainConfig
+    from nerrf_tpu.train.stream import train_stream
+
     mesh = make_mesh(MeshConfig(dp=1, tp=1, sp=1), devices=jax.devices()[:1])
     cfg = StreamConfig()
     model = StreamNet(cfg, mesh=mesh)
-    init_fn, step_fn, place = make_stream_train_step(model, mesh)
-    rng = jax.random.PRNGKey(args.seed)
-    arrays = train_sb.arrays()
-    order = np.random.default_rng(args.seed)
     with mesh:
-        idx0 = order.choice(len(train_sb), size=args.batch,
-                            replace=len(train_sb) < args.batch)
-        placed = place({k: v[idx0] for k, v in arrays.items()})
-        state = init_fn(jax.random.PRNGKey(1), placed)
         t_train = time.perf_counter()
-        for i in range(args.steps):
-            idx = order.choice(len(train_sb), size=args.batch,
-                               replace=len(train_sb) < args.batch)
-            batch = place({k: v[idx] for k, v in arrays.items()})
-            state, loss, rng = step_fn(state, batch, rng)
-        sync_result(loss)
+        res = train_stream(
+            train_sb.arrays(), cfg,
+            TrainConfig(batch_size=args.batch, num_steps=args.steps,
+                        learning_rate=1e-3, warmup_steps=min(20, args.steps // 4),
+                        weight_decay=1e-4, seed=args.seed,
+                        eval_every=max(args.steps // 4, 1)), log=_log)
+        state, loss = res.state, res.metrics["final_loss"]
         train_secs = time.perf_counter() - t_train
         _log(f"trained {args.steps} steps in {train_secs:.1f}s "
              f"(final loss {float(loss):.4f})")
@@ -167,7 +166,7 @@ def main(argv=None) -> int:
                 idx = np.arange(i, min(i + args.batch, len(sb)))
                 # fixed batch shape (wrap tail) → one compile
                 full = np.resize(idx, args.batch)
-                batch = place({k: v[full] for k, v in arrs.items()})
+                batch = {k: jnp.asarray(v[full]) for k, v in arrs.items()}
                 out = jax.device_get(fwd(state.params, batch))
                 logits = out["event_logits"][: len(idx)]
                 for j in range(len(idx)):

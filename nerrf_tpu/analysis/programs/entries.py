@@ -14,6 +14,7 @@ independent, and micro tensors keep each abstract trace ~1 s.
 
 from __future__ import annotations
 
+import functools
 from typing import List
 
 from nerrf_tpu.analysis.programs.abstract import (
@@ -31,6 +32,7 @@ SERVE_SERVICE = "nerrf_tpu/serve/service.py"
 RING = "nerrf_tpu/parallel/ring.py"
 PARALLEL_TRAIN = "nerrf_tpu/parallel/train.py"
 RESPOND_PLANNER = "nerrf_tpu/respond/planner.py"
+TRAIN_STREAM = "nerrf_tpu/train/stream.py"
 
 
 def _micro_ds_cfg():
@@ -82,6 +84,47 @@ def _flat_step_args(cfg):
             aval((2,), np.uint32))
 
 
+@functools.lru_cache(maxsize=None)
+def _stream_step_entry(window: int = 4):
+    """(flat jit fn, avals, key extra) of the stream pretrainer's scheduled
+    resident step at micro scale: one windowed attention layer, two packed
+    sequences of 16 tokens resident — the function `cache_train_step`
+    serializes (`train.stream.make_stream_step`).  One layer, not the six
+    kinds: the contracts are about the step's boundary (donation, key
+    material), and tracing a scan layer three times cost the deep pass 6 s
+    of its 30 s budget (26.6 s against 20.3 s, the parent's 20.0)."""
+    import jax
+    import numpy as np
+
+    from nerrf_tpu.models.stream import StreamConfig, StreamNet
+    from nerrf_tpu.train.loop import (TrainConfig, make_train_step_scheduled,
+                                      make_tx, step_key_extra)
+    from nerrf_tpu.train.stream import make_stream_loss_fn, stream_key_extra
+
+    scfg = StreamConfig(dim=8, num_heads=2, num_kv_heads=2, head_dim=4,
+                        mlp_dim=16, window=window, d_state=2, dt_rank=2,
+                        num_layers=1, kinds=("swa",), vocab_size=32,
+                        dropout=0.0, remat=False)
+    cfg = TrainConfig(batch_size=1, num_steps=4, warmup_steps=1)
+    model = StreamNet(scfg)
+    data = {"tokens": np.zeros((2, 16), np.int32),
+            "segments": np.ones((2, 16), np.int32)}
+    step = make_train_step_scheduled(
+        model, cfg, data, np.zeros((4, 1), np.int32),
+        loss_fn=make_stream_loss_fn(model))
+    tok = aval((1, 16), np.int32)
+    params = jax.eval_shape(
+        lambda r, t, g: model.init(r, t, g)["params"],
+        aval((2,), np.uint32), tok, tok)
+    args = (params, jax.eval_shape(make_tx(cfg).init, params),
+            aval((), np.int32), aval((2,), np.uint32),
+            {k: aval(v.shape, v.dtype) for k, v in data.items()},
+            aval((4, 1), np.int32))
+    extra = {**step_key_extra(cfg, "stream_step_scheduled"),
+             **stream_key_extra(scfg)}
+    return step.flat_jit_fn, args, extra
+
+
 def donation_entries() -> List[DonationEntry]:
     cfg = micro_train_config()
 
@@ -106,6 +149,11 @@ def donation_entries() -> List[DonationEntry]:
         # so the contract is exactly zero aliased inputs
         DonationEntry(name="serve_eval", path=TRAIN_LOOP,
                       build=build_eval, donate=(), must_donate=()),
+        # the stream pretrainer's step: 11 GB of state at the published
+        # widths, donated or the step does not fit one chip
+        DonationEntry(name="stream_step_scheduled", path=TRAIN_STREAM,
+                      build=lambda: _stream_step_entry()[:2],
+                      donate=(0, 1), must_donate=(0, 1)),
     ]
 
 
@@ -229,7 +277,14 @@ def cache_key_entries() -> List[CacheKeyEntry]:
     r_base, r_base_extra = respond_variant(_micro_mcts(), 64)
     r_puct, r_puct_extra = respond_variant(_micro_mcts(c_puct=2.5), 64)
     r_horizon, r_horizon_extra = respond_variant(_micro_mcts(), 32)
+    # the attention window folds into the masks as a literal: same avals,
+    # another program — only `stream_key_extra` tells them apart
+    st_base, st_window = _stream_step_entry(4), _stream_step_entry(8)
     return [
+        CacheKeyEntry(
+            name="stream_step_scheduled", path=TRAIN_STREAM,
+            variants=[("base", lambda: st_base[:2], st_base[2]),
+                      ("window", lambda: st_window[:2], st_window[2])]),
         CacheKeyEntry(
             name="train_step_flat", path=TRAIN_LOOP,
             variants=[("base", t_base, t_base_extra),

@@ -33,12 +33,13 @@ from typing import Any, Dict, Optional
 
 import numpy as np
 
+from nerrf_tpu.data.stream import PackConfig
 from nerrf_tpu.data.synth import SimConfig
 from nerrf_tpu.graph.builder import GraphConfig
 from nerrf_tpu.models.graphsage import GraphSAGEConfig
 from nerrf_tpu.models.joint import JointConfig
 from nerrf_tpu.models.lstm import LSTMConfig
-from nerrf_tpu.models.stream import StreamConfig
+from nerrf_tpu.models.stream import StreamConfig, layer_kinds
 from nerrf_tpu.parallel.mesh import MeshConfig
 from nerrf_tpu.planner.mcts import MCTSConfig
 from nerrf_tpu.train.data import DatasetConfig
@@ -144,6 +145,10 @@ class Experiment:
     mesh: MeshConfig = MeshConfig()
     mcts: MCTSConfig = MCTSConfig()
     stream: Optional[StreamConfig] = None
+    # How a stream experiment's traces become packed token sequences.  With
+    # ``stream.vocab_size`` > 0 the runner trains the stream encoder on the
+    # next event token (train/stream.py) instead of NerrfNet.
+    stream_data: Optional[PackConfig] = None
     # Disk-sharded corpus (train/corpus.py) for runs whose window tensors
     # exceed RAM/HBM — when set and generated, run.py takes the
     # shard-rotation path instead of in-memory `corpus` generation.
@@ -293,7 +298,32 @@ def _experiments() -> Dict[str, Experiment]:
         mcts=MCTSConfig(num_simulations=1000, batch_size=64),
         stream=StreamConfig(),
     )
-    return {e.name: e for e in (toy, lstm, joint, dense, mcts, multihost)}
+    stream_lm = Experiment(
+        name="stream-phi4-mini-flash",
+        description=(
+            "Stream-encoder pretraining on packed 8k event-token sequences: "
+            "the decoder-hybrid-decoder stack (Mamba + window + full "
+            "differential attention + gated memory units) of "
+            "Phi-4-mini-flash-reasoning at its published widths, six layers "
+            "(one period of each half, both hand-downs) and an eighth of the "
+            "tied vocabulary: what one chip of an 8-chip slice holds "
+            "(docs/stream-backbone.md; chipbench/configs/phi4-mini-flash.json)"
+        ),
+        corpus=CorpusConfig(num_traces=4, attack_fraction=0.5,
+                            duration_sec=180.0, num_target_files=45,
+                            benign_rate_hz=550.0, eval_fraction=0.0),
+        train=TrainConfig(batch_size=1, num_steps=2000, learning_rate=3e-4,
+                          warmup_steps=50, weight_decay=0.1, eval_every=20),
+        stream=StreamConfig(
+            dim=2560, num_heads=40, num_kv_heads=20, head_dim=64,
+            mlp_dim=10240, window=512, d_state=16, d_conv=4, expand=2,
+            dt_rank=160, num_layers=6, kinds=layer_kinds(6),
+            published_layers=(0, 1, 16, 17, 18, 19), vocab_size=25008,
+            dropout=0.0),
+        stream_data=PackConfig(),
+    )
+    return {e.name: e for e in (toy, lstm, joint, dense, mcts, multihost,
+                                stream_lm)}
 
 
 EXPERIMENTS: Dict[str, Experiment] = _experiments()

@@ -6,7 +6,14 @@ from nerrf_tpu.data.loaders import (
 )
 from nerrf_tpu.data.synth import SimConfig, simulate_trace, make_corpus
 from nerrf_tpu.data.labels import derive_event_labels
-from nerrf_tpu.data.stream import StreamBatch, build_stream, build_streams, STREAM_FEATURE_DIM
+from nerrf_tpu.data.stream import (
+    STREAM_FEATURE_DIM,
+    PackConfig,
+    StreamBatch,
+    build_packed_streams,
+    build_stream,
+    build_streams,
+)
 
 __all__ = [
     "GroundTruth",
@@ -19,6 +26,8 @@ __all__ = [
     "derive_event_labels",
     "StreamBatch",
     "build_stream",
+    "build_packed_streams",
+    "PackConfig",
     "build_streams",
     "STREAM_FEATURE_DIM",
 ]

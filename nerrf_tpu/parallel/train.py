@@ -205,21 +205,24 @@ def stream_shardings(mesh: Mesh) -> Dict[str, "jax.sharding.NamedSharding"]:
     }
 
 
-def make_stream_train_step(model, mesh: Mesh, learning_rate: float = 1e-3):
-    """(init_fn, step_fn) for StreamNet over a dp×sp mesh.
+def make_stream_train_step(model, mesh: Mesh,
+                           cfg: Optional["TrainConfig"] = None):
+    """(init_fn, step_fn, place) for StreamNet over a dp×sp mesh: the
+    sharded twin of `train.stream`'s step, with the same loss
+    (`make_stream_loss_fn`), the same grad/update body and the trainer's
+    optimizer (`make_tx(cfg)`; ``cfg`` None is `TrainConfig()`).
 
     ``model`` must be a StreamNet constructed with this mesh so its attention
     layers run the sp ring.  Gradients all-reduce over dp×sp automatically
     (GSPMD); the only hand-written collective in the whole step is the
     ppermute inside ring attention.
     """
-    import optax
-    from flax.training import train_state
+    from nerrf_tpu.train.stream import init_stream_state, make_stream_loss_fn
 
-    from nerrf_tpu.models.stream import stream_loss
-
+    loop = _loop()
+    cfg = cfg if cfg is not None else loop.TrainConfig()
     sh = stream_shardings(mesh)
-    tx = optax.adamw(learning_rate)
+    loss_fn = make_stream_loss_fn(model)
 
     def place(batch):
         # On a 1-device mesh, inputs committed to a NamedSharding push every
@@ -233,26 +236,11 @@ def make_stream_train_step(model, mesh: Mesh, learning_rate: float = 1e-3):
     def init_fn(rng, placed_batch):
         """``placed_batch`` must come from ``place`` — init reuses it, so the
         host→device transfer happens once per batch, not once per caller."""
-        params = jax.jit(
-            lambda r: model.init(
-                r, placed_batch["feat"], placed_batch["mask"], deterministic=True
-            )["params"]
-        )(rng)
-        return train_state.TrainState.create(
-            apply_fn=model.apply, params=params, tx=tx
-        )
-
-    def loss_fn(params, batch, dropout_rng):
-        out = model.apply(
-            {"params": params}, batch["feat"], batch["mask"],
-            deterministic=False, rngs={"dropout": dropout_rng},
-        )
-        return stream_loss(out, batch["label"], batch["mask"])
+        return init_stream_state(model, cfg, placed_batch, rng)
 
     @partial(jax.jit, donate_argnums=(0,))
     def step_fn(state, batch, rng):
-        rng, dropout_rng = jax.random.split(rng)
-        loss, grads = jax.value_and_grad(loss_fn)(state.params, batch, dropout_rng)
-        return state.apply_gradients(grads=grads), loss, rng
+        state, loss, _aux, rng = loop._step_body(loss_fn, state, batch, rng)
+        return state, loss, rng
 
     return init_fn, step_fn, place

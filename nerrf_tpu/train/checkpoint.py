@@ -246,12 +246,13 @@ def save_stream_checkpoint(path: str | Path, params, cfg,
     producer in this repo writes)."""
     import jax.numpy as jnp
 
+    from nerrf_tpu.config import to_dict
     from nerrf_tpu.data.stream import STREAM_FEATURE_DIM
+
     meta = {
-        "stream": {"dim": cfg.dim, "num_heads": cfg.num_heads,
-                   "num_layers": cfg.num_layers, "mlp_mult": cfg.mlp_mult,
-                   "dropout": cfg.dropout, "remat": cfg.remat,
-                   "dtype": jnp.dtype(cfg.dtype).name},
+        # every field of the configuration, the layer kinds and the hybrid
+        # widths among them
+        "stream": to_dict(cfg),
         "features": {"stream": STREAM_FEATURE_DIM},
         "schema_version": SCHEMA_VERSION,
     }

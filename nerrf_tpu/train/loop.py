@@ -243,7 +243,8 @@ def make_flat_train_step(model: NerrfNet, cfg: TrainConfig):
 
 
 def cache_train_step(compile_cache, train_step, model: NerrfNet,
-                     cfg: TrainConfig, resident_flavor: str):
+                     cfg: TrainConfig, resident_flavor: str,
+                     extra: Optional[dict] = None):
     """Route a (batch, rng)-shaped train step through the persistent
     compile cache — the ONE wiring point for every loop that swaps its
     jitted step for a `CachedTrainStep` (a key-material change here
@@ -251,12 +252,14 @@ def cache_train_step(compile_cache, train_step, model: NerrfNet,
     Resident steps expose their cacheable twin as ``flat_jit_fn`` with the
     device-resident arrays as the bound ``tail``; plain steps get a fresh
     `make_flat_train_step`.  ``resident_flavor`` names the resident
-    program in the cache key (scheduled vs by-idx lower different HLO)."""
+    program in the cache key (scheduled vs by-idx lower different HLO);
+    ``extra`` is key material the training config does not hold (the stream
+    encoder's own configuration)."""
     flat = getattr(train_step, "flat_jit_fn", None)
     if flat is not None:
         return CachedTrainStep(
             compile_cache, flat, program="train_step",
-            extra=step_key_extra(cfg, resident_flavor),
+            extra={**step_key_extra(cfg, resident_flavor), **(extra or {})},
             tail=train_step.tail)
     return CachedTrainStep(
         compile_cache, make_flat_train_step(model, cfg),
@@ -324,13 +327,15 @@ def device_put_chunked(arrays, max_bytes: int = 64 << 20, block: bool = False,
 
 
 def make_train_step_scheduled(model: NerrfNet, cfg: TrainConfig, arrays,
-                              idx_table: np.ndarray):
+                              idx_table: np.ndarray, loss_fn=None):
     """Fully device-driven training: the HBM-resident dataset *and* the whole
     batch-index schedule live on device, and each step picks its row with
     ``state.step`` — so a step issues zero host→device transfers and back-to-
     back steps pipeline instead of syncing on per-step input uploads.
-    ``idx_table`` is [num_steps, batch] int32."""
-    _, make_scheduled, _ = _make_resident_steps(model, cfg, arrays)
+    ``idx_table`` is [num_steps, batch] int32.  ``loss_fn(params, batch,
+    dropout_rng) -> (loss, aux)`` replaces NerrfNet's joint loss (the stream
+    encoder trains through this same step: `train/stream.py`)."""
+    _, make_scheduled, _ = _make_resident_steps(model, cfg, arrays, loss_fn)
     return make_scheduled(idx_table)
 
 
@@ -344,10 +349,11 @@ def make_train_superstep(model: NerrfNet, cfg: TrainConfig, arrays,
     return make_super(idx_table, steps_per_call)
 
 
-def _make_resident_steps(model: NerrfNet, cfg: TrainConfig, arrays):
+def _make_resident_steps(model: NerrfNet, cfg: TrainConfig, arrays,
+                         loss_fn=None):
     """One factory for both resident flavors, sharing placement, the gather,
     and the step body (so fixes to any of them apply to both)."""
-    loss_fn = make_loss_fn(model, cfg)
+    loss_fn = loss_fn or make_loss_fn(model, cfg)
     # async: the chunked upload overlaps the caller's jit tracing/compile
     dev = device_put_chunked(arrays)
 

@@ -117,6 +117,10 @@ def _run_experiment(name_or_path, out_dir, num_steps, ckpt_every, sharded,
     corpus_extra = {}
     n_dev = len(jax.devices())
 
+    # --- stream pretraining: event tokens in packed documents ---------------
+    if exp.stream is not None and exp.stream.vocab_size:
+        return _run_stream_experiment(exp, cfg, out, n_dev, compile_cache, t0)
+
     # --- disk-sharded corpus path (the true 100 h run) ----------------------
     if exp.corpus_dir:
         cdir = Path(exp.corpus_dir)
@@ -298,6 +302,49 @@ def _run_experiment(name_or_path, out_dir, num_steps, ckpt_every, sharded,
     return _finish(exp, cfg, out, n_dev, metrics, steps_per_sec, params, t0,
                    history, corpus_extra, calibrate=calibrate,
                    publish_to=publish_to, lineage=lineage)
+
+
+def _run_stream_experiment(exp, cfg, out: Path, n_dev, compile_cache,
+                           t0) -> dict:
+    """The stream encoder's pretraining (docs/stream-backbone.md): corpus ->
+    event tokens -> packed documents, resident on the device -> the
+    trainer's cached, traced, scheduled step (`train/stream.py`)."""
+    import jax
+
+    from nerrf_tpu.data.stream import PackConfig, build_packed_streams
+    from nerrf_tpu.train.checkpoint import save_stream_checkpoint
+    from nerrf_tpu.train.stream import train_stream
+
+    pack = exp.stream_data or PackConfig()
+    _log(f"experiment {exp.name}: building corpus "
+         f"({exp.corpus.num_traces} traces x {exp.corpus.duration_sec:.0f}s)")
+    traces, _ = exp.build_corpus()
+    arrays, waste = build_packed_streams(traces, exp.stream.vocab_size, pack)
+    _log(f"dataset: {pack.num_seqs} packed sequences of {pack.seq_len} "
+         f"event tokens, packing waste {waste:.4f}; stack "
+         f"{list(exp.stream.stack)}")
+    res = train_stream(arrays, exp.stream, cfg, log=_log,
+                       compile_cache=compile_cache,
+                       tokens_per_row=pack.seq_len)
+    save_stream_checkpoint(out / "model", res.state.params, exp.stream)
+    first, last = res.history[0]["loss"], res.history[-1]["loss"]
+    report = {
+        "experiment": exp.name,
+        "backend": jax.default_backend(),
+        "devices": n_dev,
+        "num_steps": cfg.num_steps,
+        "steps_per_sec": round(res.steps_per_sec, 4),
+        "loss": {"first": first, "last": last},
+        "history": res.history,
+        "metrics": {k: round(float(v), 4) for k, v in res.metrics.items()},
+        "pack_waste": round(waste, 6),
+        "gates": {"loss_fell": bool(last < first)},
+        "wall_seconds": round(time.time() - t0, 1),
+    }
+    (out / "metrics.json").write_text(json.dumps(report, indent=2) + "\n")
+    _log(f"done: loss {first:.4f} -> {last:.4f} at "
+         f"{res.steps_per_sec:.3f} steps/s")
+    return report
 
 
 def _finish(exp, cfg, out: Path, n_dev, metrics, steps_per_sec, params,

@@ -1,0 +1,117 @@
+"""Training the stream encoder (`models/stream.py`) through the trainer's
+own step: `make_tx`, `TrainState`, the resident scheduled step behind the
+AOT cache (`cache_train_step`), the `train_step_call` span.
+
+Two objectives, chosen by the encoder's configuration and nothing else:
+``vocab_size`` > 0 trains the next event token over packed documents (the
+pretrainer: `python -m nerrf_tpu.train.run --experiment
+configs/stream-phi4-mini-flash.json`); ``vocab_size`` 0 trains the per-event
+attack logit on feature streams (`benchmarks/run_stream_eval.py`,
+`bench.py`'s stream leg and the dp x sp step of `parallel/train.py` use the
+same loss and the same optimizer recipe).
+"""
+
+from __future__ import annotations
+
+import time
+from typing import Optional
+
+import jax
+import numpy as np
+from flax.training import train_state
+
+from nerrf_tpu.models.stream import (StreamConfig, StreamNet,
+                                     next_token_loss, stream_loss)
+from nerrf_tpu.train import loop
+
+
+def _input_keys(scfg: StreamConfig):
+    """The batch's two model inputs: tokens and segment ids (next token) or
+    features and mask (per-event BCE)."""
+    return ("tokens", "segments") if scfg.vocab_size else ("feat", "mask")
+
+
+def make_stream_loss_fn(model: StreamNet):
+    """``loss_fn(params, batch, dropout_rng) -> (loss, aux)`` in the shape
+    `loop._step_body` takes.  ``batch``: ``tokens``, ``segments`` (next
+    token) or ``feat``, ``mask``, ``label`` (per-event BCE)."""
+    scfg = model.cfg
+    first, second = _input_keys(scfg)
+
+    def loss_fn(params, batch, dropout_rng):
+        out = model.apply({"params": params}, batch[first], batch[second],
+                          deterministic=False, rngs={"dropout": dropout_rng})
+        if scfg.vocab_size:
+            loss = next_token_loss(scfg, params, out["hidden"],
+                                   batch["tokens"], batch["segments"])
+        else:
+            loss = stream_loss(out, batch["label"], batch["mask"])
+        return loss, {}
+
+    return loss_fn
+
+
+def stream_key_extra(scfg: StreamConfig) -> dict:
+    """AOT key material of a stream step beside the training config's."""
+    return {"stream_cfg": repr(scfg)}
+
+
+def init_stream_state(model: StreamNet, cfg: loop.TrainConfig, sample: dict,
+                      rng) -> train_state.TrainState:
+    """A fresh `TrainState` around ``model.init`` (one jitted call: at the
+    published widths the parameters are 2.8 GB) with the trainer's
+    optimizer.  ``sample``: one batch's arrays."""
+    first, second = _input_keys(model.cfg)
+    params = jax.jit(lambda r: model.init(
+        r, sample[first], sample[second], deterministic=True)["params"])(rng)
+    return train_state.TrainState.create(
+        apply_fn=model.apply, params=params, tx=loop.make_tx(cfg))
+
+
+def make_stream_step(model: StreamNet, cfg: loop.TrainConfig, arrays: dict,
+                     idx_table: np.ndarray, compile_cache=None):
+    """The resident scheduled step over ``arrays`` (uploaded here, once) ->
+    ``step(state, rng) -> (state, loss, aux, rng)``, behind the AOT cache
+    where one is given."""
+    step = loop.make_train_step_scheduled(
+        model, cfg, arrays, idx_table, loss_fn=make_stream_loss_fn(model))
+    if compile_cache is None:
+        return loop.traced_step(step)
+    return loop.cache_train_step(
+        compile_cache, step, model, cfg, "stream_step_scheduled",
+        extra=stream_key_extra(model.cfg))
+
+
+def train_stream(arrays: dict, scfg: StreamConfig, cfg: loop.TrainConfig,
+                 log=print, compile_cache=None,
+                 tokens_per_row: Optional[int] = None) -> loop.TrainResult:
+    """Train the stream encoder on device-resident ``arrays`` for
+    ``cfg.num_steps`` steps of ``cfg.batch_size`` rows; the schedule of rows
+    is `make_idx_schedule`'s.  The loss is floated every ``cfg.eval_every``
+    steps (the loop's only sync) and at the end."""
+    n = len(next(iter(arrays.values())))
+    model = StreamNet(scfg)
+    rng = jax.random.PRNGKey(cfg.seed)
+    rng, init_rng = jax.random.split(rng)
+    sample = {k: v[:min(cfg.batch_size, n)] for k, v in arrays.items()}
+    state = init_stream_state(model, cfg, sample, init_rng)
+    step = make_stream_step(model, cfg, arrays,
+                            loop.make_idx_schedule(n, cfg), compile_cache)
+    history = []
+    t_first = None
+    for i in range(cfg.num_steps):
+        state, loss, _aux, rng = step(state, rng)
+        if i == 0 or (i + 1) % cfg.eval_every == 0 or i == cfg.num_steps - 1:
+            # the loop's only sync: logged steps (eval_every)
+            history.append({"step": i, "loss": float(loss)})
+            log(f"step {i}: loss {history[-1]['loss']:.4f}")
+            if t_first is None:      # step 0 holds the compile
+                t_first = time.perf_counter()
+    steps_per_sec = max(cfg.num_steps - 1, 1) / max(
+        time.perf_counter() - (t_first or 0.0), 1e-9)
+    metrics = {"final_loss": history[-1]["loss"]}
+    if tokens_per_row:
+        metrics["tokens_per_sec"] = (steps_per_sec * min(cfg.batch_size, n)
+                                     * tokens_per_row)
+    return loop.TrainResult(state=state, metrics=metrics,
+                            steps_per_sec=steps_per_sec, history=history)

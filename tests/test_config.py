@@ -19,7 +19,7 @@ from nerrf_tpu.train.loop import TrainConfig
 def test_registry_matches_baseline_configs():
     assert set(EXPERIMENTS) == {
         "toy-graphsage", "lstm-impact", "joint-100h", "joint-dense",
-        "mcts-lockbit", "multihost-online",
+        "mcts-lockbit", "multihost-online", "stream-phi4-mini-flash",
     }
 
 

@@ -15,6 +15,7 @@ from nerrf_tpu.parallel import (
     ring_self_attention,
 )
 from nerrf_tpu.parallel.ring import _attention_local
+from nerrf_tpu.train.loop import TrainConfig
 
 
 @pytest.fixture(scope="module")
@@ -90,7 +91,9 @@ def test_stream_training_step_runs_and_improves(mesh):
 
     cfg = StreamConfig(dim=32, num_heads=2, num_layers=2, dropout=0.0)
     model = StreamNet(cfg, mesh=mesh)
-    init_fn, step_fn, place = make_stream_train_step(model, mesh, learning_rate=3e-3)
+    init_fn, step_fn, place = make_stream_train_step(
+        model, mesh, TrainConfig(learning_rate=3e-3, warmup_steps=2,
+                                 num_steps=8))
     rng = jax.random.PRNGKey(0)
     with mesh:
         placed = place(batch)
