@@ -29,6 +29,12 @@ a batch of `--batch` windows, the per-forward precompute (for dense_adj the
 compiled program's planned bytes beside the times and a mode that does not
 compile (out of memory) recorded as not fitting.
 
+`--head-gather` is the sweep `ops.gather_rows`' route on a TPU was chosen
+by (`ops.segment.SELECTION_MATMUL_MAX_ROWS`): the heads' two gathers of E
+rows from an [N, 160] table and their adjoints, batch 8, on the compiler's
+gather, on one selection matmul each way and on the Pallas one-hot kernel;
+its rows go under the artifact's `head_gather` key.
+
 Off-TPU the wall-clock columns are degraded (XLA-CPU serves all modes; the
 artifact says so) but the kernel-count attribution and the O(N²)-vs-O(E)
 work ratio still hold; an `interpret_parity` leg additionally runs the fused
@@ -42,6 +48,9 @@ Usage:
       --out benchmarks/results/kernel_bench_cpu.json
   python benchmarks/run_kernel_bench.py --stack \
       --buckets 1024,2048,4096,8192,16384 \
+      --out benchmarks/results/kernel_bench_v5e.json     # on the chip
+  python benchmarks/run_kernel_bench.py --head-gather \
+      --buckets 256,1024,2048,4096,8192,16384 \
       --out benchmarks/results/kernel_bench_v5e.json     # on the chip
 """
 
@@ -259,6 +268,134 @@ def bench_stack(n, e, hidden, layers, batch, iters, dtype):
     return {"modes": out, "wins": min(ran, key=ran.get) if ran else None}
 
 
+# --- the heads' row gather: which route serves ops.gather_rows on a TPU -----
+
+
+def _gather_candidates():
+    """{route: (gather for edge_src, gather for edge_dst)}: plain functions
+    of (table [N,H], idx [E]) -> [E,H].  `xla` and `xla_selection_matmul`
+    are the two routes `ops.gather_rows` chooses between, themselves, not
+    replicas; the others are built from the same pieces and ship nowhere.
+    Every one sums its adjoint in float32 (`ops.segment._f32_adjoint`): a
+    bf16 scatter-add into a hub node would be a lower precision, not a
+    faster gather."""
+    import jax
+    import jax.numpy as jnp
+
+    from nerrf_tpu.ops import pallas_segment
+    from nerrf_tpu.ops import segment as seg
+
+    def take(table, idx):
+        return jnp.take(table, idx, axis=0)
+
+    def scatter_add_sorted(g, idx, n):
+        return jax.ops.segment_sum(g.astype(jnp.float32), idx,
+                                   num_segments=n, indices_are_sorted=True)
+
+    return {
+        # (a) the compiler's gather and a float32 scatter-add; the second
+        # declares the builder's dst order, which `gather_rows` is not told
+        "xla": (seg._gather_take,) * 2,
+        "xla_sorted_dst": (seg._gather_take,
+                           seg._f32_adjoint(take, scatter_add_sorted)),
+        # (b) one selection matmul each way: one bf16 pass, f32 accumulation
+        "xla_selection_matmul": (seg._gather_select,) * 2,
+        "xla_take_fwd_selection_adjoint": (seg._f32_adjoint(
+            take, lambda g, idx, n: seg._select(idx, n, g, 0)),) * 2,
+        # (c) the Pallas one-hot kernel and its dense segment-sum adjoint
+        "pallas_blocked": (
+            lambda t, i: pallas_segment.gather_rows(t, i, False),) * 2,
+    }
+
+
+def bench_head_gather(n, e, hidden, batch, reps, iters, dtype):
+    """-> {route: {...}}: what `GraphSAGET`'s heads ask of `gather_rows`,
+    and nothing else of the step: ``h_src = table[src]``, ``h_dst =
+    table[dst]`` from one ``[n, hidden]`` table over ``batch`` windows under
+    `vmap`, forward alone and forward + backward (cotangents as arguments,
+    as in training), ``reps`` times over different data inside one program
+    so that the host's round trip (1-3 ms a call) is a small part of what
+    is timed.  Times are a repetition's; ``fwd_err`` is against `jnp.take`
+    (0.0 = bit for bit), ``adjoint_err`` against a float32 scatter-add of
+    the same cotangents, relative to its largest entry."""
+    import jax
+    import jax.numpy as jnp
+
+    from nerrf_tpu.ops.segment import gather_rows_route
+
+    rng = np.random.default_rng(n + 3)
+    src = rng.integers(0, n, (batch, e)).astype(np.int32)
+    dst = np.sort(rng.integers(0, n, (batch, e)), axis=1).astype(np.int32)
+    dst[:, : e // 16] = 0          # a hub: e/16 in-edges on node 0
+    tables = jnp.asarray(rng.normal(size=(reps, batch, n, hidden)), dtype)
+    cots = jnp.asarray(rng.normal(size=(reps, 2, batch, e, hidden)), dtype)
+    src, dst = jnp.asarray(src), jnp.asarray(dst)
+
+    def programs(g_src, g_dst):
+        def fwd_window(t, s, d):
+            return g_src(t, s), g_dst(t, d)
+
+        def both_window(t, s, d, c_s, c_d):
+            # the gathered rows are outputs too: a gradient alone would let
+            # the compiler drop the forward, which the adjoint never reads
+            rows, pull = jax.vjp(lambda t0: fwd_window(t0, s, d), t)
+            return rows, pull((c_s, c_d))[0]
+
+        fwd = jax.jit(lambda ts: jax.lax.map(
+            lambda t: jax.vmap(fwd_window)(t, src, dst), ts))
+        both = jax.jit(lambda ts, cs: jax.lax.map(
+            lambda tc: jax.vmap(both_window)(tc[0], src, dst, tc[1][0],
+                                             tc[1][1]), (ts, cs)))
+        return fwd, both
+
+    want_fwd = want_adj = None
+    out = {}
+    for name, (g_src, g_dst) in _gather_candidates().items():
+        try:
+            fwd, both = programs(g_src, g_dst)
+            c_fwd = fwd.lower(tables).compile()
+            c_both = both.lower(tables, cots).compile()
+            mem = c_both.memory_analysis()
+            fwd_ms, _ = _time_fn(c_fwd, tables, iters, jax.block_until_ready)
+            both_ms, _ = _time_fn(lambda ts: c_both(ts, cots), tables, iters,
+                                  jax.block_until_ready)
+            got_fwd = [np.asarray(x[0], np.float32) for x in c_fwd(tables)]
+            got_adj = np.asarray(c_both(tables, cots)[1][0], np.float32)
+        except Exception as err:  # noqa: BLE001 - a sweep records and goes on
+            line = (str(err).strip().splitlines() or [repr(err)])[0]
+            out[name] = {"fits": False, "error": line[:300]}
+            _log(f"  head_gather n={n} {name}: does not run: {line[:160]}")
+            continue
+        if want_fwd is None:       # the host's own, once
+            t0 = np.asarray(tables[0], np.float32)
+            s_np, d_np = np.asarray(src), np.asarray(dst)
+            want_fwd = [np.take_along_axis(t0, i[..., None], 1)
+                        for i in (s_np, d_np)]
+            want_adj = np.zeros((batch, n, hidden), np.float64)
+            c0 = np.asarray(cots[0], np.float64)
+            for b in range(batch):
+                np.add.at(want_adj[b], s_np[b], c0[0, b])
+                np.add.at(want_adj[b], d_np[b], c0[1, b])
+        out[name] = {
+            "fits": True,
+            "fwd_ms": round(fwd_ms / reps, 4),
+            "fwd_bwd_ms": round(both_ms / reps, 4),
+            "temp_bytes": int(mem.temp_size_in_bytes),
+            "fwd_err": float(max(np.max(np.abs(g - w))
+                                 for g, w in zip(got_fwd, want_fwd))),
+            "adjoint_err": float(np.max(np.abs(got_adj - want_adj))
+                                 / np.max(np.abs(want_adj))),
+        }
+        _log(f"  head_gather n={n} {name}: fwd {fwd_ms / reps:.3f} ms, "
+             f"fwd+bwd {both_ms / reps:.3f} ms, "
+             f"{mem.temp_size_in_bytes / 1e9:.2f} GB temp, "
+             f"fwd_err {out[name]['fwd_err']:.1e} "
+             f"adjoint_err {out[name]['adjoint_err']:.1e}")
+    ran = {m: r["fwd_bwd_ms"] for m, r in out.items() if r["fits"]}
+    return {"routes": out, "wins": min(ran, key=ran.get) if ran else None,
+            "gather_rows_takes": gather_rows_route(n)}
+
+
 def interpret_parity(hidden):
     """Run the fused Pallas kernel in interpreter mode at the smallest
     bucket against the XLA composition that serves production off-TPU
@@ -324,6 +461,34 @@ def measured_crossover(rows):
     return None
 
 
+def _head_gather_main(args, backend, dtype, t0, provenance) -> int:
+    """The `--head-gather` leg: its rows replace the `head_gather` key of
+    the artifact at --out and nothing else there (the aggregation sweep's
+    rows are another run's record)."""
+    import jax.numpy as jnp
+
+    rows = [{"nodes": n, "edges": 2 * n, "hidden": args.hidden,
+             **bench_head_gather(n, 2 * n, args.hidden, args.batch,
+                                 args.reps, args.iters, dtype)}
+            for n in (int(b) for b in args.buckets.split(","))]
+    out = Path(args.out)
+    report = json.loads(out.read_text()) if out.exists() else {}
+    report["head_gather"] = {
+        "backend": backend,
+        "dtype": jnp.dtype(dtype).name,
+        "batch": args.batch, "reps": args.reps, "iters": args.iters,
+        "buckets": rows,
+        "provenance": provenance,
+        "wall_seconds": round(time.time() - t0, 1),
+    }
+    out.parent.mkdir(parents=True, exist_ok=True)
+    out.write_text(json.dumps(report, indent=2) + "\n")
+    _log(f"wrote {out}")
+    print(json.dumps({r["nodes"]: {m: v.get("fwd_bwd_ms") for m, v in
+                                   r["routes"].items()} for r in rows}))
+    return 0
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--out", default="benchmarks/results/kernel_bench_cpu.json")
@@ -341,6 +506,14 @@ def main(argv=None) -> int:
                          "backward, batched (the leg the auto crossover is "
                          "read from) in place of the single forward call, "
                          "which on a chip times the host's round trip")
+    ap.add_argument("--head-gather", action="store_true",
+                    help="time the heads' two row gathers and their "
+                         "adjoints on every candidate route and write the "
+                         "rows under the artifact's `head_gather` key, "
+                         "keeping whatever else --out already holds")
+    ap.add_argument("--reps", type=int, default=8,
+                    help="gathers over different data inside one timed "
+                         "program of --head-gather")
     ap.add_argument("--layers", type=int, default=28,
                     help="aggregates in the stack (flagship depth 28)")
     ap.add_argument("--batch", type=int, default=8,
@@ -364,6 +537,11 @@ def main(argv=None) -> int:
     backend = jax.default_backend()
     dtype = jnp.bfloat16 if backend == "tpu" else jnp.float32
     _log(f"backend={backend} dtype={jnp.dtype(dtype).name}")
+
+    provenance = ("python benchmarks/run_kernel_bench.py "
+                  + " ".join(sys.argv[1:] if argv is None else argv))
+    if args.head_gather:
+        return _head_gather_main(args, backend, dtype, t0, provenance)
 
     rows = []
     for n in [int(b) for b in args.buckets.split(",")]:
@@ -410,8 +588,7 @@ def main(argv=None) -> int:
                 "dense_adj vs fused ms_per_layer") + ", log-interpolated "
                 "between swept buckets",
         },
-        "provenance": "python benchmarks/run_kernel_bench.py "
-                      + " ".join(sys.argv[1:] if argv is None else argv),
+        "provenance": provenance,
         "wall_seconds": round(time.time() - t0, 1),
     }
     out = Path(args.out)

@@ -11,7 +11,9 @@ the entry points a user calls, at the full width of the flagship model
             `utils.fetch_value` — the two barriers must agree
   kernels   each of the five Pallas kernels, compiled, and the XLA
             composition it stands in for, against a host reference at
-            1024n/2048e/160 and 4096n/8192e/160: forward and VJP, under vmap
+            1024n/2048e/160 and 4096n/8192e/160: forward and VJP, under vmap;
+            `ops.gather_rows` (the selection matmul on a TPU) beside the
+            Pallas gather
   train     `nerrf_tpu.train.run` on an experiment that copies
             configs/joint-100h.json's `dataset` and `train.model` and shrinks
             only the corpus and the step count: at 1024n/2048e and,
@@ -55,6 +57,7 @@ CHIP = dict(
                 benign_rate_hz=40.0),
     num_steps=60,
     model=None,                 # joint-100h's train.model, verbatim
+    gather_route="xla_selection_matmul",   # ops.gather_rows on a TPU
     serve_buckets=("1024x2048x128", "4096x8192x128"),
     # windows of ~600 and ~3,200 nodes: one trace for each bucket
     serve_traces=(dict(duration_sec=120.0, num_target_files=24,
@@ -74,6 +77,9 @@ REHEARSAL = dict(
     # (hidden 64: the narrowest kernel `parallel.mesh` still splits over tp)
     model=dict(gnn=dict(hidden=64, num_layers=2),
                lstm=dict(hidden=32, num_layers=1, impl="fused")),
+    # nothing names `gather_rows`' route: it reads the backend, and off a
+    # TPU the compiler's gather serves
+    gather_route="xla",
     serve_buckets=("256x512x32", "512x1024x32"),
     serve_traces=(dict(duration_sec=60.0, num_target_files=4,
                        benign_rate_hz=6.0, seed=103),
@@ -222,8 +228,8 @@ def kernels_phase(shapes, interpret: bool) -> None:
     import jax.numpy as jnp
     import numpy as np
 
-    from nerrf_tpu.ops import pallas_segment as pk
-    from nerrf_tpu.ops.segment import sage_aggregate_xla
+    from nerrf_tpu.ops import gather_rows, pallas_segment as pk
+    from nerrf_tpu.ops.segment import gather_rows_route, sage_aggregate_xla
 
     B, tol = 2, 2e-4
 
@@ -286,6 +292,7 @@ def kernels_phase(shapes, interpret: bool) -> None:
             jnp.asarray(data), jnp.asarray(dst))
         compare(f"gather_rows{shape}", {
             mode: jax.vmap(lambda t, i: pk.gather_rows(t, i, interpret)),
+            f"ops: {gather_rows_route(N)}": jax.vmap(gather_rows),
             "xla": jax.vmap(lambda t, i: jnp.take(t, i, axis=0))},
             gather(table, ids), lambda g: scatter(g, ids, N),
             jnp.asarray(table), jnp.asarray(ids))
@@ -364,8 +371,11 @@ def train_phase(cfg: dict, work: Path, aot, idx: int) -> Path:
     modes = (kp.pop("gnn_aggregation"), kp.pop("lstm_impl"))
     check(modes == (want_mode, "fused"),
           f"aggregation/LSTM resolved to {modes}, not ({want_mode}, fused)")
-    check(all(v.startswith("pallas_") for v in kp.values()),
-          f"an XLA op served the step: {kp}")
+    # the row gather is compiler-written (on a TPU a selection matmul);
+    # the segment ops a step could reach are the Pallas kernels
+    check(kp.pop("gather_rows") == cfg["gather_route"]
+          and all(v.startswith("pallas_") for v in kp.values()),
+          f"not the routes a TPU takes: {report['kernel_path']}")
     return work / exp["name"] / "model"
 
 

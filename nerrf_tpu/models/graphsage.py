@@ -363,8 +363,12 @@ class GraphSAGET(nn.Module):
             node_logit = nn.Dense(
                 1, dtype=jnp.float32, name="node_head")(h)[:, 0]
 
-            h_src = gather_rows(h, edge_src)
-            h_dst = gather_rows(h, edge_dst)
+            # a scope of their own inside the heads': a device trace names
+            # the two edge-row gathers (and, under transpose(jvp(...)),
+            # their adjoints) whatever route `ops.gather_rows` takes
+            with jax.named_scope("row_gather"):
+                h_src = gather_rows(h, edge_src)
+                h_dst = gather_rows(h, edge_dst)
             pair = jnp.concatenate(
                 [h_src, h_dst, h_src * h_dst, e_emb], axis=-1)
             z = nn.gelu(

@@ -18,9 +18,14 @@ Both kernels are order-independent (no sorted-ids requirement) and carry
 custom VJPs — the adjoint of a segment-sum is a row gather and vice versa, so
 the backward passes reuse the same two kernels.
 
-Use :func:`register` to install these as the implementation behind
-``nerrf_tpu.ops.segment_sum`` / ``gather_rows``; ``segment.py`` registers them
-on first use when the active backend is TPU.
+Use :func:`register` to install the segment sums (and the fused SAGE
+aggregate) behind ``nerrf_tpu.ops``; ``segment.py`` registers them on first
+use when the active backend is TPU.  The row-gather kernels serve as the
+segment sums' adjoints only: ``nerrf_tpu.ops.gather_rows`` itself is
+compiler-written on a TPU (one selection matmul each way, 14-29x faster than
+`gather_rows` here from 1024 to 8192 nodes: `ops.segment.SELECTION_MATMUL_MAX_ROWS`
+has the chip's sweep), and since then no bucket the repo ships reaches any
+kernel of this module through the model.
 """
 
 from __future__ import annotations
@@ -696,15 +701,17 @@ def tile_constants() -> dict:
 
 
 def register(interpret: bool = False) -> None:
-    """Install all five Pallas kernels behind ``nerrf_tpu.ops``'
+    """Install the Pallas segment sums (dense, and banded with its banded
+    gather adjoint) and the fused SAGE aggregate behind ``nerrf_tpu.ops``'
     switchboard, unconditionally: a kernel Mosaic refuses raises at the
     compile that needs it instead of being traded for an XLA op nobody
-    asked for.  `chip_smoke.py` compiles and checks each one on the chip."""
+    asked for.  `chip_smoke.py` compiles and checks all five kernels of this
+    module on the chip, the blocked gather (the dense sum's adjoint)
+    among them."""
     from nerrf_tpu.ops import segment as _seg
 
     _seg.use_pallas(
         lambda data, ids, n: segment_sum(data, ids, n, interpret),
-        lambda table, idx: gather_rows(table, idx, interpret),
         sorted_sum_fn=lambda data, ids, n: segment_sum_sorted(
             data, ids, n, interpret),
         sage_fn=lambda msg, *edges_and_n: sage_aggregate_fused(
@@ -715,4 +722,4 @@ def register(interpret: bool = False) -> None:
 def unregister() -> None:
     from nerrf_tpu.ops import segment as _seg
 
-    _seg.use_pallas(None, None)
+    _seg.use_pallas(None)

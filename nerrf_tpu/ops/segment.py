@@ -4,8 +4,10 @@ The graph builder emits edges sorted by destination node, so aggregation is a
 segment reduction over a monotone id vector — the memory-friendly layout for
 TPU.  This module is the single switchboard for those primitives.  Off TPU
 they are XLA's scatter-add and gather (`jax.ops.segment_sum`, `jnp.take`);
-`nerrf_tpu.ops.pallas_segment` provides hand-tiled Pallas kernels for the hot
-TPU path and registers itself here.  ``sorted_ids=True`` is a **contract**
+`nerrf_tpu.ops.pallas_segment` provides hand-tiled Pallas kernels for the
+segment reductions on a TPU and registers itself here; the row gather is
+compiler-written on every backend (:func:`gather_rows`: on a TPU one
+selection matmul each way).  ``sorted_ids=True`` is a **contract**
 (ids really are nondecreasing — it routes to a banded kernel that drops
 out-of-band rows on unsorted input), not a hint; the default is the safe
 order-independent path.
@@ -27,7 +29,6 @@ import jax.numpy as jnp
 # Optional overrides installed by nerrf_tpu.ops.pallas_segment.register().
 _SEGMENT_SUM_IMPL: Optional[Callable] = None
 _SEGMENT_SUM_SORTED_IMPL: Optional[Callable] = None
-_GATHER_IMPL: Optional[Callable] = None
 _SAGE_FUSED_IMPL: Optional[Callable] = None
 _AUTO_TRIED = False
 # per-thread: a sharded trace in one thread must not change what a serve
@@ -56,10 +57,10 @@ def _impl(fn: Optional[Callable]) -> Optional[Callable]:
     return None if getattr(_TRACING, "xla_only", False) else fn
 
 
-def use_pallas(sum_fn: Optional[Callable], gather_fn: Optional[Callable] = None,
+def use_pallas(sum_fn: Optional[Callable],
                sorted_sum_fn: Optional[Callable] = None,
                sage_fn: Optional[Callable] = None) -> None:
-    """Install (or clear) pallas segment-sum / row-gather implementations.
+    """Install (or clear) the Pallas segment-sum / aggregation kernels.
 
     ``sorted_sum_fn`` (if given) serves calls that declare nondecreasing ids
     (the builder's sorted-by-dst layout) — the banded kernel with linear MXU
@@ -70,11 +71,10 @@ def use_pallas(sum_fn: Optional[Callable], gather_fn: Optional[Callable] = None,
     An explicit call — including clearing — is a deliberate choice, so it also
     disables the one-shot TPU registration in :func:`_maybe_auto_register`.
     """
-    global _SEGMENT_SUM_IMPL, _SEGMENT_SUM_SORTED_IMPL, _GATHER_IMPL, \
-        _SAGE_FUSED_IMPL, _AUTO_TRIED
+    global _SEGMENT_SUM_IMPL, _SEGMENT_SUM_SORTED_IMPL, _SAGE_FUSED_IMPL, \
+        _AUTO_TRIED
     _SEGMENT_SUM_IMPL = sum_fn
     _SEGMENT_SUM_SORTED_IMPL = sorted_sum_fn
-    _GATHER_IMPL = gather_fn
     _SAGE_FUSED_IMPL = sage_fn
     _AUTO_TRIED = True
 
@@ -90,7 +90,9 @@ def active_impls() -> dict:
         "segment_sum": "pallas_dense" if dense else "xla",
         "segment_sum_sorted": (
             "pallas_banded" if banded else "pallas_dense" if dense else "xla"),
-        "gather_rows": "pallas_blocked" if _impl(_GATHER_IMPL) else "xla",
+        # the route of every shipped bucket; `gather_rows_route(n)` is the
+        # rule for a table of n rows
+        "gather_rows": gather_rows_route(),
         "sage_aggregate": (
             "pallas_fused" if _impl(_SAGE_FUSED_IMPL) else "xla"),
     }
@@ -239,16 +241,97 @@ def sage_aggregate_xla(msg, dst_ids, src_by_dst, src_ids, dst_by_src,
     return (fwd + rev).astype(msg.dtype)
 
 
+# The largest table, in rows, that `gather_rows` serves with a selection
+# matmul on a TPU.  From the chip's sweep (benchmarks/results/
+# kernel_bench_v5e.json `head_gather`, `python benchmarks/run_kernel_bench.py
+# --head-gather`: the heads' two gathers of E = 2N rows from [N,160] bf16 and
+# their adjoints, batch 8, one v5e; ms forward + backward):
+#
+#     N        256   1024   2048   4096   8192   16384
+#     matmul   0.15  0.26   0.64   2.19   8.12   31.99
+#     take     0.31  0.81   1.69   3.98   8.26   18.52
+#     Pallas   0.39  3.78   14.3   59.1   237    954
+#
+# The matmul does O(N E) work and the gather O(E): the matmul wins every
+# bucket anyone ships (256 .. 4096) by 1.8-3.1x, the two meet at 8192 and the
+# gather wins by 1.7x at 16384.  The Pallas one-hot kernel (float32 at
+# `HIGHEST` over an (E/128, F/128, N/128) grid; what served this op until PR
+# 30) is 14-29x behind the matmul from 1024 to 8192.
+SELECTION_MATMUL_MAX_ROWS = 8192
+
+
+def gather_rows_route(num_rows: Optional[int] = None) -> str:
+    """The route :func:`gather_rows` takes for a floating ``[num_rows, F]``
+    table in a program traced here and now: ``xla_selection_matmul`` on a
+    TPU up to `SELECTION_MATMUL_MAX_ROWS` rows (no ``num_rows``: the answer
+    for every bucket the repo ships), ``xla`` (the compiler's gather)
+    otherwise: past that size, off a TPU, and under :func:`xla_only`.  A
+    function of the backend and a static shape, like
+    `GraphSAGEConfig.resolved_aggregation`; nothing sets it."""
+    if (jax.default_backend() == "tpu"
+            and not getattr(_TRACING, "xla_only", False)
+            and (num_rows is None or num_rows <= SELECTION_MATMUL_MAX_ROWS)):
+        return "xla_selection_matmul"
+    return "xla"
+
+
+def _f32_adjoint(fwd: Callable, bwd: Callable) -> Callable:
+    """``fwd(table, idx)`` with ``bwd(g, idx, num_rows) -> f32 [num_rows, F]``
+    as its adjoint in ``table``.  The adjoint of a row gather sums every
+    cotangent row that read the same table row: that sum is taken in
+    float32 on every route and cast to the table's dtype afterwards, so a
+    bf16 table's hub node (thousands of in-edges) is not accumulated in
+    bf16."""
+
+    @jax.custom_vjp
+    def gather(table, idx):
+        return fwd(table, idx)
+
+    def gather_fwd(table, idx):
+        return fwd(table, idx), (idx, table.shape[0])
+
+    def gather_bwd(res, g):
+        idx, num_rows = res
+        return bwd(g, idx, num_rows).astype(g.dtype), None
+
+    gather.defvjp(gather_fwd, gather_bwd)
+    return gather
+
+
+def _select(idx, num_rows, operand, contract: int):
+    """``one_hot(idx, num_rows)`` [E, N] contracted with ``operand`` over its
+    axis ``contract`` (1: rows of a table picked, [N, F] -> [E, F]; 0: rows
+    of a cotangent summed by index, [E, F] -> [N, F]), accumulated in
+    float32.  Every product is by 0.0 or 1.0, so one bf16 pass is exact for
+    a bf16 operand; a wider operand takes `HIGHEST`, whose bf16 pieces of a
+    float32 add back exactly.  XLA fuses the one-hot into the product's
+    operand: it is never held in HBM."""
+    sel = jax.nn.one_hot(idx, num_rows, dtype=operand.dtype)
+    return jax.lax.dot_general(
+        sel, operand, (((contract,), (0,)), ((), ())),
+        precision=(None if operand.dtype == jnp.bfloat16
+                   else jax.lax.Precision.HIGHEST),
+        preferred_element_type=jnp.float32)
+
+
+_gather_take = _f32_adjoint(
+    lambda table, idx: jnp.take(table, idx, axis=0),
+    lambda g, idx, num_rows: jax.ops.segment_sum(
+        g.astype(jnp.float32), idx, num_segments=num_rows))
+_gather_select = _f32_adjoint(
+    lambda table, idx: _select(idx, table.shape[0], table, 1
+                               ).astype(table.dtype),
+    lambda g, idx, num_rows: _select(idx, num_rows, g, 0))
+
+
 def gather_rows(table: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
-    """Row gather ``table[idx]`` — kept as a named op so the Pallas blocked
-    gather can swap in on TPU without touching call sites."""
-    _maybe_auto_register()
-    gather = _impl(_GATHER_IMPL)
-    if (
-        gather is not None
-        and table.ndim == 2
-        and idx.ndim == 1
-        and jnp.issubdtype(table.dtype, jnp.floating)
-    ):
-        return gather(table, idx)
-    return jnp.take(table, idx, axis=0)
+    """Row gather ``table[idx]``, a named op so that what serves it is
+    chosen in one place: see :func:`gather_rows_route`.  Both routes are
+    code the compiler writes, return the same rows bit for bit, and sum
+    the adjoint in float32 (`_f32_adjoint`)."""
+    if not (table.ndim == 2 and idx.ndim == 1
+            and jnp.issubdtype(table.dtype, jnp.floating)):
+        return jnp.take(table, idx, axis=0)
+    if gather_rows_route(table.shape[0]) == "xla_selection_matmul":
+        return _gather_select(table, idx)
+    return _gather_take(table, idx)

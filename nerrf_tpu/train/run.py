@@ -59,9 +59,10 @@ def _kernel_path(model_cfg, num_nodes: int) -> dict:
     `parallel.mesh_ops` context): the switchboard's routing plus the modes
     `auto` resolves to at this node bucket.  Stamped into the log and
     metrics.json so a steps/s figure names the kernels that produced it."""
-    from nerrf_tpu.ops.segment import active_impls
+    from nerrf_tpu.ops.segment import active_impls, gather_rows_route
 
     return {**active_impls(),
+            "gather_rows": gather_rows_route(num_nodes),
             "gnn_aggregation": model_cfg.gnn.resolved_aggregation(num_nodes),
             "lstm_impl": model_cfg.lstm.resolved_impl()}
 

@@ -336,6 +336,63 @@ def test_auto_routes_every_shipped_bucket_to_the_matmul_on_a_tpu(
     assert GraphSAGEConfig().resolved_aggregation(nodes) == want
 
 
+@pytest.mark.parametrize("nodes, edges", [(1024, 2048), (4096, 8192)])
+def test_no_kernel_under_the_gnn_at_the_shipped_training_buckets_on_a_tpu(
+        monkeypatch, nodes, edges):
+    """`NerrfNet()` as both experiments train it (`auto`, 28 x 160, bf16),
+    traced the way a TPU would trace it: nothing under `gnn` is a Pallas
+    call (the aggregate is `adj @ msg` since PR 27, the heads' edge-row
+    gathers selection matmuls since PR 30); the heads' two gathers lie
+    under `gnn_heads/row_gather`, forward and backward.  What is left of
+    the kernels in a step is the seq -> node scatter of `joint.py`."""
+    import re
+
+    from nerrf_tpu.graph import EDGE_FEATURE_DIM, NODE_FEATURE_DIM
+    from nerrf_tpu.ops import pallas_segment, segment
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    pallas_segment.register(interpret=True)    # what a TPU's first use does
+    try:
+        assert segment.active_impls()["gather_rows"] == "xla_selection_matmul"
+        S = jax.ShapeDtypeStruct
+        seqs, seq_len = 128, 100               # both experiments' dataset
+        args = (S((nodes, NODE_FEATURE_DIM), jnp.float32),
+                S((nodes,), jnp.int32), S((nodes,), jnp.int32),
+                S((nodes,), jnp.bool_), S((edges,), jnp.int32),
+                S((edges,), jnp.int32),
+                S((edges, EDGE_FEATURE_DIM), jnp.float32),
+                S((edges,), jnp.bool_),
+                S((seqs, seq_len, SEQ_FEATURE_DIM), jnp.float32),
+                S((seqs, seq_len), jnp.bool_), S((seqs,), jnp.int32))
+        model = NerrfNet(JointConfig())
+        assert model.cfg.gnn.resolved_aggregation(nodes) == "dense_adj"
+        params = jax.eval_shape(
+            lambda *a: model.init(jax.random.PRNGKey(0), *a)["params"], *args)
+
+        def loss(p, *a):
+            out = model.apply({"params": p}, *a)
+            return (out["edge_logit"].sum() + out["node_logit"].sum()
+                    + out["seq_logit"].sum())
+
+        text = jax.jit(jax.grad(loss)).lower(params, *args).as_text(
+            debug_info=True)
+    finally:
+        pallas_segment.unregister()
+    paths = set(re.findall(r'^#loc\d+ = loc\("([^"]+)"', text, flags=re.M))
+    assert not [p for p in paths if "/gnn/" in p and "pallas_call" in p]
+    assert any("pallas_call" in p for p in paths)      # joint.py's scatter
+    gathers = [p for p in paths if "/gnn_heads/row_gather/" in p]
+    for stage in ("jit(loss)/jvp(", "jit(loss)/transpose(jvp("):
+        own = [p for p in gathers if p.startswith(stage)]
+        assert sum(p.endswith("/dot_general") for p in own) >= 1, (stage,
+                                                                   gathers)
+    # the selection matmuls and what builds their one-hots, nothing else:
+    # no XLA gather or scatter either
+    assert not [p for p in gathers
+                if p.rsplit("/", 1)[1] in ("gather", "scatter-add",
+                                           "scatter_add", "pallas_call")]
+
+
 @pytest.mark.parametrize("nodes", [256, 4096, "past"])
 def test_auto_is_segment_off_a_tpu(monkeypatch, nodes):
     monkeypatch.setattr(jax, "default_backend", lambda: "cpu")

@@ -147,7 +147,7 @@ def test_switchboard_routes_and_reports_pallas_fused(monkeypatch):
     np.testing.assert_allclose(got, _ref(msg, edges, 30),
                                rtol=1e-5, atol=1e-5)
 
-    segment.use_pallas(None, None)
+    segment.use_pallas(None)
     assert segment.active_impls()["sage_aggregate"] == "xla"
     np.testing.assert_allclose(segment.sage_aggregate(msg, *edges, 30),
                                _ref(msg, edges, 30), rtol=1e-5, atol=1e-5)

@@ -259,14 +259,18 @@ def test_first_use_inside_a_trace_installs_the_kernels(monkeypatch):
     traced = jax.make_jaxpr(
         lambda d: segment.segment_sum(d, ids, 9, sorted_ids=True))(data)
     assert "pallas_call" in str(traced)
-    assert set(segment.active_impls().values()) == {
-        "pallas_dense", "pallas_banded", "pallas_blocked", "pallas_fused"}
+    assert segment.active_impls() == {
+        "segment_sum": "pallas_dense", "segment_sum_sorted": "pallas_banded",
+        "gather_rows": "xla_selection_matmul",
+        "sage_aggregate": "pallas_fused"}
 
 
-def test_xla_only_scope_bypasses_registered_kernels():
+def test_xla_only_scope_bypasses_registered_kernels(monkeypatch):
     """What a GSPMD-partitioned program traces under (parallel.mesh_ops):
     every op from its XLA composition, reported as such, and the
-    registration back in force when the scope ends."""
+    registration (and a TPU's gather route) back in force when the scope
+    ends."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     pallas_segment.register(interpret=True)
     data = _rand((20, 7), 42)
     ids = jnp.asarray(np.sort(np.random.default_rng(43).integers(0, 9, 20)),
@@ -279,8 +283,128 @@ def test_xla_only_scope_bypasses_registered_kernels():
     with segment.xla_only():
         assert set(segment.active_impls().values()) == {"xla"}
         assert "pallas_call" not in trace()
-    assert segment.active_impls()["gather_rows"] == "pallas_blocked"
-    assert "pallas_call" in trace()
+        assert "dot_general" not in trace()
+    assert segment.active_impls()["gather_rows"] == "xla_selection_matmul"
+    assert "pallas_call" in trace() and "dot_general" in trace()
+
+
+# -- ops.gather_rows: compiler-written on every backend (PR 30) ---------------
+
+
+def _edge_ids(n, e, seed, hub=0):
+    """[e] ids into n rows the way a padded window has them: ``hub``
+    repeats of row 7, random repeats, and a padded tail on row n - 1."""
+    rng = np.random.default_rng(seed)
+    ids = rng.integers(0, n, e)
+    ids[:hub] = 7
+    ids[e - e // 3:] = n - 1
+    return jnp.asarray(ids, jnp.int32)
+
+
+@pytest.mark.parametrize("backend, n, want", [
+    ("tpu", None, "xla_selection_matmul"), ("tpu", 256, "xla_selection_matmul"),
+    ("tpu", 4096, "xla_selection_matmul"),
+    ("tpu", segment.SELECTION_MATMUL_MAX_ROWS, "xla_selection_matmul"),
+    ("tpu", 2 * segment.SELECTION_MATMUL_MAX_ROWS, "xla"),
+    ("cpu", None, "xla"), ("cpu", 4096, "xla")])
+def test_gather_route_reads_the_backend_and_the_table_rows(
+        monkeypatch, backend, n, want):
+    """The rule of `gather_rows_route`: a function of the backend and a
+    static shape.  `active_impls()` names the route of the shipped buckets,
+    outside `xla_only()` and inside it."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert segment.gather_rows_route(n) == want
+    if n is None:
+        assert segment.active_impls()["gather_rows"] == want
+    with segment.xla_only():
+        assert segment.gather_rows_route(n) == "xla"
+        assert segment.active_impls()["gather_rows"] == "xla"
+
+
+def _traced_route(monkeypatch, backend, table, idx):
+    """`ops.gather_rows` and its gradient as a program traced on
+    ``backend`` would hold them (a fresh function: jax caches traces by
+    identity), with the jaxpr of the two."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+
+    def both(t, c):
+        rows, pull = jax.vjp(lambda t0: segment.gather_rows(t0, idx), t)
+        return rows, pull(c)[0]
+
+    cot = jnp.asarray(np.random.default_rng(61).normal(
+        size=(idx.shape[0], table.shape[1])), table.dtype)
+    text = str(jax.make_jaxpr(both)(table, cot))
+    rows, grad = jax.jit(both)(table, cot)
+    return rows, grad, cot, text
+
+
+@pytest.mark.parametrize("n, e", [(1024, 2048), (4096, 8192)])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_selection_matmul_forward_is_take_bit_for_bit(monkeypatch, n, e,
+                                                      dtype):
+    """The route a TPU takes at both shipped training buckets returns
+    `jnp.take`'s rows exactly, with repeated and padded indices: every
+    product is by 0.0 or 1.0 and one term of each sum is not zero."""
+    table = jnp.asarray(np.random.default_rng(n).normal(size=(n, 160)),
+                        dtype)
+    idx = _edge_ids(n, e, seed=e)
+    rows, _, _, text = _traced_route(monkeypatch, "tpu", table, idx)
+    assert "dot_general" in text and "pallas_call" not in text
+    assert "gather" not in text and "scatter" not in text
+    want = jnp.take(table, idx, axis=0)
+    assert rows.dtype == want.dtype
+    np.testing.assert_array_equal(np.asarray(rows, np.float32),
+                                  np.asarray(want, np.float32))
+
+
+@pytest.mark.parametrize("backend, n", [
+    ("tpu", 1024), ("tpu", 2 * segment.SELECTION_MATMUL_MAX_ROWS),
+    ("cpu", 1024)])
+def test_gather_adjoint_sums_in_float32_on_every_route(monkeypatch, backend,
+                                                       n):
+    """A bf16 table with a hub node of 512 in-edges: the gradient equals
+    a float32 `segment_sum` of the cotangent, cast once, on the selection
+    matmul, on the compiler's gather past the crossover and off a TPU.
+    Accumulating the hub's 512 rows in bf16 (what XLA derives for a bare
+    `jnp.take`) misses it by tens of bf16 steps."""
+    e = 2048
+    table = jnp.asarray(np.random.default_rng(n).normal(size=(n, 160)),
+                        jnp.bfloat16)
+    idx = _edge_ids(n, e, seed=5, hub=512)
+    _, grad, cot, text = _traced_route(monkeypatch, backend, table, idx)
+    assert grad.dtype == jnp.bfloat16
+    assert ("dot_general" in text) == (
+        backend == "tpu" and n <= segment.SELECTION_MATMUL_MAX_ROWS)
+    want32 = jax.ops.segment_sum(cot.astype(jnp.float32), idx,
+                                 num_segments=n)
+    want = np.asarray(want32.astype(jnp.bfloat16), np.float32)
+    got = np.asarray(grad, np.float32)
+    # float32 round-off in another order of summation can move a value
+    # across one bf16 rounding boundary, never further
+    step = np.maximum(np.abs(want), 1e-3) * 2.0 ** -7
+    assert np.all(np.abs(got - want) <= step)
+    # the case a bf16 accumulation fails, on the same data
+    bare = np.asarray(jax.grad(lambda t: jnp.sum(
+        jnp.take(t, idx, axis=0).astype(jnp.float32) * cot))(table),
+        np.float32)
+    assert np.abs(bare[7] - want[7]).max() > 4 * step[7].max()
+
+
+def test_gather_rows_under_vmap_matches_take_and_its_adjoint(monkeypatch):
+    """The model vmaps the heads over the window batch: both routes batch
+    and differentiate there, in float32 to float32 round-off."""
+    B, n, e, f = 3, 96, 200, 9
+    rng = np.random.default_rng(71)
+    table = jnp.asarray(rng.normal(size=(B, n, f)), jnp.float32)
+    idx = jnp.asarray(rng.integers(0, n, (B, e)), jnp.int32)
+    want_fn = jax.vmap(lambda t, i: jnp.take(t, i, axis=0))
+    want_g = jax.grad(lambda t: jnp.sum(want_fn(t, idx) ** 2))(table)
+    for backend in ("tpu", "cpu"):
+        monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
+        fn = jax.vmap(lambda t, i: segment.gather_rows(t, i))
+        np.testing.assert_array_equal(fn(table, idx), want_fn(table, idx))
+        got_g = jax.grad(lambda t: jnp.sum(fn(t, idx) ** 2))(table)
+        np.testing.assert_allclose(got_g, want_g, rtol=1e-5, atol=1e-5)
 
 
 def test_sorted_kernels_compiled_on_tpu():
