@@ -7,10 +7,7 @@ defaults are 256 nodes / 512 edges.  This bench answers, with numbers:
 
   1. what a 25 k-event window actually needs (exact node/edge counts),
   2. lowering time and drop counts across the capacity ladder,
-  3. whether GraphConfig.fit's auto-bucketing achieves zero drops,
-  4. (TPU) where the Pallas one-hot segment-sum crosses over against
-     jax.ops.segment_sum as capacities grow past toy size — the
-     "make-or-break kernel" question from SURVEY §7.
+  3. whether GraphConfig.fit's auto-bucketing achieves zero drops.
 
 Writes benchmarks/results/graph_capacity.json.
 """
@@ -24,10 +21,6 @@ import time
 from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
-
-import numpy as np
-
-from nerrf_tpu.utils import sync_result
 
 
 def _log(m):
@@ -100,69 +93,11 @@ def bench_builder(report: dict) -> None:
         "fits": bool(n2 <= g.max_nodes and e2 <= g.max_edges)}
 
 
-def bench_segment_crossover(report: dict) -> None:
-    import jax
-    import jax.numpy as jnp
-
-    if jax.default_backend() != "tpu":
-        report["pallas_crossover"] = {"skipped": "no TPU backend"}
-        return
-    from nerrf_tpu.ops import pallas_segment
-    from nerrf_tpu.ops import segment as seg
-
-    # which kernels the flagship train step will actually dispatch to on
-    # this backend (after the register-time Mosaic probe)
-    report["kernel_path"] = seg.active_impls()
-
-    rows = []
-    # (nodes, edges, feature width): the first row IS the flagship training
-    # shape (configs/joint-100h.json 1024/2048, hidden=160) — the crossover
-    # question only matters if it is answered at the shape training runs
-    shapes = [(1024, 2048, 160), (256, 512, 128), (1024, 2048, 128),
-              (2048, 4096, 128), (4096, 8192, 128), (8192, 16384, 128)]
-    for n, e, F in shapes:
-        rng = np.random.default_rng(0)
-        ids = np.sort(rng.integers(0, n, e)).astype(np.int32)
-        data = rng.normal(size=(e, F)).astype(np.float32)
-        ids_d, data_d = jnp.asarray(ids), jnp.asarray(data)
-
-        def timed(fn):
-            out = fn(ids_d, data_d)
-            sync_result(out)
-            t0 = time.perf_counter()
-            reps = 50
-            for _ in range(reps):
-                out = fn(ids_d, data_d)
-            np.asarray(out[0, 0])  # sync via readback
-            return (time.perf_counter() - t0) / reps * 1e6
-
-        xla_us = timed(jax.jit(
-            lambda i, d, n=n: jax.ops.segment_sum(
-                d, i, num_segments=n, indices_are_sorted=True)))
-        pal_us = timed(jax.jit(
-            lambda i, d, n=n: pallas_segment.segment_sum(
-                d, i, num_segments=n)))
-        srt_us = timed(jax.jit(
-            lambda i, d, n=n: pallas_segment.segment_sum_sorted(
-                d, i, num_segments=n)))
-        best = min(xla_us, pal_us, srt_us)
-        rows.append({"nodes": n, "edges": e, "feat": F,
-                     "xla_us": round(xla_us, 1),
-                     "pallas_dense_us": round(pal_us, 1),
-                     "pallas_sorted_us": round(srt_us, 1),
-                     "winner": ("xla" if best == xla_us else
-                                "pallas_dense" if best == pal_us else
-                                "pallas_sorted")})
-        _log(f"  segsum n={n} e={e}: xla {xla_us:.0f}us "
-             f"dense {pal_us:.0f}us sorted {srt_us:.0f}us")
-    report["pallas_crossover"] = rows
-
-
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--out", default="benchmarks/results/graph_capacity.json")
     ap.add_argument("--cpu", action="store_true",
-                    help="force CPU (skips the Pallas crossover leg)")
+                    help="force CPU")
     args = ap.parse_args(argv)
 
     if args.cpu:
@@ -175,7 +110,10 @@ def main(argv=None) -> int:
     enable_compilation_cache()
     report: dict = {"generated": time.strftime("%Y-%m-%d %H:%M:%S")}
     bench_builder(report)
-    bench_segment_crossover(report)
+    from nerrf_tpu.ops.segment import active_impls
+
+    # the routes a step's ops take on this backend
+    report["kernel_path"] = active_impls()
     out = Path(args.out)
     out.parent.mkdir(parents=True, exist_ok=True)
     out.write_text(json.dumps(report, indent=2) + "\n")

@@ -5,18 +5,20 @@ The 28-layer GraphSAGE-T's neighbor aggregation is the hot op of every
 forward the system runs, and `GraphSAGEConfig.aggregation="auto"` must route
 each node bucket to the shape that actually wins there — a threshold that
 should come from measured numbers, not the r5 anecdote.  This bench sweeps
-the three parity-tested aggregation shapes across the deployment buckets and
-records, per (mode, bucket):
+the three parity-tested aggregation shapes (every one an XLA composition;
+benchmarks/results/kernel_bench_v5e.json is the record of the sweeps that
+also ran the hand-written kernels PR 31 deleted) across the deployment
+buckets and records, per (mode, bucket):
 
   * per-layer aggregation time (one aggregation call == one layer's work),
   * the one-off per-forward precompute cost the mode amortizes over the
     28 layers (adjacency build / sorted-view normalization),
-  * sequential kernel launches per layer — the quantity the r5 profile
-    showed dominating at ~0.27 ms fixed cost per launch: segment ≈ 6
-    (2 gathers + 2×2 segment-mean sums), dense_adj = 1 matmul, fused = 1
-    `sage_aggregate` kernel,
+  * sequential device ops per layer — the quantity the r5 profile showed
+    dominating at ~0.27 ms fixed cost per launch: segment ≈ 6 (2 gathers +
+    2×2 segment-mean sums), dense_adj = 1 matmul, fused = 1
+    `sage_aggregate` composition,
   * `kernel_path` (ops.active_impls()) so every number is attributed to the
-    implementation that actually served it (TpuGraphs' lesson, arXiv:
+    routes that actually served it (TpuGraphs' lesson, arXiv:
     2308.13490: a runtime number without its kernel config is unusable).
 
 `--stack` runs the leg the `auto` crossover is read from, measured where
@@ -32,15 +34,12 @@ compile (out of memory) recorded as not fitting.
 `--head-gather` is the sweep `ops.gather_rows`' route on a TPU was chosen
 by (`ops.segment.SELECTION_MATMUL_MAX_ROWS`): the heads' two gathers of E
 rows from an [N, 160] table and their adjoints, batch 8, on the compiler's
-gather, on one selection matmul each way and on the Pallas one-hot kernel;
-its rows go under the artifact's `head_gather` key.
+gather and on one selection matmul each way; its rows go under the
+artifact's `head_gather` key.
 
 Off-TPU the wall-clock columns are degraded (XLA-CPU serves all modes; the
-artifact says so) but the kernel-count attribution and the O(N²)-vs-O(E)
-work ratio still hold; an `interpret_parity` leg additionally runs the fused
-Pallas kernel in interpreter mode at the smallest bucket to pin its
-numerics to the segment oracle inside the same artifact.  The `auto`
-routing threshold (`DENSE_ADJ_MAX_NODES`, nerrf_tpu/models/graphsage.py)
+artifact says so) but the op-count attribution and the O(N²)-vs-O(E)
+work ratio still hold.  The `auto` routing threshold (`DENSE_ADJ_MAX_NODES`, nerrf_tpu/models/graphsage.py)
 cites the artifact this script writes.
 
 Usage:
@@ -66,9 +65,9 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
 import numpy as np
 
-# sequential kernel launches per layer per mode — the launch-overhead
+# sequential device ops per layer per mode — the launch-overhead
 # attribution (segment: fwd gather + fwd sum + fwd denom + rev gather +
-# rev sum + rev denom; the one-kernel modes are the point of this PR)
+# rev sum + rev denom)
 KERNELS_PER_LAYER = {"segment": 6, "dense_adj": 1, "fused": 1}
 
 
@@ -116,7 +115,7 @@ def bench_bucket(n, e, hidden, iters, dtype, fetch, report_rows):
         np.random.default_rng(n + 1).normal(size=(n, hidden)), dtype)
     w_dt = w32.astype(dtype)
 
-    # --- segment: the 6-kernel per-layer path (SageBlock's shape) -----------
+    # --- segment: the 6-op per-layer path (SageBlock's shape) -----------
     src_sorted = jnp.asarray(src_np[order_np])
     dst_srcorder = jnp.asarray(dst_np[order_np])
     w_s = jnp.asarray(w_np[order_np]).astype(dtype)
@@ -145,7 +144,7 @@ def bench_bucket(n, e, hidden, iters, dtype, fetch, report_rows):
     adj = _build_adj(w32)
     agg_dense = jax.jit(lambda m: adj @ m)
 
-    # --- fused: one sage_aggregate kernel per layer -------------------------
+    # --- fused: one sage_aggregate call per layer ---------------------------
     agg_fused = jax.jit(lambda m: sage_aggregate(m, *edges, n))
 
     modes = {}
@@ -282,7 +281,6 @@ def _gather_candidates():
     import jax
     import jax.numpy as jnp
 
-    from nerrf_tpu.ops import pallas_segment
     from nerrf_tpu.ops import segment as seg
 
     def take(table, idx):
@@ -302,9 +300,6 @@ def _gather_candidates():
         "xla_selection_matmul": (seg._gather_select,) * 2,
         "xla_take_fwd_selection_adjoint": (seg._f32_adjoint(
             take, lambda g, idx, n: seg._select(idx, n, g, 0)),) * 2,
-        # (c) the Pallas one-hot kernel and its dense segment-sum adjoint
-        "pallas_blocked": (
-            lambda t, i: pallas_segment.gather_rows(t, i, False),) * 2,
     }
 
 
@@ -396,33 +391,6 @@ def bench_head_gather(n, e, hidden, batch, reps, iters, dtype):
             "gather_rows_takes": gather_rows_route(n)}
 
 
-def interpret_parity(hidden):
-    """Run the fused Pallas kernel in interpreter mode at the smallest
-    bucket against the XLA composition that serves production off-TPU
-    (ops.segment.sage_aggregate_xla) over the MODEL's own view builder, so
-    the artifact carries the kernel's numerics alongside its timings
-    (degraded-CPU acceptance path)."""
-    import jax.numpy as jnp
-
-    from nerrf_tpu.models.graphsage import fused_edge_views
-    from nerrf_tpu.ops import pallas_segment
-    from nerrf_tpu.ops.segment import sage_aggregate_xla
-
-    n, e = 256, 512
-    src_np, dst_np, w_np = _graph(n, e, seed=99)
-    edges, _, _, _, _ = fused_edge_views(
-        jnp.asarray(src_np), jnp.asarray(dst_np), jnp.asarray(w_np), n)
-    msg = jnp.asarray(
-        np.random.default_rng(100).normal(size=(n, hidden)), jnp.float32)
-
-    got = pallas_segment.sage_aggregate_fused(msg, *edges, n, True)
-    want = sage_aggregate_xla(msg, *edges, n)
-    err = float(jnp.max(jnp.abs(got - want)))
-    _log(f"interpret-mode fused parity at 256n/512e: max_abs_err={err:.2e}")
-    return {"nodes": n, "edges": e, "max_abs_err": err,
-            "pallas_calls_per_layer": 1, "ok": bool(err < 1e-4)}
-
-
 def _dense_minus_fused(row):
     """dense_adj's time less fused's at one bucket, in ms: of the batched
     forward + backward stack where the sweep ran it (`--stack`: the rule's
@@ -438,7 +406,7 @@ def _dense_minus_fused(row):
 
 
 def measured_crossover(rows):
-    """The smallest node count where the fused kernel's time matches
+    """The smallest node count where the fused mode's time matches
     dense_adj's, log-interpolated between swept buckets — the number the
     `nerrf tune` kernel-routing prior cites.  None when one mode dominates
     every bucket both ran at (no crossing to cite: the prior then falls
@@ -568,10 +536,9 @@ def main(argv=None) -> int:
         "iters": args.iters,
         "kernel_path": active_impls(),
         "buckets": rows,
-        "interpret_parity": interpret_parity(args.hidden),
         "routing": {
             "auto_rule": "tpu: dense_adj if nodes <= dense_adj_max_nodes "
-                         "else fused; off-tpu: segment",
+                         "else segment; off-tpu: segment",
             "dense_adj_max_nodes": DENSE_ADJ_MAX_NODES,
             "dense_adj_max_nodes_consumer":
                 "nerrf_tpu/models/graphsage.py DENSE_ADJ_MAX_NODES (cites "

@@ -9,16 +9,17 @@ the entry points a user calls, at the full width of the flagship model
 
   barrier   a jitted call timed to `jax.block_until_ready` and to
             `utils.fetch_value` — the two barriers must agree
-  kernels   each of the five Pallas kernels, compiled, and the XLA
-            composition it stands in for, against a host reference at
-            1024n/2048e/160 and 4096n/8192e/160: forward and VJP, under vmap;
-            `ops.gather_rows` (the selection matmul on a TPU) beside the
-            Pallas gather
+  kernels   the ops that have two routes, each route against a host
+            reference at 1024n/2048e/160 and 4096n/8192e/160, forward and
+            VJP, under vmap: `ops.gather_rows` (the selection matmul on a
+            TPU) beside `jnp.take`, and the SAGE aggregate as gathers and
+            scatter-adds (`segment`) beside the adjacency matmul
+            (`dense_adj`)
   train     `nerrf_tpu.train.run` on an experiment that copies
             configs/joint-100h.json's `dataset` and `train.model` and shrinks
             only the corpus and the step count: at 1024n/2048e and,
             re-padded, at 4096n/8192e (`auto` takes the dense adjacency at
-            both; the fused kernel is checked by the kernels phase)
+            both)
   serve     `nerrf serve-detect` on the checkpoint the trainer wrote, a
             sparse and a dense seeded trace, over both buckets
   four      `train.run` on configs/multihost-online.json's dp x tp mesh —
@@ -27,8 +28,8 @@ the entry points a user calls, at the full width of the flagship model
 A phase that fails raises: nothing is caught, logged and continued.  The
 last line of stdout is one JSON object naming the device as JAX reports it.
 
-`--rehearsal` runs the same code on the CPU at a toy width with the Pallas
-kernels in interpret mode — for debugging the script, and for tier-1.  It
+`--rehearsal` runs the same code on the CPU at a toy width — for debugging
+the script, and for tier-1.  It
 stamps `rehearsal` on every line it prints; a number from it is not a
 device metric.
 """
@@ -219,17 +220,18 @@ def barrier_phase(n: int) -> None:
           f"({fetch * 1e3:.2f} ms) disagree about when the call ended")
 
 
-def kernels_phase(shapes, interpret: bool) -> None:
-    """The five Pallas kernels AND the XLA compositions they stand in for,
-    each against a host (numpy, float64) reference: forward and VJP, jitted,
-    under vmap, at the tolerance the chip-gated CPU tests use.  The oracle
-    is the host, not XLA: either side can be the one that is wrong."""
+def kernels_phase(shapes) -> None:
+    """The ops that have more than one route, each route against a host
+    (numpy, float64) reference: forward and VJP, jitted, under vmap.  Every
+    route is code the compiler writes; the oracle is the host, not one of
+    them: either side can be the one that is wrong."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from nerrf_tpu.ops import gather_rows, pallas_segment as pk
-    from nerrf_tpu.ops.segment import gather_rows_route, sage_aggregate_xla
+    from nerrf_tpu.models.graphsage import dense_adjacency
+    from nerrf_tpu.ops import gather_rows, sage_aggregate
+    from nerrf_tpu.ops.segment import gather_rows_route
 
     B, tol = 2, 2e-4
 
@@ -267,53 +269,47 @@ def kernels_phase(shapes, interpret: bool) -> None:
                   f"{name} [{label}] disagrees with the host reference: "
                   f"fwd {e_fwd:.2e} vjp {e_vjp:.2e}")
 
-    mode = "pallas interpret" if interpret else "pallas compiled"
     for N, E, F in shapes:
         rng = np.random.default_rng(N)
-        ids = rng.integers(0, N, (B, E)).astype(np.int32)
-        dst = np.sort(ids, axis=1)                       # nondecreasing ids
-        data = rng.normal(size=(B, E, F)).astype(np.float32)
+        src = rng.integers(0, N, (B, E)).astype(np.int32)
+        dst = np.sort(rng.integers(0, N, (B, E)), axis=1).astype(np.int32)
         table = rng.normal(size=(B, N, F)).astype(np.float32)
         shape = f"@{N}n/{E}e/{F}"
 
-        compare(f"segment_sum{shape}", {
-            mode: jax.vmap(lambda d, i: pk.segment_sum(d, i, N, interpret)),
-            "xla": jax.vmap(lambda d, i: jax.ops.segment_sum(
-                d, i, num_segments=N))},
-            scatter(data, ids, N), lambda g: gather(g, ids),
-            jnp.asarray(data), jnp.asarray(ids))
-        # its VJP is the fourth kernel: the banded (sorted) gather adjoint
-        compare(f"segment_sum_sorted+gather_sorted_adjoint{shape}", {
-            mode: jax.vmap(lambda d, i: pk.segment_sum_sorted(
-                d, i, N, interpret)),
-            "xla": jax.vmap(lambda d, i: jax.ops.segment_sum(
-                d, i, num_segments=N, indices_are_sorted=True))},
-            scatter(data, dst, N), lambda g: gather(g, dst),
-            jnp.asarray(data), jnp.asarray(dst))
         compare(f"gather_rows{shape}", {
-            mode: jax.vmap(lambda t, i: pk.gather_rows(t, i, interpret)),
             f"ops: {gather_rows_route(N)}": jax.vmap(gather_rows),
-            "xla": jax.vmap(lambda t, i: jnp.take(t, i, axis=0))},
-            gather(table, ids), lambda g: scatter(g, ids, N),
-            jnp.asarray(table), jnp.asarray(ids))
+            "take": jax.vmap(lambda t, i: jnp.take(t, i, axis=0))},
+            gather(table, src), lambda g: scatter(g, src, N),
+            jnp.asarray(table), jnp.asarray(src))
 
-        # both sorted views of one random graph, weights in both orders
-        src = ids
+        # one random graph in both sorted orders, weights in both: the
+        # aggregate as `segment` / `fused` sum it (gathers and scatter-adds)
+        # and as `dense_adj` does (one matmul against the [N,N] adjacency;
+        # float32 at `highest`, or the MXU's bf16 passes are what differs)
         order = np.argsort(src, axis=1)
         by_src = lambda a: np.take_along_axis(a, order, 1)
         wf = rng.uniform(0.1, 1.0, (B, E)).astype(np.float32)
         wr = rng.uniform(0.1, 1.0, (B, E)).astype(np.float32)
         edges = (dst, src, by_src(src), by_src(dst),
                  wf, by_src(wf), by_src(wr), wr)
-        compare(f"sage_aggregate_fused{shape}", {
-            mode: jax.vmap(lambda m, *e: pk.sage_aggregate_fused(
-                m, *e, N, interpret)),
-            "xla": jax.vmap(lambda m, *e: sage_aggregate_xla(m, *e, N))},
-            scatter(wf[..., None] * gather(table, src), dst, N)
-            + scatter(wr[..., None] * gather(table, dst), src, N),
-            lambda g: scatter(wf[..., None] * gather(g, dst), src, N)
-            + scatter(wr[..., None] * gather(g, src), dst, N),
-            jnp.asarray(table), *map(jnp.asarray, edges))
+        one, zero = jnp.ones((N,), jnp.float32), jnp.zeros((N,), jnp.float32)
+
+        def dense(m, dst, src, _s, _d, wf, _wfs, _wrs, wr):
+            # the model's own build, once a direction (its two weights are
+            # one vector there; here they differ so that a swap would show)
+            fwd = dense_adjacency(src, dst, wf, one, zero, N, m.dtype)
+            rev = dense_adjacency(src, dst, wr, zero, one, N, m.dtype)
+            return (fwd + rev) @ m
+
+        with jax.default_matmul_precision("highest"):
+            compare(f"sage_aggregate{shape}", {
+                "segment": jax.vmap(lambda m, *e: sage_aggregate(m, *e, N)),
+                "dense_adj": jax.vmap(dense)},
+                scatter(wf[..., None] * gather(table, src), dst, N)
+                + scatter(wr[..., None] * gather(table, dst), src, N),
+                lambda g: scatter(wf[..., None] * gather(g, dst), src, N)
+                + scatter(wr[..., None] * gather(g, src), dst, N),
+                jnp.asarray(table), *map(jnp.asarray, edges))
 
 
 def _experiment(cfg: dict, source: str, name: str, bucket=None,
@@ -371,10 +367,8 @@ def train_phase(cfg: dict, work: Path, aot, idx: int) -> Path:
     modes = (kp.pop("gnn_aggregation"), kp.pop("lstm_impl"))
     check(modes == (want_mode, "fused"),
           f"aggregation/LSTM resolved to {modes}, not ({want_mode}, fused)")
-    # the row gather is compiler-written (on a TPU a selection matmul);
-    # the segment ops a step could reach are the Pallas kernels
-    check(kp.pop("gather_rows") == cfg["gather_route"]
-          and all(v.startswith("pallas_") for v in kp.values()),
+    # nothing else has a route: every op is compiler-written
+    check(kp == {"gather_rows": cfg["gather_route"]},
           f"not the routes a TPU takes: {report['kernel_path']}")
     return work / exp["name"] / "model"
 
@@ -442,8 +436,8 @@ def main(argv=None) -> int:
     global TAG
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--rehearsal", action="store_true",
-                    help="CPU, toy width, Pallas in interpret mode; every "
-                         "line says so and nothing printed is a device metric")
+                    help="CPU, toy width; every line says so and nothing "
+                         "printed is a device metric")
     args = ap.parse_args(argv)
     cfg = REHEARSAL if args.rehearsal else CHIP
     TAG = " rehearsal" if args.rehearsal else ""
@@ -469,11 +463,7 @@ def main(argv=None) -> int:
         f"jaxlib={jaxlib.__version__} libtpu={libtpu_version} "
         f"compile_cache={compile_cache_dir()} "
         f"(entries: {_cache_entries()})")
-    if args.rehearsal:
-        from nerrf_tpu.ops import pallas_segment
-
-        pallas_segment.register(interpret=True)
-    else:
+    if not args.rehearsal:
         if dev.platform != "tpu":
             raise SystemExit(
                 f"chip_smoke: no TPU — JAX picked {dev.platform!r}; the "
@@ -494,7 +484,7 @@ def main(argv=None) -> int:
         with phase("barrier"):
             barrier_phase(cfg["barrier_n"])
         with phase("kernels"):
-            kernels_phase(cfg["kernel_shapes"], interpret=args.rehearsal)
+            kernels_phase(cfg["kernel_shapes"])
         for idx, bucket in enumerate(cfg["train_buckets"]):
             with phase(f"train@{bucket[0]}n/{bucket[1]}e"):
                 model_dir = train_phase(cfg, work, aot, idx)

@@ -1,6 +1,6 @@
 """nerrf_tpu — a TPU-native undo-computing framework.
 
-A ground-up JAX/XLA/Pallas implementation of the capability set specified by the
+A ground-up JAX/XLA implementation of the capability set specified by the
 NERRF reference (Itz-Agasta/nerrf): streaming syscall-event ingest, a temporal
 dependency graph, GraphSAGE-T + BiLSTM attack detection, an MCTS rollback
 planner with batched value-net rollouts on TPU, and a verified file-level

@@ -15,7 +15,7 @@ model in ``locks.py``), and the deep (jaxpr-level) program contracts in
 ``nerrf_tpu/analysis/programs/`` — abstract tracing of the real
 serve/train/parallel entry points behind ``nerrf lint --deep``
 (signature closure, donation discipline, collective/sharding
-consistency, Pallas VMEM budgets, cache-key coverage).
+consistency, cache-key coverage).
 
 Entry points: ``python scripts/nerrflint.py [--deep]``, ``nerrf lint``
 (CLI), ``tests/test_analysis.py`` / ``tests/test_programs.py`` (the

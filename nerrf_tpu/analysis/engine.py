@@ -288,7 +288,7 @@ def main(argv=None) -> int:
     ap.add_argument("--deep", action="store_true",
                     help="also run the jaxpr-level program-contract rules "
                          "(signature closure, donation, collectives, "
-                         "Pallas budgets, cache-key coverage) — imports "
+                         "cache-key coverage) — imports "
                          "jax and forces a virtual multi-device CPU "
                          "backend; ~20 s instead of ~2 s")
     args = ap.parse_args(argv)

@@ -2,10 +2,9 @@
 
 Where the base rules (`nerrf_tpu/analysis/*.py`) read source ASTs, these
 rules abstractly trace the *real entry points* — the serve bucket ladder,
-the flat train-step boundary, the shard_map/pjit shims, the Pallas
-kernels — via `jax.eval_shape`/`jax.make_jaxpr`/`jit.lower` over
+the flat train-step boundary, the shard_map/pjit shims — via `jax.eval_shape`/`jax.make_jaxpr`/`jit.lower` over
 `ShapeDtypeStruct` avals (no devices, no data, no compiles) and verify
-five contracts:
+four contracts:
 
   ============================  ============================================
   program-closure               warmup-compiled set == admission-reachable
@@ -14,8 +13,6 @@ five contracts:
                                 wasted/forbidden/double donation
   collective-consistency        collective axis names vs the mesh spec,
                                 PartitionSpec rank-match
-  pallas-budget                 block shapes × dtype vs the VMEM budget,
-                                tile/grid divisibility
   cache-key-coverage            jaxpr dependencies the CompileCache
                                 fingerprint cannot see
   ============================  ============================================
@@ -36,18 +33,16 @@ from nerrf_tpu.analysis.programs.cachekey import CacheKeyCoverage
 from nerrf_tpu.analysis.programs.closure import SignatureClosure
 from nerrf_tpu.analysis.programs.collectives import CollectiveConsistency
 from nerrf_tpu.analysis.programs.donation import DonationDiscipline
-from nerrf_tpu.analysis.programs.pallas_budget import PallasBudget
 
 DEEP_RULE_IDS = ("program-closure", "donation-discipline",
-                 "collective-consistency", "pallas-budget",
-                 "cache-key-coverage")
+                 "collective-consistency", "cache-key-coverage")
 
 
 def deep_rules():
     """The deep ruleset, in contract order (engine.main --deep appends
     these to the base rules)."""
     return [SignatureClosure(), DonationDiscipline(),
-            CollectiveConsistency(), PallasBudget(), CacheKeyCoverage()]
+            CollectiveConsistency(), CacheKeyCoverage()]
 
 
 __all__ = [
@@ -55,7 +50,6 @@ __all__ = [
     "CollectiveConsistency",
     "DEEP_RULE_IDS",
     "DonationDiscipline",
-    "PallasBudget",
     "SignatureClosure",
     "deep_rules",
     "prepare_backend",

@@ -8,8 +8,8 @@ execution-free tensor-program regime of TpuGraphs (arXiv:2308.13490) and
 the configuration cross-attention predictor (arXiv:2405.16623).  That lets
 the chip-queue pre-flight *prove* contracts on CPU in seconds that today
 only surface by burning accelerator minutes: warmup signature closure,
-donation aliasing, collective axis validity, Pallas VMEM budgets, and
-compile-cache key coverage.
+donation aliasing, collective axis validity, and compile-cache key
+coverage.
 
 Everything here defers its jax import to call time: the base engine (and
 the plain ``nerrf lint`` tier-1 gate) must stay importable with no jax on
@@ -245,9 +245,16 @@ def big_consts(closed_jaxpr, min_bytes: int) -> List[Tuple[tuple, str, int]]:
     least ``min_bytes`` baked into the jaxpr — the material a cache
     fingerprint cannot see (it hashes argument avals, and a capture is not
     an argument)."""
+    import numpy as np
+
     out = []
     for c in closed_jaxpr.consts:
-        nbytes = int(getattr(c, "nbytes", 0) or 0)
+        # from shape and dtype, not `.nbytes`: the wrapper jax 0.9 hands a
+        # captured numpy array back in (`TypedNdArray`) has no such field
+        try:
+            nbytes = int(np.prod(c.shape)) * np.dtype(c.dtype).itemsize
+        except (AttributeError, TypeError):   # a captured non-array
+            continue
         if nbytes >= min_bytes:
             out.append((tuple(getattr(c, "shape", ())),
                         str(getattr(c, "dtype", type(c).__name__)), nbytes))

@@ -799,8 +799,8 @@ def cmd_lint(args) -> int:
     (tests/test_analysis.py); rule catalog in docs/static-analysis.md.
     Deliberately NO jax import — safe on any host and never takes the
     chip.  ``--deep`` adds the jaxpr-level program-contract tier
-    (signature closure, donation, collectives, Pallas budgets, cache-key
-    coverage): it imports jax but forces a virtual CPU backend, so it
+    (signature closure, donation, collectives, cache-key coverage): it
+    imports jax but forces a virtual CPU backend, so it
     needs no accelerator either."""
     from nerrf_tpu.analysis.engine import main as lint_main
 
@@ -2179,8 +2179,8 @@ def main(argv=None) -> int:
                    help="print the rule catalog and exit")
     p.add_argument("--deep", action="store_true",
                    help="also verify the jaxpr-level program contracts "
-                        "(signature closure, donation, collectives, Pallas "
-                        "budgets, cache-key coverage) — abstract tracing "
+                        "(signature closure, donation, collectives, "
+                        "cache-key coverage) — abstract tracing "
                         "on a virtual CPU backend, no devices needed")
     p.add_argument("--rule", action="append", default=None, metavar="ID",
                    help="run only this rule (repeatable)")

@@ -44,8 +44,8 @@ EXECUTABLES_DIR = "executables"
 def serve_program_key(model_cfg, bucket_tag: str) -> dict:
     """The caller-side key material for one serve bucket program: the
     model architecture (same param pytree, different HLO — e.g. fuse mode
-    or aggregation routing) plus the kernel switchboard state the lowered
-    graph depends on.  Warmup and export MUST build keys through here or a
+    or aggregation routing) plus the routes the backend gives the ops
+    (`ops.segment.active_impls`), which the lowered graph depends on.  Warmup and export MUST build keys through here or a
     published executable would never be found at boot."""
     from nerrf_tpu.ops.segment import active_impls
 

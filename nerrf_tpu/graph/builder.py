@@ -177,8 +177,8 @@ class GraphBatch:
     """One padded window graph (all arrays fixed-shape, device-ready).
 
     Edges are sorted by ``edge_dst`` so neighbor aggregation is a single
-    segment-sum over a monotone segment-id vector — the layout the Pallas
-    aggregation kernel and `jax.ops.segment_sum` both want.
+    segment-sum over a monotone segment-id vector — the layout
+    `jax.ops.segment_sum` takes as `indices_are_sorted`.
     """
 
     node_feat: np.ndarray  # float32 [max_nodes, NODE_FEATURE_DIM]

@@ -7,7 +7,8 @@ ROC-AUC ≥ 0.90) as a pure-JAX flax module, built TPU-first:
 
 * message passing is a dense matmul + sorted segment reduction (the layout
   the graph builder guarantees), so the MXU does the FLOPs and aggregation is
-  one bandwidth-bound pass handled by `nerrf_tpu.ops` (Pallas on TPU);
+  one pass handled by `nerrf_tpu.ops`, or on a TPU one more matmul against
+  a per-window adjacency (`GraphSAGEConfig.aggregation`);
 * all shapes are static (padded graphs with masks), so the whole forward jits
   once regardless of window content;
 * compute runs in bfloat16 with float32 params (`dtype`/`param_dtype` split),
@@ -38,23 +39,20 @@ from nerrf_tpu.ops import gather_rows, sage_aggregate, segment_mean
 # v5e in benchmarks/results/kernel_bench_v5e.json (`python
 # benchmarks/run_kernel_bench.py --stack`: a stack of 28 aggregates, forward
 # and backward, vmap batch 8, H = 160, bf16, e = 2n, the [N,N] build inside).
-# No crossover in time was found: dense_adj wins by 30x / 25x / 12x / 10x
-# at 1024 / 2048 / 4096 / 8192 (34 ms against 408 for the stack at 4096; the
+# No crossover in time was found: dense_adj beat what the sweep ran beside
+# it (the hand-written kernels that `fused` and `segment` rode on a TPU until
+# PR 31) by 30x / 25x / 12x / 10x and more at 1024 / 2048 / 4096 / 8192 (the
 # whole step, PERF.md §6 PR 27: 160 ms against 549 at 4096, 459 against
-# 1,522 at 8192).  The fused kernel's grid is 2 x N/128 x E/128 steps, mostly
-# empty, so its time too grows faster than its O(E) work (2.3-2.9x a
-# doubling of N against the matmul's 2.9-5.2x): the gap narrows and does
-# not close.  Memory sets the bound: at 8192 the step holds 9.1 GB of 16 (an
-# [8,N,N] f32 scatter, its transpose and the bf16 adjacency kept for the
-# backward); at 16384 the compiler refuses the batch-8 stack (20.0 GB), and
-# refuses the fused kernel too (VMEM), so only `segment` runs there.  Move
-# this only with a new sweep.
+# 1,522 at 8192).  Memory sets the bound: at 8192 the step holds 9.1 GB of
+# 16 (an [8,N,N] f32 scatter, its transpose and the bf16 adjacency kept for
+# the backward); at 16384 the compiler refuses the batch-8 stack (20.0 GB),
+# so `segment` serves there.  Move this only with a new sweep.
 DENSE_ADJ_MAX_NODES = 8192
 
 
 def fused_edge_views(edge_src, edge_dst, w32, num_nodes):
-    """Per-forward normalized edge views for the one-kernel-per-layer
-    aggregation modes — THE single definition of the precompute both
+    """Per-forward normalized edge views for the aggregation modes that
+    do one op a layer — THE single definition of the precompute both
     `GraphSAGET` and the kernel microbenchmark
     (benchmarks/run_kernel_bench.py) run, so the artifact the `auto`
     routing threshold cites cannot drift from the shape the model
@@ -105,20 +103,19 @@ class GraphSAGEConfig:
     num_layers: int = 28
     dropout: float = 0.1
     dtype: Any = jnp.bfloat16
-    # Three parity-tested aggregation shapes (docs/kernel-paths.md):
-    # "fused": ONE Pallas kernel per layer, O(E) useful work — blocked-CSR
-    # bands over the builder's dst-sorted edges plus the per-window
-    # src-sorted view, gather + weight + scatter-accumulate fused in VMEM
-    # (ops.sage_aggregate; XLA composition with identical semantics
-    # off-TPU).  "dense_adj": ONE [N,N]@[N,H] matmul per layer against a
-    # normalized adjacency built once per forward — pure MXU work, O(N²·H),
-    # and on a v5e the fastest of the three at every bucket it fits
-    # (0.04-2.7 ms a layer, forward + backward, batch 8, from 1024 to 8192
-    # nodes, against 2.5-42 for "fused" and 4.7-280 for "segment":
-    # benchmarks/results/kernel_bench_v5e.json).  "segment": per-layer
-    # gather + banded-segment-mean — the portable parity oracle.  "auto"
-    # (default): on TPU, dense_adj up to DENSE_ADJ_MAX_NODES and fused above
-    # it; segment elsewhere.
+    # Three parity-tested aggregation shapes (docs/kernel-paths.md), every
+    # one of them compiler-written on every backend.  "dense_adj": ONE
+    # [N,N]@[N,H] matmul per layer against a normalized adjacency built once
+    # per forward: pure MXU work, O(N²·H), and on a v5e the fastest at every
+    # bucket it fits (0.04-2.7 ms a layer, forward + backward, batch 8, from
+    # 1024 to 8192 nodes: benchmarks/results/kernel_bench_v5e.json).
+    # "segment": per-layer row gather + weighted segment mean, O(E); what
+    # every backend but a TPU runs, and a TPU past DENSE_ADJ_MAX_NODES.
+    # "fused": the same sums over edge views normalized once per forward
+    # (ops.sage_aggregate), so a layer is two gathers and two scatter-adds
+    # with no division; kept because the benchmark's reference test names
+    # it (ROADMAP.md, named debts).  "auto" (default): on a TPU dense_adj up
+    # to DENSE_ADJ_MAX_NODES, segment everywhere else.
     aggregation: str = "auto"
     # Per-rung kernel routing table fitted by `nerrf tune` (docs/tuning.md):
     # sorted ((max_nodes, mode), ...) pairs consulted BEFORE the auto
@@ -162,9 +159,8 @@ class GraphSAGEConfig:
         is the padded node bucket: a tuned per-rung routing table (see
         ``routing``) wins first; otherwise on TPU, `auto` takes the dense
         adjacency wherever it was measured to win the step and fit
-        (≤ DENSE_ADJ_MAX_NODES — see the constant) and routes bigger
-        buckets to the fused O(E) kernel; with no bucket given it assumes
-        the large-bucket answer."""
+        (≤ DENSE_ADJ_MAX_NODES — see the constant); bigger buckets, a call
+        with no bucket given and every other backend get `segment`."""
         if self.aggregation != "auto":
             if self.aggregation not in ("fused", "dense_adj", "segment"):
                 raise ValueError(
@@ -175,11 +171,10 @@ class GraphSAGEConfig:
             for cap, mode in self.routing:  # sorted: smallest cover wins
                 if num_nodes <= cap:
                     return mode
-        if jax.default_backend() != "tpu":
-            return "segment"
-        if num_nodes is not None and num_nodes <= DENSE_ADJ_MAX_NODES:
+        if (jax.default_backend() == "tpu" and num_nodes is not None
+                and num_nodes <= DENSE_ADJ_MAX_NODES):
             return "dense_adj"
-        return "fused"
+        return "segment"
 
 
 class SageBlock(nn.Module):
@@ -204,11 +199,11 @@ class SageBlock(nn.Module):
         ).astype(self.dtype)
         if fused_view is not None:
             # fused aggregation: the whole bidirectional weighted mean of
-            # `msg` is ONE sage_aggregate call (a single Pallas kernel on
-            # TPU) over GraphSAGET's pre-normalized sorted edge views —
-            # same decomposition as the dense path below (e_emb's mean in
-            # c_sum, the empty-segment zeroing in s_f/s_r), but O(E) work
-            # and no [N,N] materialization
+            # `msg` is ONE sage_aggregate call over GraphSAGET's
+            # pre-normalized sorted edge views — same decomposition as the
+            # dense path below (e_emb's mean in c_sum, the empty-segment
+            # zeroing in s_f/s_r), but O(E) work and no [N,N]
+            # materialization
             edges, c_sum, s_f, s_r = fused_view
             agg = (sage_aggregate(msg, *edges, num_nodes) + c_sum
                    + dir_bias[0] * s_f[:, None] + dir_bias[1] * s_r[:, None])
@@ -224,7 +219,7 @@ class SageBlock(nn.Module):
             # c_sum, and s_f/s_r carry the empty-segment zeroing the
             # segment path gets from its max(denom, eps) guard)
             adj, c_sum, s_f, s_r = dense_view
-            # the scope the fused route's op carries: the same work under
+            # the scope `ops.sage_aggregate` carries: the same work under
             # the same name in a device trace, whichever route served it
             with jax.named_scope("sage_aggregate"):
                 agg = adj @ msg
@@ -234,20 +229,20 @@ class SageBlock(nn.Module):
                 jnp.concatenate([hn, agg], axis=-1)
             )
             return h + nn.gelu(upd)
-        # src→dst messages land on dst (builder-sorted ids: banded fast path)
+        # src→dst messages land on dst (builder-sorted ids)
         m_fwd = gather_rows(msg, edge_src) + e_emb + dir_bias[0]
         agg_fwd = segment_mean(m_fwd, edge_dst, num_nodes, weights=edge_w, sorted_ids=True)
         if rev_view is not None:
             # dst→src messages, iterated in src-sorted edge order (the
             # per-window argsort view GraphSAGET precomputes) so this
-            # direction also rides the banded kernel; summation order
-            # differs only by a permutation
+            # direction's ids are sorted too; summation order differs only
+            # by a permutation
             src_sorted, dst_srcorder, e_emb_s, w_s = rev_view
             m_rev = gather_rows(msg, dst_srcorder) + e_emb_s + dir_bias[1]
             agg_rev = segment_mean(m_rev, src_sorted, num_nodes, weights=w_s,
                                    sorted_ids=True)
         else:
-            # dst→src messages land on src (unsorted ids: dense path)
+            # dst→src messages land on src (unsorted ids)
             m_rev = gather_rows(msg, edge_dst) + e_emb + dir_bias[1]
             agg_rev = segment_mean(m_rev, edge_src, num_nodes, weights=edge_w,
                                    sorted_ids=False)
@@ -304,15 +299,13 @@ class GraphSAGET(nn.Module):
         with jax.named_scope("agg_views"):
             if agg_mode in ("dense_adj", "fused"):
                 # Per-forward aggregation state shared by all layers, so each
-                # of the 28 layers costs ONE kernel (a matmul or the fused
-                # Pallas scatter) — no gather/scatter/normalize on the layer
-                # critical path at all.  fused_edge_views is the shared
-                # precompute (normalizations + both sorted pre-weighted edge
-                # orders; the fused kernel's forward rides one pair, its
-                # adjoint the exchanged pair, so fwd AND bwd stay at one
-                # kernel per layer); the (layer-invariant) e_emb term folds
-                # into c_sum, and s_f/s_r carry the empty-segment zeroing the
-                # segment path gets from its max(denom, eps) guard.
+                # of the 28 layers costs ONE op (a matmul or a
+                # sage_aggregate) with no normalize on the layer critical
+                # path.  fused_edge_views is the shared precompute
+                # (normalizations + both sorted pre-weighted edge orders);
+                # the (layer-invariant) e_emb term folds into c_sum, and
+                # s_f/s_r carry the empty-segment zeroing the segment path
+                # gets from its max(denom, eps) guard.
                 edges, d_fwd, d_rev, inv_f, inv_r = fused_edge_views(
                     edge_src, edge_dst, w32, n)
                 we = w32[:, None] * e_emb.astype(jnp.float32)
@@ -330,9 +323,9 @@ class GraphSAGET(nn.Module):
                 fused_view = (edges, c_sum, s_f, s_r)
             elif agg_mode == "segment":
                 # src-sorted edge view, computed once and shared by every layer:
-                # with it the reverse aggregation also declares sorted ids and
-                # the banded Pallas kernel serves both directions (one [E]
-                # argsort per window vs 28 dense one-hot contractions)
+                # with it the reverse aggregation also declares sorted ids
+                # (one [E] argsort per window; whether `indices_are_sorted`
+                # pays for it is ROADMAP.md's named debt iii)
                 src_order = jnp.argsort(edge_src)
                 rev_view = (
                     jnp.take(edge_src, src_order),   # nondecreasing segment ids

@@ -15,11 +15,11 @@ import dataclasses
 from typing import Any, Dict
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 from nerrf_tpu.models.graphsage import GraphSAGEConfig, GraphSAGET
 from nerrf_tpu.models.lstm import ImpactLSTM, LSTMConfig
-from nerrf_tpu.ops import segment_sum
 
 
 @dataclasses.dataclass(frozen=True)
@@ -60,8 +60,8 @@ class NerrfNet(nn.Module):
             ok = seq_node_idx >= 0
             # route invalid sequences to slot n (dropped by the slice below)
             tgt = jnp.where(ok, seq_node_idx, n)
-            fused = segment_sum(
-                h_seq * ok[:, None].astype(h_seq.dtype), tgt, n + 1, sorted_ids=False
+            fused = jax.ops.segment_sum(
+                h_seq * ok[:, None].astype(h_seq.dtype), tgt, num_segments=n + 1
             )[:n]
             node_feat = node_feat + fused
 

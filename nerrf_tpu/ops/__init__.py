@@ -2,7 +2,6 @@ from nerrf_tpu.ops.segment import (
     gather_rows,
     sage_aggregate,
     segment_mean,
-    segment_sum,
 )
 
-__all__ = ["segment_sum", "segment_mean", "gather_rows", "sage_aggregate"]
+__all__ = ["segment_mean", "gather_rows", "sage_aggregate"]

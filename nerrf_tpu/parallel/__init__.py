@@ -7,7 +7,6 @@ from nerrf_tpu.parallel.mesh import (
 )
 from nerrf_tpu.parallel.train import (
     make_sharded_train_step,
-    mesh_ops,
     shard_batch,
     init_sharded_state,
     make_stream_train_step,
@@ -22,7 +21,6 @@ __all__ = [
     "param_sharding",
     "init_distributed",
     "make_sharded_train_step",
-    "mesh_ops",
     "shard_batch",
     "init_sharded_state",
     "make_stream_train_step",

@@ -11,7 +11,6 @@ no hand-written communication anywhere, per the TPU-first design stance
 
 from __future__ import annotations
 
-import contextlib
 from functools import partial
 from typing import Dict, Optional
 
@@ -24,7 +23,6 @@ from jax.sharding import Mesh
 from typing import TYPE_CHECKING
 
 from nerrf_tpu.models.joint import NerrfNet
-from nerrf_tpu.ops.segment import xla_only
 from nerrf_tpu.parallel.mesh import batch_sharding, param_sharding, replicated
 
 if TYPE_CHECKING:  # runtime import is deferred: models → parallel → train.loop
@@ -37,14 +35,6 @@ def _loop():
     from nerrf_tpu.train import loop
 
     return loop
-
-
-def mesh_ops(mesh: Mesh):
-    """The context a program jitted over ``mesh`` traces its sparse ops
-    under: the XLA compositions once GSPMD has more than one device to
-    partition over (it refuses Mosaic kernels — see `ops.segment.xla_only`),
-    the backend's own registration on a one-device mesh."""
-    return xla_only() if mesh.size > 1 else contextlib.nullcontext()
 
 
 def shard_batch(mesh: Mesh, batch: Dict[str, np.ndarray]) -> Dict[str, jax.Array]:
@@ -82,9 +72,8 @@ def init_sharded_state(
     one = {k: jnp.asarray(v[0]) for k, v in sample.items()}
 
     def init_fn(rng):
-        with mesh_ops(mesh):
-            return model.init(
-                rng, *loop.model_inputs(one), deterministic=True)["params"]
+        return model.init(
+            rng, *loop.model_inputs(one), deterministic=True)["params"]
 
     shapes = jax.eval_shape(init_fn, rng)
     p_shard = param_sharding(mesh, shapes)
@@ -119,9 +108,8 @@ def make_sharded_train_step(model: NerrfNet, cfg: "TrainConfig", mesh: Mesh,
         # telemetry axis (cfg.telemetry) can never drift per flavor —
         # under the mesh the norm reductions become collectives, which is
         # exactly what a sharded health reading should be
-        with mesh_ops(mesh):
-            state, loss, aux, rng = loop._step_body(
-                loss_fn, state, batch, rng, telemetry=cfg.telemetry)
+        state, loss, aux, rng = loop._step_body(
+            loss_fn, state, batch, rng, telemetry=cfg.telemetry)
         # hand the state back in the layout it came in (param_sharding is
         # a rule over leaf names and shapes, so it covers the optimizer's
         # moments too).  Left to GSPMD, the outputs come back in a layout
@@ -152,8 +140,7 @@ def make_sharded_train_step(model: NerrfNet, cfg: "TrainConfig", mesh: Mesh,
         in_shardings=(None, None, None, b_shard, r_shard),
         out_shardings=None)
 
-    with mesh_ops(mesh):  # the key names the ops the program is made of
-        extra = loop.step_key_extra(cfg, "train_step_sharded")
+    extra = loop.step_key_extra(cfg, "train_step_sharded")
     extra["mesh"] = repr(sorted(mesh.shape.items()))
     return loop.CachedTrainStep(compile_cache, flat_step,
                                 program="train_step_sharded", extra=extra)

@@ -28,8 +28,9 @@ from nerrf_tpu.tracing import span as trace_span
 # seq widths); the version catches everything else — bump it whenever the
 # meaning of stamped fields or the param-tree layout changes such that old
 # checkpoints must not load silently.  v2: r4 feature stamp era + the
-# three-way aggregation config ("fused" joins segment/dense_adj — same
-# param tree, so no bump needed for it; recorded here for the audit trail).
+# three aggregation names (segment / dense_adj / fused: XLA compositions
+# of one sum over the same param tree, so no bump for any of them;
+# recorded here for the audit trail).
 SCHEMA_VERSION = 2
 # the oldest stamped schema this code still loads: raise this floor (not
 # just SCHEMA_VERSION) when a change means older checkpoints must not load

@@ -425,9 +425,9 @@ def step_key_extra(cfg: TrainConfig, flavor: str) -> dict:
     """Caller-side compile-cache key material for a train-step program: the
     full training config (model architecture AND optimizer/loss
     hyperparameters — learning-rate schedule, loss weights, pos_weight all
-    constant-fold into the HLO), the kernel switchboard routing, and the
-    donation spec — every axis beyond the argument avals that changes the
-    lowered program.  Conservative by construction: a config change that
+    constant-fold into the HLO), the routes the backend gives the ops
+    (`ops.segment.active_impls`), and the donation spec — every axis beyond
+    the argument avals that changes the lowered program.  Conservative by construction: a config change that
     would NOT change the HLO still misses (one extra compile), but a stale
     executable can never be reused."""
     from nerrf_tpu.ops.segment import active_impls
@@ -687,10 +687,10 @@ def train_nerrfnet(
         state = init_state(model, cfg, train_ds.arrays, init_rng)
     n = len(train_ds)
     if log:
-        # the same kernel attribution the bench artifacts carry, stamped
-        # into the training log: a steps/s claim from this run is only
-        # interpretable against the aggregation mode + kernels that served
-        # it (the `auto` rule routes by node bucket and backend)
+        # the same attribution the bench artifacts carry, stamped into the
+        # training log: a steps/s claim from this run is only
+        # interpretable against the aggregation mode and op routes that
+        # served it (both are rules over node bucket and backend)
         from nerrf_tpu.ops.segment import active_impls
 
         log(f"gnn aggregation="

@@ -55,10 +55,10 @@ def run_experiment(name_or_path: str, out_dir: str | Path,
 
 
 def _kernel_path(model_cfg, num_nodes: int) -> dict:
-    """What serves the step's ops in this process (and under the active
-    `parallel.mesh_ops` context): the switchboard's routing plus the modes
-    `auto` resolves to at this node bucket.  Stamped into the log and
-    metrics.json so a steps/s figure names the kernels that produced it."""
+    """The routes the step's ops take in a program traced in this process,
+    one-device or sharded alike: functions of the backend and this node
+    bucket.  Stamped into the log and metrics.json so a steps/s figure
+    names the ops that produced it."""
     from nerrf_tpu.ops.segment import active_impls, gather_rows_route
 
     return {**active_impls(),
@@ -195,13 +195,11 @@ def _run_experiment(name_or_path, out_dir, num_steps, ckpt_every, sharded,
             init_sharded_state,
             make_mesh,
             make_sharded_train_step,
-            mesh_ops,
             shard_batch,
         )
 
         mesh = make_mesh(exp.mesh)
-        with mesh_ops(mesh):
-            kernel_path = _kernel_path(cfg.model, num_nodes)
+        kernel_path = _kernel_path(cfg.model, num_nodes)
         _log(f"sharded training over {n_dev} devices "
              f"(mesh {dict(mesh.shape)}) kernel_path={kernel_path}")
         model = NerrfNet(cfg.model)

@@ -40,11 +40,11 @@ Bucket = Tuple[int, int, int]
 
 _TAG = re.compile(r"^(\d+)n/(\d+)e/(\d+)s$")
 
-# Sequential kernel launches per GNN layer by aggregation mode — the
-# segment path is ~6 small kernels/layer (gathers + banded segment means,
-# ops/pallas_segment.py), the dense/fused paths collapse each layer's
-# aggregate to ONE kernel (the r5-measured ~0.27 ms/launch fixed cost is
-# exactly what `beta` fits).
+# Sequential device ops per GNN layer by aggregation mode — the segment
+# path is ~6 small ops/layer (two gathers + two weighted segment means),
+# the dense/fused paths collapse each layer's aggregate to ONE op or
+# composition (the r5-measured ~0.27 ms/launch fixed cost is exactly what
+# `beta` fits).
 LAUNCHES_PER_LAYER = {"segment": 6.0, "dense_adj": 1.0, "fused": 1.0}
 
 # Below this many archived batches a bucket's mean is noise, not signal —

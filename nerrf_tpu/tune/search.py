@@ -3,10 +3,8 @@
 The decision variables are exactly the two knobs serving exposes:
 
 * **rung placement** — which ``(max_nodes, max_edges, max_seqs)`` buckets
-  the ladder carries (bounded count, every rung pallas-budget-clean via
-  the SAME `analysis.programs.pallas_budget` inventory the deep lint
-  audits, so a tuned ladder can never propose a rung the lint would
-  reject);
+  the ladder carries (bounded count, node rungs up to
+  `MAX_CANDIDATE_NODES`);
 * **per-rung kernel routing** — which of {fused, dense_adj, segment} each
   rung's programs aggregate with, replacing the single global
   ``DENSE_ADJ_MAX_NODES`` constant with a fitted table.
@@ -32,10 +30,8 @@ from nerrf_tpu.tune.costmodel import Bucket, LadderCostModel
 
 MODES = ("fused", "dense_adj", "segment")
 
-# Hard ceiling on candidate node rungs: past 16k the fused kernel's
-# full-height message block blows the 16 MiB VMEM budget anyway (see
-# pallas_budget docstring) — the audit gate below enforces the real
-# boundary; this just bounds the enumeration.
+# Hard ceiling on candidate node rungs: the largest bucket any route has
+# been run at on the chip (docs/kernel-paths.md); it bounds the enumeration.
 MAX_CANDIDATE_NODES = 16384
 SEQ_MIN, SEQ_MAX = 32, 512
 
@@ -128,26 +124,10 @@ def demand_points(corpus: dict) -> List[DemandPoint]:
     return points
 
 
-def budget_clean(n: int, e: int, model_cfg=None) -> bool:
-    """True iff every kernel inventory at this rung clears the per-core
-    VMEM budget — the SAME audit `nerrf lint --deep` runs, invoked as a
-    search gate so a tuned ladder is lint-clean by construction."""
-    from nerrf_tpu.analysis.programs.pallas_budget import PallasBudget
-    from nerrf_tpu.graph.builder import NODE_FEATURE_DIM
-    from nerrf_tpu.models.graphsage import GraphSAGEConfig
-    from nerrf_tpu.ops.pallas_segment import kernel_vmem_blocks
-
-    hidden = (model_cfg.hidden if model_cfg is not None
-              else GraphSAGEConfig().hidden)
-    width = max(hidden, NODE_FEATURE_DIM)
-    return not PallasBudget().audit(kernel_vmem_blocks(n, e, width),
-                                    shape=(n, e, width))
-
-
-def candidate_graph_rungs(points: Sequence[DemandPoint],
-                          model_cfg=None) -> List[Tuple[int, int]]:
+def candidate_graph_rungs(points: Sequence[DemandPoint]
+                          ) -> List[Tuple[int, int]]:
     """Power-of-two ``(max_nodes, max_edges)`` rungs covering the demand
-    window, budget-gated.  Edge capacity starts at the ladder's 2n rule
+    window.  Edge capacity starts at the ladder's 2n rule
     (what the static ladder uses) and widens by powers of two up to the
     edge need the demand at that node rung actually carries — dense
     windows (many events between few inodes: attack bursts) overflow a
@@ -163,13 +143,9 @@ def candidate_graph_rungs(points: Sequence[DemandPoint],
         e_top = max(2 * n, min(_pow2_at_least(edge_need),
                                2 * MAX_CANDIDATE_NODES))
         while e <= e_top:
-            if budget_clean(n, e, model_cfg):
-                rungs.append((n, e))
+            rungs.append((n, e))
             e <<= 1
         n <<= 1
-    if not rungs:
-        raise TuneError("no budget-clean candidate rungs cover the "
-                        "observed demand")
     return rungs
 
 
@@ -180,8 +156,7 @@ def candidate_graph_rungs(points: Sequence[DemandPoint],
 MAX_CANDIDATE_BUCKETS = 24
 
 
-def candidate_buckets(points: Sequence[DemandPoint],
-                      model_cfg=None) -> List[Bucket]:
+def candidate_buckets(points: Sequence[DemandPoint]) -> List[Bucket]:
     """Full ``(max_nodes, max_edges, max_seqs)`` candidates: graph rungs
     crossed with the power-of-two sequence capacities the demand's file
     counts actually need.  Sequence capacity is a REAL search dimension,
@@ -191,7 +166,7 @@ def candidate_buckets(points: Sequence[DemandPoint],
     variants lets small-file traffic stop paying for the file-heavy
     tail's slots (exactly the structure the static default ladder's
     graph×seq product encodes by hand)."""
-    rungs = candidate_graph_rungs(points, model_cfg)
+    rungs = candidate_graph_rungs(points)
     seqs = sorted({min(max(_pow2_at_least(p.files), SEQ_MIN), SEQ_MAX)
                    for p in points})
     cands = [(n, e, s) for n, e in rungs for s in seqs]
@@ -291,7 +266,7 @@ def search_ladder(model: LadderCostModel, points: Sequence[DemandPoint],
     if max_rungs is None:
         max_rungs = max(len({b[0] for b in static_buckets}), 3)
 
-    cands = candidate_buckets(points, model_cfg)
+    cands = candidate_buckets(points)
     # ONE rejection price for every ladder scored (static included):
     # 10× the costliest candidate rung under the worst mode, so shedding
     # admissible traffic can never beat serving it

@@ -49,8 +49,7 @@ python scripts/nerrflint.py
 
 # pre-flight: the deep (jaxpr-level) program contracts — signature
 # closure of the serve ladder, donation discipline over the flat train
-# step, collective/sharding consistency, Pallas VMEM budgets, cache-key
-# coverage — proven abstractly on a virtual CPU backend (<30 s, no
+# step, collective/sharding consistency, cache-key coverage — proven abstractly on a virtual CPU backend (<30 s, no
 # devices; docs/static-analysis.md "The deep pass").
 timeout 120 python scripts/nerrflint.py --deep
 

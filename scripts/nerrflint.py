@@ -12,8 +12,8 @@ import), so ``scripts/e2e.sh`` fails fast on analysis errors instead of
 burning chip time.  ``--deep`` adds the
 program-contract tier (``nerrf_tpu/analysis/programs/``): abstract
 tracing of the real serve/train/parallel entry points on a virtual CPU
-backend — signature closure, donation, collectives, Pallas budgets,
-cache-key coverage — in under 30 s, still with no accelerator.  Rule
+backend — signature closure, donation, collectives, cache-key
+coverage — in under 30 s, still with no accelerator.  Rule
 catalog and suppression workflow: docs/static-analysis.md.
 """
 

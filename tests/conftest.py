@@ -9,31 +9,24 @@ makes a bare ``pytest`` on a TPU host land on the CPU mesh too.
 
 import os
 
-# NERRF_TEST_REAL_BACKEND=1 runs against whatever backend the host offers —
-# for the chip-gated tests (the compiled-Mosaic parity checks in
-# test_pallas_ops.py / test_ops_fused.py); everything else keeps the
-# virtual CPU mesh.
-_real = os.environ.get("NERRF_TEST_REAL_BACKEND") == "1"
-
 _flags = os.environ.get("XLA_FLAGS", "")
-if not _real and "xla_force_host_platform_device_count" not in _flags:
+if "xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (_flags + " --xla_force_host_platform_device_count=8").strip()
 
-if not _real:
-    # Keep the persistent compilation cache OUT of CPU test runs.  In-process
-    # CLI tests (test_cli drives cli.main directly) call
-    # enable_compilation_cache(), arming the on-disk cache for the whole
-    # pytest process; XLA:CPU's executable serialize/deserialize path then
-    # aborts/segfaults this host (observed: test_cli + test_elastic kills the
-    # run inside train_elastic's cached step_by_idx, reproducibly, at any
-    # commit — and never with the cache disabled).  Chip-gated runs
-    # (_real) keep the cache: there it saves real compile minutes.
-    os.environ.setdefault("NERRF_NO_COMPILE_CACHE", "1")
+# Keep the persistent compilation cache OUT of CPU test runs.  In-process
+# CLI tests (test_cli drives cli.main directly) call
+# enable_compilation_cache(), arming the on-disk cache for the whole
+# pytest process; XLA:CPU's executable serialize/deserialize path then
+# aborts/segfaults this host (observed: test_cli + test_elastic kills the
+# run inside train_elastic's cached step_by_idx, reproducibly, at any
+# commit — and never with the cache disabled).
+os.environ.setdefault("NERRF_NO_COMPILE_CACHE", "1")
 
 import jax  # noqa: E402
 
-if not _real:
-    jax.config.update("jax_platforms", "cpu")
+# the suite runs on the virtual CPU mesh wherever it is started; what needs
+# the chip is chip_smoke.py's and chipbench/'s, run through the tool
+jax.config.update("jax_platforms", "cpu")
 
 
 def pytest_configure(config):
@@ -48,6 +41,15 @@ import pytest  # noqa: E402
 @pytest.fixture(scope="session")
 def repo_root() -> pathlib.Path:
     return pathlib.Path(__file__).resolve().parent.parent
+
+
+def scope_paths(lowered_text: str) -> list:
+    """The `jax.named_scope` / primitive path of every location in a
+    lowered module's text (``.as_text(debug_info=True)``), e.g.
+    ``jit(loss)/jvp(NerrfNet)/gnn/gnn_heads/row_gather/dot_general``."""
+    import re
+
+    return re.findall(r'^#loc\d+ = loc\("([^"]+)"', lowered_text, flags=re.M)
 
 
 def make_service_shell(cfg, registry=None, journal=None):

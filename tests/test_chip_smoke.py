@@ -19,8 +19,8 @@ def _run(repo_root, tmp_path, *argv):
 
 
 def test_rehearsal_runs_every_phase(repo_root, tmp_path):
-    """The same code the chip runs — barrier, five kernels (interpret
-    mode), both train buckets through train.run, serve-detect on the
+    """The same code the chip runs — barrier, the two-route ops against the
+    host, both train buckets through train.run, serve-detect on the
     checkpoint, and (conftest's 8 virtual devices) the dp x tp leg — at a
     toy width, with both compile caches placed by the environment."""
     before = set(os.listdir(repo_root))

@@ -56,7 +56,7 @@ def test_graphsage_forward_shapes_and_masking():
 def test_graphsage_rev_view_matches_unsorted_path():
     """The src-sorted reverse-aggregation view is a pure reordering: node
     outputs must match the unsorted segment path up to float summation
-    order (it exists so both directions ride the banded Pallas kernel)."""
+    order (it exists so both directions declare sorted ids)."""
     from nerrf_tpu.models.graphsage import SageBlock
 
     ds = _dataset()
@@ -163,8 +163,8 @@ def test_nerrfnet_jit_recompile_free():
 
 def test_gnn_aggregation_paths_parity():
     """All three aggregation shapes — dense_adj (one [N,N] matmul per
-    layer), fused (one sage_aggregate kernel per layer) and segment
-    (gather + banded segment-mean) — must compute the same aggregation on
+    layer), fused (one sage_aggregate call per layer) and segment
+    (gather + weighted segment-mean) — must compute the same aggregation on
     the same param tree: the bench times dense/fused, training checkpoints
     must load into any of them."""
     import dataclasses
@@ -196,11 +196,8 @@ def test_gnn_aggregation_paths_parity():
 
 def test_gnn_fused_mode_gradient_parity():
     """The fused path must TRAIN identically, not just infer: parameter
-    gradients through the fused-mode wiring (pre-normalized views + the
-    XLA composition this CPU suite dispatches to) must match the segment
-    oracle in f32.  The fused KERNEL's custom VJP is covered separately:
-    tests/test_ops_fused.py runs model-level gradients with the
-    interpret-mode Pallas kernel registered."""
+    gradients through the fused-mode wiring (pre-normalized views over
+    `ops.sage_aggregate`) must match the segment oracle in f32."""
     import dataclasses
 
     import jax
@@ -270,9 +267,8 @@ def test_dense_adj_aggregate_is_scoped_like_the_fused_route():
     transpose carry the `sage_aggregate` scope the fused route's op carries
     (one name for the same work in a device trace), and nothing else does —
     the `c_sum` / `dir_bias` terms lie outside it on both routes."""
-    import re
-
     from nerrf_tpu.graph import EDGE_FEATURE_DIM, NODE_FEATURE_DIM
+    from tests.conftest import scope_paths
 
     n, e, layers = 16, 32, 2
     rng = np.random.default_rng(0)
@@ -292,7 +288,7 @@ def test_dense_adj_aggregate_is_scoped_like_the_fused_route():
         return out["edge_logit"].sum() + out["node_logit"].sum()
 
     text = jax.jit(jax.grad(loss)).lower(params).as_text(debug_info=True)
-    paths = re.findall(r'^#loc\d+ = loc\("([^"]+)"', text, flags=re.M)
+    paths = scope_paths(text)
     scoped = [p for p in paths if "sage_aggregate" in p]
     for i in range(layers):
         here = f"/gnn_layer_{i}/block_{i}/sage_aggregate/dot_general"
@@ -323,64 +319,59 @@ def _past_crossover():
 
 @pytest.mark.parametrize("nodes, want", [
     (256, "dense_adj"), (1024, "dense_adj"), (2048, "dense_adj"),
-    (4096, "dense_adj"), ("past", "fused"), (None, "fused")])
+    (4096, "dense_adj"), ("past", "segment"), (None, "segment")])
 def test_auto_routes_every_shipped_bucket_to_the_matmul_on_a_tpu(
         monkeypatch, nodes, want):
     """Every rung the repo ships (`pipeline._GRAPH_WARMUP_RUNGS`, both
     experiment files) lies under the crossover measured on the chip
     (benchmarks/results/kernel_bench_v5e.json); the first bucket past it,
-    and a caller that names no bucket, get the fused kernel."""
+    and a caller that names no bucket, get `segment`, which compiles and
+    runs at any size."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     if nodes == "past":
         nodes = _past_crossover()
     assert GraphSAGEConfig().resolved_aggregation(nodes) == want
 
 
-@pytest.mark.parametrize("nodes, edges", [(1024, 2048), (4096, 8192)])
-def test_no_kernel_under_the_gnn_at_the_shipped_training_buckets_on_a_tpu(
-        monkeypatch, nodes, edges):
+@pytest.mark.parametrize("nodes", [256, 512, 1024, 2048, 4096])
+def test_no_kernel_in_a_nerrfnet_step_at_any_ladder_bucket_on_a_tpu(
+        monkeypatch, nodes):
     """`NerrfNet()` as both experiments train it (`auto`, 28 x 160, bf16),
-    traced the way a TPU would trace it: nothing under `gnn` is a Pallas
-    call (the aggregate is `adj @ msg` since PR 27, the heads' edge-row
-    gathers selection matmuls since PR 30); the heads' two gathers lie
-    under `gnn_heads/row_gather`, forward and backward.  What is left of
-    the kernels in a step is the seq -> node scatter of `joint.py`."""
-    import re
-
+    forward and backward, traced the way a TPU would trace it: every op of
+    the program is one the compiler writes.  The aggregate is `adj @ msg`
+    (PR 27), the heads' edge-row gathers selection matmuls under
+    `gnn_heads/row_gather` (PR 30), and the seq -> node scatter of
+    `joint.py` XLA's scatter-add with a gather as its transpose (PR 31)."""
     from nerrf_tpu.graph import EDGE_FEATURE_DIM, NODE_FEATURE_DIM
-    from nerrf_tpu.ops import pallas_segment, segment
+    from nerrf_tpu.ops import segment
+    from tests.conftest import scope_paths
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    pallas_segment.register(interpret=True)    # what a TPU's first use does
-    try:
-        assert segment.active_impls()["gather_rows"] == "xla_selection_matmul"
-        S = jax.ShapeDtypeStruct
-        seqs, seq_len = 128, 100               # both experiments' dataset
-        args = (S((nodes, NODE_FEATURE_DIM), jnp.float32),
-                S((nodes,), jnp.int32), S((nodes,), jnp.int32),
-                S((nodes,), jnp.bool_), S((edges,), jnp.int32),
-                S((edges,), jnp.int32),
-                S((edges, EDGE_FEATURE_DIM), jnp.float32),
-                S((edges,), jnp.bool_),
-                S((seqs, seq_len, SEQ_FEATURE_DIM), jnp.float32),
-                S((seqs, seq_len), jnp.bool_), S((seqs,), jnp.int32))
-        model = NerrfNet(JointConfig())
-        assert model.cfg.gnn.resolved_aggregation(nodes) == "dense_adj"
-        params = jax.eval_shape(
-            lambda *a: model.init(jax.random.PRNGKey(0), *a)["params"], *args)
+    assert segment.active_impls() == {"gather_rows": "xla_selection_matmul"}
+    S = jax.ShapeDtypeStruct
+    edges, seqs, seq_len = 2 * nodes, 128, 100     # both experiments' dataset
+    args = (S((nodes, NODE_FEATURE_DIM), jnp.float32),
+            S((nodes,), jnp.int32), S((nodes,), jnp.int32),
+            S((nodes,), jnp.bool_), S((edges,), jnp.int32),
+            S((edges,), jnp.int32),
+            S((edges, EDGE_FEATURE_DIM), jnp.float32),
+            S((edges,), jnp.bool_),
+            S((seqs, seq_len, SEQ_FEATURE_DIM), jnp.float32),
+            S((seqs, seq_len), jnp.bool_), S((seqs,), jnp.int32))
+    model = NerrfNet(JointConfig())
+    assert model.cfg.gnn.resolved_aggregation(nodes) == "dense_adj"
+    params = jax.eval_shape(
+        lambda *a: model.init(jax.random.PRNGKey(0), *a)["params"], *args)
 
-        def loss(p, *a):
-            out = model.apply({"params": p}, *a)
-            return (out["edge_logit"].sum() + out["node_logit"].sum()
-                    + out["seq_logit"].sum())
+    def loss(p, *a):
+        out = model.apply({"params": p}, *a)
+        return (out["edge_logit"].sum() + out["node_logit"].sum()
+                + out["seq_logit"].sum())
 
-        text = jax.jit(jax.grad(loss)).lower(params, *args).as_text(
-            debug_info=True)
-    finally:
-        pallas_segment.unregister()
-    paths = set(re.findall(r'^#loc\d+ = loc\("([^"]+)"', text, flags=re.M))
-    assert not [p for p in paths if "/gnn/" in p and "pallas_call" in p]
-    assert any("pallas_call" in p for p in paths)      # joint.py's scatter
+    text = jax.jit(jax.grad(loss)).lower(params, *args).as_text(
+        debug_info=True)
+    assert "custom_call" not in text and "pallas" not in text
+    paths = set(scope_paths(text))
     gathers = [p for p in paths if "/gnn_heads/row_gather/" in p]
     for stage in ("jit(loss)/jvp(", "jit(loss)/transpose(jvp("):
         own = [p for p in gathers if p.startswith(stage)]
@@ -390,7 +381,13 @@ def test_no_kernel_under_the_gnn_at_the_shipped_training_buckets_on_a_tpu(
     # no XLA gather or scatter either
     assert not [p for p in gathers
                 if p.rsplit("/", 1)[1] in ("gather", "scatter-add",
-                                           "scatter_add", "pallas_call")]
+                                           "scatter_add")]
+    # joint.py's scatter sits beside the modules, not under one
+    outside = [p for p in paths
+               if "/gnn/" not in p and "/lstm/" not in p]
+    assert any(p.startswith("jit(loss)/jvp(")
+               and p.rsplit("/", 1)[1] in ("scatter-add", "scatter_add")
+               for p in outside), outside
 
 
 @pytest.mark.parametrize("nodes", [256, 4096, "past"])
@@ -420,27 +417,29 @@ def test_routing_table_and_explicit_aggregation_outrank_the_auto_rule(
             assert cfg.resolved_aggregation(n) == mode
 
 
-def test_dense_adj_matches_segment_at_the_deployed_bucket():
-    """The route `auto` now takes at 4096n / 8192e against the portable
-    oracle (XLA on this CPU), in f32, with about half of the edge slots
-    masked as a padded window has them: the logits, and the loss's gradient
-    with respect to every parameter and to the node features every layer's
-    `msg` is made from."""
+@pytest.mark.parametrize("n", [256, 512, 1024, 2048, 4096])
+def test_dense_adj_matches_segment_at_every_ladder_bucket(n):
+    """The route `auto` takes on a TPU at every bucket of the ladder (e =
+    2n) against the portable oracle (XLA on this CPU), in f32, with about
+    half of the edge slots masked as a padded window has them: the logits,
+    and the loss's gradient with respect to every parameter and to the node
+    features every layer's `msg` is made from."""
     import dataclasses
 
     from nerrf_tpu.graph import EDGE_FEATURE_DIM, NODE_FEATURE_DIM
 
-    n, e = 4096, 8192
+    e = 2 * n
+    real = n * 3020 // 4096      # joint-dense's mean share of real nodes
     rng = np.random.default_rng(27)
     edge_feat = rng.normal(size=(e, EDGE_FEATURE_DIM)).astype(np.float32)
     edge_feat[:, 12] = rng.uniform(0.0, 1.0, e)  # the causality weight
     edge_mask = np.arange(e) < e // 2 + 37
-    node_mask = np.arange(n) < 3020  # joint-dense's mean of real nodes
+    node_mask = np.arange(n) < real
     args = (rng.normal(size=(n, NODE_FEATURE_DIM)).astype(np.float32),
             rng.integers(0, 4, n).astype(np.int32),
             rng.integers(0, 8, n).astype(np.int32), node_mask,
-            rng.integers(0, 3020, e).astype(np.int32),
-            np.sort(rng.integers(0, 3020, e)).astype(np.int32),
+            rng.integers(0, real, e).astype(np.int32),
+            np.sort(rng.integers(0, real, e)).astype(np.int32),
             edge_feat, edge_mask)
     cfg = GraphSAGEConfig(hidden=16, num_layers=2, dropout=0.0,
                           dtype=jnp.float32, aggregation="segment")

@@ -104,3 +104,105 @@ def test_sharded_train_step_runs_and_matches_semantics():
         0.0,
     )
     assert delta > 0
+
+
+# -- one-chip and sharded steps are the same ops -------------------------------
+
+
+def _row_gather_prims(text):
+    """Primitive names under `gnn_heads/row_gather` in a lowered module."""
+    from tests.conftest import scope_paths
+
+    return {p.rsplit("/", 1)[1] for p in scope_paths(text)
+            if "/gnn_heads/row_gather/" in p}
+
+
+def test_sharded_and_one_device_steps_trace_the_same_ops_on_a_tpu(
+        monkeypatch):
+    """With the backend read as a TPU, the step over the 8-device mesh and
+    the one-device step are keyed by the same `active_impls()` and hold the
+    same route for the heads' row gather (the selection matmul: a
+    `dot_general`, which GSPMD partitions): no scope swaps a mesh program's
+    ops for others any more."""
+    from nerrf_tpu.ops.segment import active_impls
+    from nerrf_tpu.train import loop
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    ds = _dataset()
+    cfg = TrainConfig(
+        model=JointConfig(
+            gnn=GraphSAGEConfig(hidden=32, num_layers=2, dropout=0.0),
+            lstm=LSTMConfig(hidden=32, num_layers=1, dropout=0.0)),
+        batch_size=8, num_steps=2)
+    model = NerrfNet(cfg.model)
+    idx = np.arange(8) % len(ds)
+    batch_np = {k: v[idx] for k, v in ds.arrays.items()}
+    mesh = make_mesh(MeshConfig(dp=4, tp=2))
+    rng = jax.random.PRNGKey(0)
+
+    state = init_sharded_state(model, cfg, ds.arrays, mesh)
+    sharded = make_sharded_train_step(model, cfg, mesh).lower(
+        state, shard_batch(mesh, batch_np), rng).as_text(debug_info=True)
+    one_state = loop.init_state(model, cfg, ds.arrays, rng)
+    one = loop.make_train_step(model, cfg).lower(
+        one_state, {k: jnp.asarray(v) for k, v in batch_np.items()},
+        rng).as_text(debug_info=True)
+
+    for text in (sharded, one):
+        assert "custom_call" not in text.replace("@Sharding", "")
+    assert "dot_general" in _row_gather_prims(one)
+    assert _row_gather_prims(sharded) == _row_gather_prims(one)
+    assert not {"gather", "scatter-add"} & _row_gather_prims(sharded)
+    assert (loop.step_key_extra(cfg, "train_step_sharded")["ops"]
+            == loop.step_key_extra(cfg, "train_step")["ops"]
+            == repr(sorted(active_impls().items()))
+            == "[('gather_rows', 'xla_selection_matmul')]")
+
+
+def test_stream_step_traces_the_same_ops_on_any_mesh_on_a_tpu(monkeypatch):
+    """`make_stream_train_step` over dp x sp and over one device, with the
+    backend read as a TPU: `active_impls()` is the same before, between and
+    after the two traces (tracing installs nothing), and every primitive of
+    both programs is the compiler's."""
+    from nerrf_tpu.models import StreamConfig, StreamNet
+    from nerrf_tpu.ops.segment import active_impls
+    from nerrf_tpu.parallel import make_stream_train_step
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    r = np.random.default_rng(0)
+    batch = {"feat": r.normal(size=(2, 64, 12)).astype(np.float32),
+             "mask": np.ones((2, 64), np.bool_),
+             "label": (r.random((2, 64)) < 0.1).astype(np.float32)}
+    cfg = StreamConfig(dim=32, num_heads=2, num_layers=2, dropout=0.0)
+
+    def prims(mesh):
+        model = StreamNet(cfg, mesh=mesh)
+        init_fn, step_fn, place = make_stream_train_step(model, mesh)
+        with mesh:
+            placed = place(batch)
+            state = init_fn(jax.random.PRNGKey(0), placed)
+            closed = jax.make_jaxpr(step_fn)(state, placed,
+                                             jax.random.PRNGKey(1))
+        found = set()
+
+        def walk(jaxpr):
+            for eqn in jaxpr.eqns:
+                found.add(eqn.primitive.name)
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub)
+
+        walk(closed.jaxpr)
+        return found
+
+    want = {"gather_rows": "xla_selection_matmul"}
+    assert active_impls() == want
+    over_mesh = prims(make_mesh(MeshConfig(dp=2, tp=1, sp=4)))
+    assert active_impls() == want
+    one = prims(make_mesh(MeshConfig(dp=1, tp=1, sp=1),
+                          devices=jax.devices()[:1]))
+    assert active_impls() == want
+    # what the mesh adds is the ring's own (the one hand-written
+    # collective); no program holds a kernel call
+    assert {"shard_map", "ppermute"} <= over_mesh - one
+    assert not {p for p in over_mesh | one
+                if "pallas" in p or "custom_call" in p}

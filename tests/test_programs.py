@@ -3,9 +3,9 @@ per-contract positive/negative fixtures.
 
 Mirrors tests/test_analysis.py one tier down: ``test_deep_repo_is_clean``
 runs the full deep pass over the real entry points (serve ladder, flat
-train step, ring shard_map, Pallas kernels, cache keys) and asserts the
+train step, ring shard_map, cache keys) and asserts the
 <30 s CPU budget the chip-queue pre-flights rely on; the fixture tests
-prove each of the five contracts fires on a deliberately broken input and
+prove each of the four contracts fires on a deliberately broken input and
 stays quiet on a clean one.  Runs entirely on the virtual CPU mesh — no
 devices, no compiles."""
 
@@ -31,7 +31,6 @@ from nerrf_tpu.analysis.programs.cachekey import CacheKeyCoverage
 from nerrf_tpu.analysis.programs.closure import SignatureClosure
 from nerrf_tpu.analysis.programs.collectives import CollectiveConsistency
 from nerrf_tpu.analysis.programs.donation import DonationDiscipline
-from nerrf_tpu.analysis.programs.pallas_budget import PallasBudget
 
 
 # -- the tier-1 gate ----------------------------------------------------------
@@ -311,6 +310,31 @@ def test_donation_ast_double_donation(tmp_path):
     assert found[0].anchor == "bad:double:state"
 
 
+def test_donation_coarse_fallback_catches_forbidden(monkeypatch):
+    """When the leaf mapping degrades (lowered arg count != pytree leaf
+    count), an entry declaring donate=() whose module still aliases
+    inputs must fail — the serve shared-params hazard (review
+    regression: the coarse path previously checked only wasted)."""
+    import jax
+
+    import nerrf_tpu.analysis.programs.donation as dn
+
+    a = aval((8, 8), np.float32)
+
+    def step(state, batch):
+        return state - batch.sum(), batch.mean()
+
+    sneaky = jax.jit(step, donate_argnums=(0,))
+    # force the coarse path: pretend the pytree has an extra leaf
+    monkeypatch.setattr(dn, "leaf_paths",
+                        lambda tree: ["<leaf>", "<phantom>"])
+    found = DonationDiscipline(entries=[
+        _entry("serve_like_coarse", sneaky, (a, a), donate=()),
+    ]).run(project=None)
+    assert len(found) == 1
+    assert found[0].anchor.endswith("coarse-forbidden")
+
+
 # -- collective-consistency ---------------------------------------------------
 
 
@@ -336,8 +360,10 @@ def _shard_map_entry(name, body, mesh_axes, axis_sizes):
         from jax.sharding import PartitionSpec as P
 
         mesh = _two_device_mesh()
+        # no `check_rep=False`: jax 0.9 renamed it, and the TypeError made
+        # every fixture a trace failure before the rule saw a collective
         fn = shard_map_fn(body, mesh=mesh, in_specs=(P("dp", "sp"),),
-                          out_specs=P("dp", "sp"), check_rep=False)
+                          out_specs=P("dp", "sp"))
         return fn, (aval((2, 4), np.float32),)
 
     return CollectiveEntry(name=name, path="tests/fixture.py", build=build,
@@ -381,151 +407,6 @@ def test_collectives_flags_sharding_rank_and_axis():
     assert "sharding:prog:batch:rank" in anchors
     assert "sharding:prog:feat:axes" in anchors
     assert len(found) == 2
-
-
-# -- pallas-budget ------------------------------------------------------------
-
-
-def test_pallas_budget_clean_at_ladder_shapes(project):
-    assert PallasBudget().run(project) == []
-
-
-def test_pallas_budget_flags_over_vmem_block():
-    rule = PallasBudget()
-    # a full-height 64k-row f32 message block, double-buffered: 64 MiB
-    over = {"sage_fused": [("msg", (65536, 128), "float32", 2),
-                           ("out", (128, 128), "float32", 1)]}
-    found = rule.audit(over, shape=(65536, 131072, 128))
-    assert len(found) == 1 and "vmem" in found[0].anchor
-    assert "msg" in found[0].message
-
-    # the real inventory, against a deliberately tiny budget
-    from nerrf_tpu.ops.pallas_segment import kernel_vmem_blocks
-
-    found = rule.audit(kernel_vmem_blocks(4096, 8192, 160),
-                       shape=(4096, 8192, 160), budget=1 << 16)
-    assert found and all("vmem" in f.anchor for f in found)
-
-
-def test_kernel_vmem_inventory_pins_real_blockspecs(monkeypatch):
-    """`kernel_vmem_blocks` is the budget rule's premise; pin it to the
-    BlockSpecs the kernels actually hand pallas_call (same drift-pin
-    pattern as sample_spec↔window_sample): per kernel, the single-copy
-    resident bytes of the declared inventory must equal the bytes of the
-    captured block shapes + scratch."""
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-
-    import nerrf_tpu.ops.pallas_segment as ps
-
-    captured = {}
-
-    class _Stop(Exception):
-        pass
-
-    def spy_for(name):
-        def spy(kernel, **kw):
-            gs = kw.get("grid_spec")
-            if gs is not None:
-                in_specs = list(getattr(gs, "in_specs", []))
-                out_specs = getattr(gs, "out_specs", [])
-                scratch = list(getattr(gs, "scratch_shapes", []) or [])
-            else:
-                in_specs = list(kw.get("in_specs", []))
-                out_specs = kw.get("out_specs")
-                scratch = []
-            if not isinstance(out_specs, (list, tuple)):
-                out_specs = [out_specs]
-            shapes = [tuple(s.block_shape) for s in in_specs + out_specs]
-            shapes += [tuple(s.shape) for s in scratch]
-            captured[name] = shapes
-            raise _Stop
-
-        return spy
-
-    N, E, F = 128, 256, 64
-    rng = np.random.default_rng(0)
-    dst = np.sort(rng.integers(0, N, E)).astype(np.int32)
-    src = rng.integers(0, N, E).astype(np.int32)
-    order = np.argsort(src, kind="stable")
-    w = rng.uniform(0.1, 1.0, E).astype(np.float32)
-    data = jnp.zeros((E, F), jnp.float32)
-    table = jnp.zeros((N, F), jnp.float32)
-    drives = {
-        "segment_sum": lambda: ps._segment_sum_call(
-            data, jnp.asarray(dst), N),
-        "segment_sum_sorted": lambda: ps._segment_sum_sorted_call(
-            data, jnp.asarray(dst), N),
-        "gather_rows": lambda: ps._gather_call(table, jnp.asarray(src)),
-        "gather_rows_sorted": lambda: ps._gather_sorted_call(
-            table, jnp.asarray(np.sort(src))),
-        "sage_fused": lambda: ps._sage_call(
-            table, jnp.asarray(dst), jnp.asarray(src), jnp.asarray(w),
-            jnp.asarray(src[order]), jnp.asarray(dst[order]),
-            jnp.asarray(w[order]), N),
-    }
-    for name, drive in drives.items():
-        monkeypatch.setattr(pl, "pallas_call", spy_for(name))
-        with pytest.raises(_Stop):
-            drive()
-
-    from nerrf_tpu.analysis.programs.pallas_budget import _ITEMSIZE
-
-    inventory = ps.kernel_vmem_blocks(N, E, F)
-    assert set(inventory) == set(drives)
-    for name, blocks in inventory.items():
-        want = sum(int(np.prod(s)) * 4 for s in captured[name])
-        # copies weighting is costing policy (double-buffering), not a
-        # BlockSpec fact; band pointers ride scalar prefetch (SMEM), not
-        # a VMEM BlockSpec — excluded from the pin on both sides
-        got = sum(int(np.prod(shape)) * _ITEMSIZE[str(dtype)]
-                  for bname, shape, dtype, _copies in blocks
-                  if bname != "band_ptrs")
-        assert got == want, (name, blocks, captured[name])
-
-
-def test_pallas_budget_flags_lane_misalignment():
-    found = PallasBudget().audit(
-        {"broken": [("tile", (128, 200), "float32", 2)]})
-    assert len(found) == 1 and found[0].anchor.endswith("tile:lanes")
-
-
-def test_pallas_budget_tile_constants_lane_rule(monkeypatch):
-    """A lane-extent tile (TF/TN) shrunk below the 128-lane register
-    shape must fail even though it still divides by 8 (review
-    regression: the sublane rule alone would pass TF=64)."""
-    import nerrf_tpu.ops.pallas_segment as ps
-
-    monkeypatch.setattr(ps, "tile_constants",
-                        lambda: {"TN": 128, "TE": 128, "TF": 64})
-    found = [f for f in PallasBudget(shapes=[]).run(None)
-             if f.anchor == "pallas:tile:TF"]
-    assert len(found) == 1 and "multiple of 128" in found[0].message
-
-
-def test_donation_coarse_fallback_catches_forbidden(monkeypatch):
-    """When the leaf mapping degrades (lowered arg count != pytree leaf
-    count), an entry declaring donate=() whose module still aliases
-    inputs must fail — the serve shared-params hazard (review
-    regression: the coarse path previously checked only wasted)."""
-    import jax
-
-    import nerrf_tpu.analysis.programs.donation as dn
-
-    a = aval((8, 8), np.float32)
-
-    def step(state, batch):
-        return state - batch.sum(), batch.mean()
-
-    sneaky = jax.jit(step, donate_argnums=(0,))
-    # force the coarse path: pretend the pytree has an extra leaf
-    monkeypatch.setattr(dn, "leaf_paths",
-                        lambda tree: ["<leaf>", "<phantom>"])
-    found = DonationDiscipline(entries=[
-        _entry("serve_like_coarse", sneaky, (a, a), donate=()),
-    ]).run(project=None)
-    assert len(found) == 1
-    assert found[0].anchor.endswith("coarse-forbidden")
 
 
 # -- cache-key-coverage -------------------------------------------------------

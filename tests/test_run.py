@@ -52,8 +52,28 @@ def test_run_sharded_experiment_on_virtual_mesh(tmp_path):
     assert report["steps_per_sec"] > 0
     # the layout as placed: tp really partitions parameters, the batch
     # really spans the mesh, and the ops the partitioned program is made
-    # of are named (Mosaic kernels cannot ride GSPMD: XLA here)
+    # of are named (the routes of this backend, as in a one-device run)
     assert report["sharding"]["mesh"] == {"dp": 4, "tp": 2, "sp": 1}
     assert report["sharding"]["tp_sharded_leaves"] > 0
     assert report["sharding"]["batch_devices"] == 8
     assert set(report["kernel_path"].values()) <= {"xla", "segment", "rnn"}
+
+
+@pytest.mark.parametrize("backend, nodes, want", [
+    ("tpu", 1024, ("xla_selection_matmul", "dense_adj", "fused")),
+    ("tpu", 4096, ("xla_selection_matmul", "dense_adj", "fused")),
+    ("tpu", 16384, ("xla", "segment", "fused")),
+    ("cpu", 4096, ("xla", "segment", "rnn"))])
+def test_kernel_path_is_a_function_of_backend_and_bucket(monkeypatch, backend,
+                                                         nodes, want):
+    """What `train.run` stamps into its log and `metrics.json`: the route
+    of every op that has two, at the run's own node bucket, and nothing
+    that a registration or a mesh could have changed."""
+    import jax
+
+    from nerrf_tpu.models import JointConfig
+    from nerrf_tpu.train.run import _kernel_path
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert _kernel_path(JointConfig(), nodes) == dict(
+        zip(("gather_rows", "gnn_aggregation", "lstm_impl"), want))

@@ -246,20 +246,24 @@ def tuned_serve_cfg():
     return apply_to_serve_config(tune(golden_corpus()))
 
 
-def test_tuned_rungs_are_pallas_budget_clean(tuned_serve_cfg):
-    """Every tuned rung clears the same per-core VMEM audit `nerrf lint
-    --deep` enforces — the search's budget gate is the lint's, so this
-    can only fail if they drift apart."""
-    from nerrf_tpu.analysis.programs.pallas_budget import PallasBudget
-    from nerrf_tpu.graph.builder import NODE_FEATURE_DIM
-    from nerrf_tpu.models.graphsage import GraphSAGEConfig
-    from nerrf_tpu.ops.pallas_segment import kernel_vmem_blocks
+def test_candidate_rungs_are_bounded_by_the_largest_measured_bucket(
+        tuned_serve_cfg):
+    """No audit gates a rung any more (every route is the compiler's, and
+    compiles at any size): what bounds the enumeration is
+    `MAX_CANDIDATE_NODES`, the largest bucket a route has run at on the
+    chip, with edges from the ladder's 2n up to the demand's need."""
+    from nerrf_tpu.tune.search import (MAX_CANDIDATE_NODES, DemandPoint,
+                                       candidate_graph_rungs)
 
-    width = max(GraphSAGEConfig().hidden, NODE_FEATURE_DIM)
+    rungs = candidate_graph_rungs([DemandPoint(300, 5000, 8, 1.0),
+                                   DemandPoint(10 ** 6, 10 ** 7, 8, 1.0)])
+    nodes = sorted({n for n, _ in rungs})
+    assert nodes[0] == 256 and nodes[-1] == MAX_CANDIDATE_NODES
+    assert all(e >= 2 * n for n, e in rungs)
+    assert (512, 8192) in rungs and (256, 1024) not in rungs
+    assert max(e for _, e in rungs) == 2 * MAX_CANDIDATE_NODES
     for n, e, _s in tuned_serve_cfg.buckets:
-        findings = PallasBudget().audit(kernel_vmem_blocks(n, e, width),
-                                        shape=(n, e, width))
-        assert findings == [], f"rung {n}n/{e}e over VMEM budget"
+        assert n <= MAX_CANDIDATE_NODES and e >= 2 * n
 
 
 def test_tuned_ladder_passes_program_closure(repo_root):
