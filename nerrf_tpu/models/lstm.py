@@ -7,11 +7,18 @@ ransomware probability, target F1 ≥ 0.95).  TPU-native shape: the recurrence
 is a single fused `lax.scan` per layer — both directions ride one scan
 (stacked on a leading axis; one batched matmul per timestep), and the
 input-side gate projections are hoisted out of the scan as one big matmul
-over all timesteps.  The r5 chip profile measured a ~0.27 ms fixed cost per
-sequential kernel on the runtime, so cutting in-scan ops from 4 matmuls per
-timestep (2 dirs x input+recurrent) to 1 batched recurrent matmul is worth
-~2x on the whole sequence tower.  Param tree is bit-compatible with the
-previous `flax.linen.RNN(OptimizedLSTMCell)` implementation
+per direction over all timesteps, written time-major from a time-major copy
+of the narrow layer input, so the 4H-wide scan inputs are never re-laid.
+The reverse direction reads the same prefix-first sequence reversed along
+time by a static `jnp.flip` — its padding first — and multiplies its new
+`h` and `c` by the step's validity: its state is exactly zero until its
+first real event and its outputs on padding are exactly zero, with no
+data-dependent gather in the forward program and no scatter in the backward
+one (reversing inside each valid prefix by `take_along_axis` costs 13 ms a
+step of 8 x 128 sequences on a v5e; PERF.md, PR 32).  An operation inside
+the loop costs 2-11 us on that chip, so what the loop body holds is counted
+in fusions, not in FLOPs.  Param tree is bit-compatible with the previous
+`flax.linen.RNN(OptimizedLSTMCell)` implementation
 (``OptimizedLSTMCell_{2i}``=fwd / ``_{2i+1}``=bwd, ``ii..io``/``hi..ho``
 leaves), which remains available as ``LSTMConfig.impl="rnn"`` and is
 parity-tested against the fused path.  Sequences are left-padded with a
@@ -35,12 +42,14 @@ class LSTMConfig:
     num_layers: int = 2
     dropout: float = 0.1
     dtype: Any = jnp.bfloat16
-    # "fused": both directions in one scan, input projections hoisted (the
-    # TPU-shaped path; r5 chip measurement).  "rnn": the original flax
-    # RNN/OptimizedLSTMCell pair — same math, same param tree (bit-equal in
-    # f32, parity-tested), and ~1.5x faster on CPU where per-op overhead is
-    # cheap but the batched-einsum layout is not.  "auto" (default): fused
-    # on the TPU backend, rnn elsewhere.
+    # "fused": both directions in one scan, input projections hoisted and
+    # written time-major, the reverse direction a masked scan over the
+    # statically reversed input (the TPU-shaped path; no gather, no
+    # scatter).  "rnn": the original flax RNN/OptimizedLSTMCell pair with
+    # `seq_lengths` — same math, same param tree (outputs and gradients
+    # parity-tested in f32), and ~1.5x faster on CPU where the
+    # batched-einsum layout is not cheap.  "auto" (default): fused on the
+    # TPU backend, rnn elsewhere.
     impl: str = "auto"
 
     @property
@@ -96,28 +105,24 @@ class _CellParams(nn.Module):
                 jnp.concatenate(bh, axis=0))
 
 
-def _flip_valid(x, lengths):
-    """Reverse each sequence within its valid prefix (prefix-first layout);
-    positions at or beyond ``lengths`` become zero."""
-    T = x.shape[-2] if x.ndim >= 2 else x.shape[0]
-    t = jnp.arange(T)
-    src = lengths[..., None] - 1 - t  # [..., T]
-    ok = src >= 0
-    src = jnp.where(ok, src, 0).astype(jnp.int32)
-    g = jnp.take_along_axis(x, src[..., None], axis=-2)
-    return g * ok[..., None].astype(x.dtype)
-
-
 class ImpactLSTM(nn.Module):
     """[B, T, F] event sequences → encrypt-probability logits [B] + embedding.
 
-    Returns dict with `seq_logit` [B] and `seq_emb` [B, 2*hidden].
+    Returns dict with `seq_logit` [B] and `seq_emb` [B, hidden].
     """
 
     cfg: LSTMConfig
 
-    def _fused_bilayer(self, x, lengths, layer: int):
-        """One BiLSTM layer as a single scan: [B,T,H_in] → (fwd, bwd)."""
+    def _fused_bilayer(self, x, valid, layer: int):
+        """One BiLSTM layer as a single scan: [B,T,H_in] → (fwd, bwd).
+
+        ``x`` is prefix-first (real events, then padding); ``valid`` [B,T] is
+        1 on its real steps.  Direction 1 reads the statically reversed
+        sequence — padding first — and its new ``h`` / ``c`` are multiplied
+        by that step's validity, so its state is exactly zero until the
+        first real event and its outputs on padding are exactly zero.
+        Direction 0 takes no mask: its padding follows its real steps and
+        the caller masks it after the merge."""
         cfg = self.cfg
         dt = cfg.dtype
         H = cfg.hidden
@@ -130,38 +135,45 @@ class ImpactLSTM(nn.Module):
                 H, name=f"OptimizedLSTMCell_{2 * layer + d}")(in_f)
             cells.append((ki.astype(dt), kh.astype(dt), bh.astype(dt)))
 
-        xr = _flip_valid(x, lengths)
-        # hoisted input projections: one matmul per direction over ALL
-        # timesteps — nothing input-dependent remains inside the scan
-        xin = jnp.stack([x.astype(dt) @ cells[0][0],
-                         xr.astype(dt) @ cells[1][0]])      # [2,B,T,4H]
+        # time-major from the narrow side: the H_in-wide input is re-laid
+        # once, and the hoisted input projections (one matmul per direction
+        # over ALL timesteps — nothing input-dependent remains inside the
+        # scan) write the 4H-wide scan inputs as [T,B,4H] directly
+        x_tm = jnp.moveaxis(x.astype(dt), -2, 0)            # [T,B,H_in]
+        xs_fwd = x_tm @ cells[0][0]                         # [T,B,4H]
+        xs_bwd = jnp.flip(x_tm, axis=0) @ cells[1][0]
+        keep_bwd = jnp.flip(jnp.moveaxis(valid.astype(dt), -1, 0),
+                            axis=0)[..., None]              # [T,B,1]
+        keep = jnp.stack([jnp.ones_like(keep_bwd), keep_bwd], axis=1)
         wh = jnp.stack([cells[0][1], cells[1][1]])          # [2,H,4H]
 
-        batch_shape = xin.shape[:-2][1:]  # [B] (or () for unbatched input)
+        batch_shape = x.shape[:-2]  # [B] (or () for unbatched input)
         # bias must broadcast against [2, *batch_shape, 4H] whatever the
         # batch rank — a fixed [:, None, :] breaks the unbatched case
         bias = jnp.stack([cells[0][2], cells[1][2]]).reshape(
             (2,) + (1,) * len(batch_shape) + (-1,))
         h0 = jnp.zeros((2,) + batch_shape + (H,), dt)
         c0 = jnp.zeros_like(h0)
-        xs = jnp.moveaxis(xin, -2, 0)                       # [T,2,B,4H]
 
-        def step(carry, x_t):
+        def step(carry, inp):
             h, c = carry
-            gates = x_t + jnp.einsum("d...h,dhg->d...g", h, wh) + bias
+            x_fwd, x_bwd, keep_t = inp
+            gates = (jnp.stack([x_fwd, x_bwd])
+                     + jnp.einsum("d...h,dhg->d...g", h, wh) + bias)
             gi, gf, gg, go = jnp.split(gates, 4, axis=-1)
-            c = nn.sigmoid(gf) * c + nn.sigmoid(gi) * jnp.tanh(gg)
-            h = nn.sigmoid(go) * jnp.tanh(c)
+            c = (nn.sigmoid(gf) * c + nn.sigmoid(gi) * jnp.tanh(gg)) * keep_t
+            h = nn.sigmoid(go) * jnp.tanh(c) * keep_t
             return (h, c), h
 
         # named scope mirrors the host tracing spine: the recurrence's XLA
         # trace rows appear as lstm_scan in Perfetto next to the
         # train_step_call host span
         with jax.named_scope("lstm_scan"):
-            (_, _), hs = jax.lax.scan(step, (h0, c0), xs)   # [T,2,B,H]
-        hs = jnp.moveaxis(hs, 0, -2)                        # [2,B,T,H]
-        fwd = hs[0]
-        bwd = _flip_valid(hs[1], lengths)  # back to original time order
+            (_, _), hs = jax.lax.scan(
+                step, (h0, c0), (xs_fwd, xs_bwd, keep))     # [T,2,B,H]
+        fwd = jnp.moveaxis(hs[:, 0], 0, -2)                 # [B,T,H]
+        # back to original time order
+        bwd = jnp.moveaxis(jnp.flip(hs[:, 1], axis=0), 0, -2)
         return fwd, bwd
 
     @nn.compact
@@ -187,7 +199,7 @@ class ImpactLSTM(nn.Module):
         for i in range(cfg.num_layers):
             with jax.named_scope(f"lstm_layer_{i}"):
                 if impl == "fused":
-                    fwd, bwd = self._fused_bilayer(x, lengths, i)
+                    fwd, bwd = self._fused_bilayer(x, mask_pf[..., 0], i)
                 else:
                     fwd = nn.RNN(nn.OptimizedLSTMCell(cfg.hidden, dtype=dt),
                                  name=f"fwd_{i}")(x, seq_lengths=lengths)

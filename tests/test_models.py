@@ -1,3 +1,4 @@
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -96,8 +97,10 @@ def test_graphsage_param_count_matches_spec():
     assert GraphSAGEConfig().num_layers == 28
 
 
-def test_lstm_padding_invariance():
-    """Left-padding must not change the prediction for the same events."""
+@pytest.mark.parametrize("impl", ["fused", "rnn"])
+def test_lstm_padding_invariance(impl):
+    """Left-padding must not change the prediction for the same events, on
+    either implementation (dropout off: the comparison is of two forwards)."""
     rng = np.random.default_rng(0)
     T, F = 16, SEQ_FEATURE_DIM
     ev = rng.normal(size=(1, 6, F)).astype(np.float32)
@@ -110,7 +113,8 @@ def test_lstm_padding_invariance():
     mask_long = np.zeros((1, T + 8), np.bool_)
     mask_long[:, T + 8 - 6:] = True
 
-    model = ImpactLSTM(LSTMConfig(hidden=16, num_layers=1, dropout=0.0))
+    model = ImpactLSTM(LSTMConfig(hidden=16, num_layers=1, dropout=0.0,
+                                  impl=impl))
     params = model.init(jax.random.PRNGKey(1), jnp.asarray(short), jnp.asarray(mask_short))["params"]
     o1 = model.apply({"params": params}, jnp.asarray(short), jnp.asarray(mask_short))
     o2 = model.apply({"params": params}, jnp.asarray(longpad), jnp.asarray(mask_long))
@@ -226,32 +230,54 @@ def test_gnn_fused_mode_gradient_parity():
     assert worst < 1e-3, errs
 
 
-def test_lstm_impl_paths_parity():
-    """fused (one scan, both directions, hoisted input projections) and
-    rnn (flax RNN/OptimizedLSTMCell) must agree exactly in f32 on shared
-    params, including ragged lengths and an all-pad row."""
-    import dataclasses
-
-    import jax
-    import jax.numpy as jnp
-
-    from nerrf_tpu.models.lstm import ImpactLSTM, LSTMConfig
-
+def _ragged_lstm_case(lengths, T=20, F=12, hidden=16, num_layers=2):
+    """Left-padded float32 sequences of the given lengths, the `fused`
+    model and its parameters with every bias moved to 0.3: a step taken on
+    padding then leaves a non-zero state behind, so a direction that walks
+    into its padding shows."""
     rng = np.random.default_rng(0)
-    B, T, F = 6, 20, 12
+    B = len(lengths)
     feat = rng.normal(size=(B, T, F)).astype(np.float32)
-    lengths = np.array([20, 13, 7, 1, 0, 19])
     mask = np.zeros((B, T), bool)
-    for i, L in enumerate(lengths):
-        if L:
-            mask[i, T - L:] = True  # left-padded: valid suffix
+    for i, n in enumerate(lengths):
+        if n:
+            mask[i, T - n:] = True  # left-padded: valid suffix
     feat = feat * mask[..., None]
 
-    cfg_f = LSTMConfig(hidden=16, num_layers=2, dropout=0.0,
-                       dtype=jnp.float32, impl="fused")
-    cfg_r = dataclasses.replace(cfg_f, impl="rnn")
-    mf, mr = ImpactLSTM(cfg_f), ImpactLSTM(cfg_r)
+    mf = ImpactLSTM(LSTMConfig(hidden=hidden, num_layers=num_layers,
+                               dropout=0.0, dtype=jnp.float32, impl="fused"))
     p = mf.init(jax.random.PRNGKey(0), feat, mask)["params"]
+    p = jax.tree_util.tree_map_with_path(
+        lambda path, v: v + 0.3 if path[-1].key == "bias" else v, p)
+    return mf, p, jnp.asarray(feat), jnp.asarray(mask)
+
+
+def _weighted_loss(model, mask, seed=1):
+    """A scalar of both outputs with fixed random weights, so that every
+    sequence and every embedding column has a gradient of its own."""
+    rng = np.random.default_rng(seed)
+    B = mask.shape[0]
+    w = jnp.asarray(rng.normal(size=(B,)).astype(np.float32))
+    we = jnp.asarray(
+        rng.normal(size=(B, model.cfg.hidden)).astype(np.float32))
+
+    def loss(p, x):
+        out = model.apply({"params": p}, x, mask)
+        return (out["seq_logit"] * w).sum() + (out["seq_emb"] * we).sum()
+
+    return loss
+
+
+def test_lstm_impl_paths_parity():
+    """fused (one scan, both directions, hoisted input projections, the
+    reverse direction a masked scan over the statically reversed input) and
+    rnn (flax RNN/OptimizedLSTMCell + seq_lengths) must agree in f32 on
+    shared params — outputs AND gradients (parameters and `seq_feat`) —
+    over ragged lengths that include 0, 1 and T."""
+    import dataclasses
+
+    mf, p, feat, mask = _ragged_lstm_case([20, 13, 7, 2, 1, 0, 19])
+    mr = ImpactLSTM(dataclasses.replace(mf.cfg, impl="rnn"))
     pr = mr.init(jax.random.PRNGKey(0), feat, mask)["params"]
     assert (jax.tree_util.tree_structure(p)
             == jax.tree_util.tree_structure(pr))
@@ -260,6 +286,73 @@ def test_lstm_impl_paths_parity():
     for k in ("seq_logit", "seq_emb"):
         err = np.max(np.abs(np.asarray(of[k]) - np.asarray(orr[k])))
         assert err < 1e-4, (k, err)
+
+    gf = jax.grad(_weighted_loss(mf, mask), argnums=(0, 1))(p, feat)
+    gr = jax.grad(_weighted_loss(mr, mask), argnums=(0, 1))(p, feat)
+    errs = jax.tree_util.tree_map(
+        lambda a, b: float(jnp.max(jnp.abs(a - b))), gf, gr)
+    worst = max(jax.tree_util.tree_leaves(errs))
+    assert worst < 1e-4, errs
+    # the gradients are not trivially equal: every leaf is alive, and no
+    # input gradient lands on a padded step
+    for leaf in jax.tree_util.tree_leaves(gf[0]):
+        assert float(jnp.max(jnp.abs(leaf))) > 1e-3
+    assert float(jnp.max(jnp.abs(gf[1] * ~mask[..., None]))) == 0.0
+    assert float(jnp.max(jnp.abs(gf[1]))) > 1e-3
+
+
+def test_lstm_fused_program_holds_no_gather_and_no_scatter():
+    """The mechanism's witness off the chip: the reverse direction is a
+    static reversal plus a mask, so neither the forward nor the gradient
+    program of the `fused` path holds a gather or, as a gather's
+    transpose, a scatter, whatever the lengths are."""
+    mf, p, feat, mask = _ragged_lstm_case([20, 13, 7, 2, 1, 0, 19])
+    fwd = jax.jit(lambda p, x: mf.apply({"params": p}, x, mask))
+    grad = jax.jit(jax.grad(_weighted_loss(mf, mask), argnums=(0, 1)))
+    for program in (fwd, grad):
+        text = program.lower(p, feat).as_text()
+        assert "stablehlo.while" in text  # the scan is in this text
+        assert "gather" not in text and "scatter" not in text, [
+            line for line in text.splitlines()
+            if "gather" in line or "scatter" in line][:4]
+
+
+def test_lstm_fused_bilayer_masks_the_reverse_direction_and_takes_no_batch():
+    """`_fused_bilayer` alone, prefix-first input: the reverse direction's
+    hidden outputs on padded steps are exactly zero (its state is held at
+    zero until its first real event, though the biases are not), on real
+    steps they are what the sequence cut to its length gives, and an
+    unbatched `[T, F]` input gives its row of the batch."""
+    mf, p, feat, mask = _ragged_lstm_case(
+        [20, 13, 7, 2, 1, 0, 19], F=16, num_layers=1)
+    x = jnp.flip(feat, axis=-2)                     # prefix-first
+    valid = jnp.flip(mask, axis=-1).astype(jnp.float32)
+
+    class Bilayer(ImpactLSTM):
+        @nn.compact
+        def __call__(self, x, valid):
+            return self._fused_bilayer(x, valid, 0)
+
+    def bilayer(x, valid):
+        return Bilayer(mf.cfg).apply({"params": p}, x, valid)
+
+    fwd, bwd = bilayer(x, valid)
+    assert fwd.shape == bwd.shape == (7, 20, 16)
+    pad = np.asarray(valid) == 0
+    assert pad.any() and np.all(np.asarray(bwd)[pad] == 0.0)
+    assert np.all(np.abs(np.asarray(bwd)[~pad]) > 0.0)
+    # direction 0 is not masked here: its padding follows its real steps
+    assert np.all(np.abs(np.asarray(fwd)[pad]) > 0.0)
+    for row, n in ((1, 13), (3, 2), (4, 1)):
+        _, cut = bilayer(x[row, :n], valid[row, :n])
+        np.testing.assert_allclose(np.asarray(bwd[row, :n]), np.asarray(cut),
+                                   rtol=0, atol=1e-6)
+    f1, b1 = bilayer(x[1], valid[1])                # unbatched [T, F]
+    assert f1.shape == b1.shape == (20, 16)
+    np.testing.assert_allclose(np.asarray(f1), np.asarray(fwd[1]),
+                               rtol=0, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(b1), np.asarray(bwd[1]),
+                               rtol=0, atol=1e-6)
 
 
 def test_dense_adj_aggregate_is_scoped_like_the_fused_route():
