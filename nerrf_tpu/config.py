@@ -322,8 +322,36 @@ def _experiments() -> Dict[str, Experiment]:
             dropout=0.0),
         stream_data=PackConfig(),
     )
+    stream_moe = Experiment(
+        name="stream-keye-vl2-30b-a3b",
+        description=(
+            "Stream-encoder pretraining on whole-document 8k event streams: "
+            "Keye-VL-2.0-30B-A3B's decoder (grouped-query attention over the "
+            "2048 keys a learned indexer chooses, 128 routed experts, 8 a "
+            "token) at its published widths, six of 48 layers, experts 0-15 "
+            "of 128 and an eighth of both vocabulary matrices: what one chip "
+            "of an 8-chip expert-parallel slice holds "
+            "(docs/stream-backbone.md; chipbench/configs/keye-vl2-30b-a3b.json)"
+        ),
+        corpus=CorpusConfig(num_traces=6, attack_fraction=0.5,
+                            duration_sec=180.0, num_target_files=45,
+                            benign_rate_hz=550.0, eval_fraction=0.0),
+        # a long warm-up: under a short one the router's load on the held
+        # experts moves within the first twenty steps (PERF.md section 6)
+        train=TrainConfig(batch_size=1, num_steps=20000, learning_rate=3e-4,
+                          warmup_steps=2000, weight_decay=0.1, eval_every=20),
+        stream=StreamConfig(
+            dim=2048, num_heads=32, num_kv_heads=4, head_dim=128,
+            num_layers=6, kinds=("dsa_moe",) * 6, vocab_size=18992,
+            dropout=0.0, rope_theta=1e7, index_heads=16, index_head_dim=64,
+            index_topk=2048, index_loss_weight=1.0, num_experts=128,
+            experts_per_token=8, expert_dim=768, first_expert=0,
+            held_experts=16, rms_eps=1e-6, tie_head=False),
+        stream_data=PackConfig(doc_median=16384.0, doc_sigma=0.5,
+                               doc_min=2048),
+    )
     return {e.name: e for e in (toy, lstm, joint, dense, mcts, multihost,
-                                stream_lm)}
+                                stream_lm, stream_moe)}
 
 
 EXPERIMENTS: Dict[str, Experiment] = _experiments()

@@ -29,10 +29,22 @@ The backbone is a stack built from a list of layer kinds
   *tokens* (``vocab_size`` > 0: an embedding tied to the output head) in
   packed sequences with segment ids, train on the next token, and run on
   one chip's local attention path (``sp`` > 1 is refused by the ring).
+* ``dsa_moe`` — a sparse-attention, sparse-expert decoder layer (RMSNorm;
+  grouped-query attention with per-head q/k norm and rotary positions that
+  restart at each packed document, over the ``index_topk`` keys a learned
+  indexer chooses for each query: `ops/dsa.py`; then a router over
+  ``num_experts`` experts, ``experts_per_token`` a token, of which this
+  chip computes the ``held_experts`` from ``first_expert`` on:
+  `ops/moe.py`).  It reads event tokens like the hybrid kinds, ends in an
+  RMSNorm and, with ``tie_head`` false, a head of its own; beside the
+  residual it returns the indexer's loss and its routing and selection
+  counts, which `StreamNet` sums into ``aux``.
 
-No positional encoding anywhere (event streams are irregularly sampled —
-wall-clock gaps carry signal, so Δt enters as a feature or a token, not a
-position index); bfloat16 compute, float32 parameters.
+The ``block`` and hybrid kinds carry no positional encoding (event streams
+are irregularly sampled — wall-clock gaps carry signal, so Δt enters as a
+feature or a token, not a position index); ``dsa_moe`` carries its source's
+rotary embedding, counted inside a document.  bfloat16 compute, float32
+parameters.
 """
 
 from __future__ import annotations
@@ -47,10 +59,12 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
+from nerrf_tpu.ops import dsa, moe
 from nerrf_tpu.ops.ssm import causal_conv1d, selective_scan
 from nerrf_tpu.parallel.ring import ring_self_attention
 
 HYBRID_KINDS = ("mamba", "swa", "full", "gmu", "cross")
+SPARSE_KIND = "dsa_moe"
 
 
 def layer_kinds(num_layers: int) -> Tuple[str, ...]:
@@ -102,6 +116,25 @@ class StreamConfig:
     # each layer's index in the published stack, where the stack is a cut
     # of one: differential attention's lam_init is a function of it
     published_layers: Tuple[int, ...] = ()
+    # the ``dsa_moe`` kind.  Attention: rotary base, the indexer's heads,
+    # their size, the keys a query keeps, the weight of the indexer's loss
+    rope_theta: float = 1e7
+    index_heads: int = 16
+    index_head_dim: int = 64
+    index_topk: int = 2048
+    index_loss_weight: float = 1.0
+    # its experts: the router's outputs, the experts a token is sent to,
+    # an expert's width, and this chip's share ``[first_expert,
+    # first_expert + held_experts)``
+    num_experts: int = 128
+    experts_per_token: int = 8
+    expert_dim: int = 768
+    first_expert: int = 0
+    held_experts: int = 16
+    rms_eps: float = 1e-6
+    # False: the output head is a matrix of its own (``lm_head``), not the
+    # embedding
+    tie_head: bool = True
 
     def __post_init__(self):
         for name in ("kinds", "published_layers"):
@@ -295,6 +328,77 @@ class _HybridLayer(nn.Module):
             return _SwiGLU(cfg, name="mlp")(x + out), m, kv
 
 
+class _SparseMoELayer(nn.Module):
+    """One ``dsa_moe`` decoder layer: ``h = x + W_o Attn_S(RMSNorm(x))``
+    over the keys the indexer chooses, ``y = h + Experts(RMSNorm(h))`` over
+    the experts held here.  ``(x, seg) -> (y, aux)``; ``aux`` holds float32
+    scalars: the indexer's loss (mean ``KL_t`` over real tokens), the
+    selected and the allowed pairs, the assignments to held experts and
+    their largest count over the mean."""
+
+    cfg: StreamConfig
+    index: int
+
+    @nn.compact
+    def __call__(self, x, seg):
+        cfg = self.cfg
+        hq, hk, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+        b, t, _ = x.shape
+        f32 = jnp.float32
+        norm = lambda name: nn.RMSNorm(epsilon=cfg.rms_eps, dtype=cfg.dtype,
+                                       name=name)
+        exact = lambda features, name: nn.Dense(
+            features, use_bias=False, dtype=f32, name=name,
+            precision=jax.lax.Precision.HIGHEST)
+        with jax.named_scope(f"stream_layer_{self.index}"):
+            u = norm("attn_norm")(x)
+            q = norm("q_norm")(
+                _dense(hq * d, cfg, "wq")(u).reshape(b, t, hq, d))
+            k = norm("k_norm")(
+                _dense(hk * d, cfg, "wk")(u).reshape(b, t, hk, d))
+            v = _dense(hk * d, cfg, "wv")(u).reshape(b, t, hk, d)
+            # the indexer reads the layer's input without moving it
+            ui = jax.lax.stop_gradient(u).astype(f32)
+            j, e = cfg.index_heads, cfg.index_head_dim
+            qi = exact(j * e, "index_q")(ui).reshape(b, t, j, e)
+            ki = nn.LayerNorm(epsilon=cfg.rms_eps, dtype=f32,
+                              name="index_k_norm")(exact(e, "index_k")(ui))
+            wi = exact(j, "index_w")(ui) * (j * e) ** -0.5
+            # read only by `apply(..., mutable=["intermediates"])`
+            self.sow("intermediates", "index_inputs", (qi, ki, wi))
+
+            def one(q, k, v, qi, ki, wi, seg):
+                pos = dsa.doc_positions(seg)
+                return dsa.sparse_attention(
+                    dsa.rope(q, pos, cfg.rope_theta),
+                    dsa.rope(k, pos, cfg.rope_theta), v, qi, ki, wi, seg,
+                    topk=cfg.index_topk) + (dsa.causal_pairs(seg),)
+
+            o, kl, chosen, allowed = jax.vmap(one)(q, k, v, qi, ki, wi, seg)
+            h = x + _dense(cfg.dim, cfg, "wo")(o.reshape(b, t, hq * d))
+
+            z = norm("moe_norm")(h).reshape(b * t, cfg.dim)
+            logits = exact(cfg.num_experts, "router")(z.astype(f32))
+            self.sow("intermediates", "router_logits", logits)
+            shape = (cfg.held_experts, cfg.dim, cfg.expert_dim)
+            init = lambda fan_in: nn.initializers.normal(fan_in ** -0.5)
+            w = [self.param(name, init(s[1]), s, f32)
+                 for name, s in (("w_gate", shape), ("w_up", shape),
+                                 ("w_down", (shape[0], shape[2], shape[1])))]
+            y, counts = moe.moe_share(z, logits, *w,
+                                      k=cfg.experts_per_token,
+                                      first=cfg.first_expert)
+            real = jnp.maximum(jnp.sum(seg > 0), 1).astype(f32)
+            counts = counts.astype(f32)
+            aux = {"index_loss": jnp.sum(kl) / real,
+                   "selected_pairs": jnp.sum(chosen).astype(f32),
+                   "allowed_pairs": jnp.sum(allowed),
+                   "held_assignments": jnp.sum(counts),
+                   "load_max_over_mean": jnp.max(counts) / jnp.maximum(
+                       jnp.mean(counts), 1.0)}
+            return h + y.reshape(b, t, cfg.dim).astype(cfg.dtype), aux
+
+
 class StreamNet(nn.Module):
     """The event-stream encoder.
 
@@ -302,9 +406,11 @@ class StreamNet(nn.Module):
     mask [B, T] bool)`` -> per-event attack logits ``event_logits`` [B, T]
     and their ``stream_logit``.  ``vocab_size`` > 0 (the pretrainer):
     ``(tokens [B, T] int32, segments [B, T] int32: a packed document's id,
-    0 = padding)`` -> ``hidden`` [B, T, dim] after the final LayerNorm; the
-    logits are ``hidden @ embedding.T`` and are never built whole
-    (`next_token_loss`).
+    0 = padding)`` -> ``hidden`` [B, T, dim] after the final norm; the
+    logits are ``hidden @ embedding.T`` (``hidden @ lm_head.T`` where the
+    head is untied) and are never built whole (`next_token_loss`).  A stack
+    with ``dsa_moe`` layers also returns ``aux``: their indexer losses
+    summed, their counts summed, their load imbalance averaged.
 
     ``mesh`` is a static module attribute: when it carries an ``sp`` axis of
     size > 1, every ``block`` layer runs as ring attention with T sharded
@@ -334,17 +440,39 @@ class StreamNet(nn.Module):
             x = nn.gelu(x)
         block_cls = nn.remat(_Block, static_argnums=(2,)) if cfg.remat else _Block
         layer_cls = nn.remat(_HybridLayer) if cfg.remat else _HybridLayer
+        # its remat keeps what `ops/dsa.py` names: the backward pass then
+        # does not run the indexer, the selection and the attention again
+        sparse_cls = (nn.remat(
+            _SparseMoELayer,
+            policy=jax.checkpoint_policies.save_only_these_names(dsa.SAVED))
+            if cfg.remat else _SparseMoELayer)
         m = kv = None
+        sparse_aux = []
         for i, kind in enumerate(cfg.stack):
             if kind == "block":
                 x = block_cls(cfg, self.mesh, name=f"block_{i}")(
                     x, deterministic
                 )
+            elif kind == SPARSE_KIND:
+                x, aux = sparse_cls(cfg, i, name=f"layer_{i}")(x, seg)
+                sparse_aux.append(aux)
             else:
                 x, m, kv = layer_cls(cfg, kind, i, name=f"layer_{i}")(
                     x, seg, m, kv)
-        x = nn.LayerNorm(epsilon=1e-5 if cfg.vocab_size else 1e-6, dtype=dt,
-                         name="final_ln")(x)
+        if sparse_aux:
+            x = nn.RMSNorm(epsilon=cfg.rms_eps, dtype=dt, name="final_norm")(x)
+        else:
+            x = nn.LayerNorm(epsilon=1e-5 if cfg.vocab_size else 1e-6,
+                             dtype=dt, name="final_ln")(x)
+        if cfg.vocab_size and not cfg.tie_head:
+            self.param("lm_head", nn.initializers.normal(cfg.dim ** -0.5),
+                       (cfg.vocab_size, cfg.dim), jnp.float32)
+        if sparse_aux:
+            aux = jax.tree_util.tree_map(lambda *v: sum(v), *sparse_aux)
+            aux["load_max_over_mean"] /= len(sparse_aux)
+            # every position is routed, padding too
+            aux["routed_tokens"] = jnp.float32(x.shape[0] * x.shape[1])
+            return {"hidden": x, "aux": aux}
         if cfg.vocab_size:
             return {"hidden": x}
         logits = nn.Dense(1, dtype=jnp.float32, name="head")(x)[..., 0]
@@ -375,9 +503,11 @@ LOSS_CHUNK = 1024
 def next_token_loss(cfg: StreamConfig, params, hidden, tokens, seg,
                     chunk: int = LOSS_CHUNK):
     """Mean next-token cross-entropy over the held vocabulary rows, through
-    the head tied to the embedding, ``chunk`` positions at a time: a chunk's
-    logits live only inside its `jax.checkpoint`."""
-    emb = params["tok_embed"]["embedding"].astype(cfg.dtype)
+    the head (the embedding itself, or ``lm_head`` where ``tie_head`` is
+    false), ``chunk`` positions at a time: a chunk's logits live only
+    inside its `jax.checkpoint`."""
+    emb = (params["tok_embed"]["embedding"] if cfg.tie_head
+           else params["lm_head"]).astype(cfg.dtype)
     b, t, dim = hidden.shape
     chunk = min(chunk, b * t)
     if (b * t) % chunk:

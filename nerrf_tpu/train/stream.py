@@ -41,14 +41,50 @@ def make_stream_loss_fn(model: StreamNet):
     def loss_fn(params, batch, dropout_rng):
         out = model.apply({"params": params}, batch[first], batch[second],
                           deterministic=False, rngs={"dropout": dropout_rng})
-        if scfg.vocab_size:
-            loss = next_token_loss(scfg, params, out["hidden"],
-                                   batch["tokens"], batch["segments"])
-        else:
-            loss = stream_loss(out, batch["label"], batch["mask"])
-        return loss, {}
+        if not scfg.vocab_size:
+            return stream_loss(out, batch["label"], batch["mask"]), {}
+        loss = next_token_loss(scfg, params, out["hidden"],
+                               batch["tokens"], batch["segments"])
+        aux = out.get("aux", {})
+        if aux:
+            # the indexer's own term (`ops/dsa.py`): without it the
+            # indexer's parameters would never receive a gradient
+            aux = dict(aux, token_loss=loss)
+            loss = loss + scfg.index_loss_weight * aux["index_loss"]
+        return loss, aux
 
     return loss_fn
+
+
+def count_sparse(aux: dict, scfg: StreamConfig, steps: int = 1) -> None:
+    """A ``dsa_moe`` step's ``aux`` -> the program's registry.  It floats
+    device scalars, so the loop calls it only where it already syncs;
+    ``steps`` is how many steps that sync stands for (the counters then
+    assume they routed alike)."""
+    from nerrf_tpu.observability import DEFAULT_REGISTRY as reg
+
+    if "held_assignments" not in aux:
+        return
+    held = float(aux["held_assignments"])
+    chosen, allowed = (float(aux[k]) for k in ("selected_pairs",
+                                               "allowed_pairs"))
+    layers = scfg.stack.count("dsa_moe")
+    total = float(aux["routed_tokens"]) * scfg.experts_per_token * layers
+    help_ = "token-to-expert assignments of the routed layers, by whether " \
+            "this chip holds the expert"
+    reg.counter_inc("moe_assignments_total", held * steps,
+                    labels={"held": "true"}, help=help_)
+    reg.counter_inc("moe_assignments_total", (total - held) * steps,
+                    labels={"held": "false"}, help=help_)
+    reg.counter_inc("dsa_selected_pairs_total", chosen * steps,
+                    help="query-key pairs the indexer's selection kept")
+    reg.gauge_set("moe_expert_load_max_over_mean",
+                  float(aux["load_max_over_mean"]),
+                  help="largest held expert's assignments over the mean, "
+                       "averaged over layers, last synced step")
+    reg.gauge_set("dsa_selected_share", chosen / max(allowed, 1.0),
+                  help="selected over causal same-document pairs, last "
+                       "synced step")
 
 
 def stream_key_extra(scfg: StreamConfig) -> dict:
@@ -99,12 +135,15 @@ def train_stream(arrays: dict, scfg: StreamConfig, cfg: loop.TrainConfig,
                             loop.make_idx_schedule(n, cfg), compile_cache)
     history = []
     t_first = None
+    synced = -1
     for i in range(cfg.num_steps):
-        state, loss, _aux, rng = step(state, rng)
+        state, loss, aux, rng = step(state, rng)
         if i == 0 or (i + 1) % cfg.eval_every == 0 or i == cfg.num_steps - 1:
             # the loop's only sync: logged steps (eval_every)
             history.append({"step": i, "loss": float(loss)})
             log(f"step {i}: loss {history[-1]['loss']:.4f}")
+            count_sparse(aux, scfg, steps=i - synced)
+            synced = i
             if t_first is None:      # step 0 holds the compile
                 t_first = time.perf_counter()
     steps_per_sec = max(cfg.num_steps - 1, 1) / max(

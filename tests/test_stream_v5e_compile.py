@@ -66,3 +66,42 @@ def test_local_attention_compiles_with_window_and_segments(one_chip,
         shape(one_chip, (1, T), jnp.int32)).compile()
     # never the [heads, T, T] scores (10.7 GB in float32); 2.2 GB today
     assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
+
+
+def test_sparse_attention_runs_its_blocks_one_after_another(one_chip):
+    """`ops/dsa.py::sparse_attention` at 32 query / 4 key-value heads of 128,
+    16 index heads of 64 and 2048 keys a query, forward + backward: a block
+    of 256 queries holds a few [32, 256, 8192] float32 arrays, and the four
+    key lengths' copies of the block each get scratch of their own; side by
+    side (a Python loop over the blocks) the plan was 98 GB."""
+    from nerrf_tpu.ops import dsa
+
+    def loss(q, k, v, qi, ki, wi, seg):
+        o, kl, _ = dsa.sparse_attention(q, k, v, qi, ki, wi, seg, topk=2048)
+        return jnp.sum(o.astype(jnp.float32) ** 2) + kl
+
+    kv = shape(one_chip, (T, 4, 128), jnp.bfloat16)
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4, 5))).lower(
+        shape(one_chip, (T, 32, 128), jnp.bfloat16), kv, kv,
+        shape(one_chip, (T, 16, 64)), shape(one_chip, (T, 64)),
+        shape(one_chip, (T, 16)), shape(one_chip, (T,), jnp.int32)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 5 << 30
+
+
+def test_expert_walk_never_copies_the_weights_per_tile(one_chip):
+    """`ops/moe.py::moe_share` at 8192 tokens, 16 held experts of 2048 x
+    768, 8 of 128 a token, forward + backward: the worst routing has 272
+    tiles, and a copy of the three matrices for each (what reverse mode
+    through a scan of conds kept) would be 38 GB; the hand-written walk
+    holds the float32 gradients (0.45 GB), the buffer and its row maps."""
+    from nerrf_tpu.ops import moe
+
+    def loss(x, logits, wg, wu, wd):
+        y, _ = moe.moe_share(x, logits, wg, wu, wd, k=8, first=0)
+        return jnp.sum(y ** 2)
+
+    w = shape(one_chip, (16, 2048, 768))
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 2, 3, 4))).lower(
+        shape(one_chip, (T, 2048), jnp.bfloat16), shape(one_chip, (T, 128)),
+        w, w, shape(one_chip, (16, 768, 2048))).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
