@@ -17,7 +17,27 @@ data-dependent gather in the forward program and no scatter in the backward
 one (reversing inside each valid prefix by `take_along_axis` costs 13 ms a
 step of 8 x 128 sequences on a v5e; PERF.md, PR 32).  An operation inside
 the loop costs 2-11 us on that chip, so what the loop body holds is counted
-in fusions, not in FLOPs.  Param tree is bit-compatible with the previous
+in fusions, not in FLOPs.
+
+The recurrence itself is `bilstm_recurrence`, a function of arrays with a
+hand-written reverse rule (PERF.md, PR 34).  `jax.grad` of `lax.scan` kept
+13 stacked tensors of `[T,2,B,H]` a layer, and what they cost was bytes,
+not fusions: at 8 windows x 128 sequences x 100 steps one is 104.9 MB, a
+step zero-filled and then stored 24 of them (2.52 GB each way, both near
+the memory's speed, 8 of the BiLSTM's 29 ms).  The rule keeps `h` (the
+output anyway), the cell state each step started from and the four
+activated gates as ONE `[T,2,B,4H]` tensor; its backward loop carries
+`(dh, dc, c_t)`, is one gate-derivative fusion and one product an
+iteration, and stacks one tensor, `dgates`, which IS the cotangent of the
+hoisted projections.  The weight gradient is one product AFTER the loop
+(`h_{t-1}` against `dgates`, K = T x B, float32 out), because the training
+step is `jax.grad` of a `jax.vmap` over windows with the parameters closed
+over (`train/loop.py`): a rule's weight gradient comes out a window and is
+summed by `custom_vjp`'s batching rule, so inside the loop it would be a
+window's worth of K = B products an iteration.  Asked for no gradient the
+function is the plain scan, one stacked output.
+
+Param tree is bit-compatible with the previous
 `flax.linen.RNN(OptimizedLSTMCell)` implementation
 (``OptimizedLSTMCell_{2i}``=fwd / ``_{2i+1}``=bwd, ``ii..io``/``hi..ho``
 leaves), which remains available as ``LSTMConfig.impl="rnn"`` and is
@@ -105,6 +125,109 @@ class _CellParams(nn.Module):
                 jnp.concatenate(bh, axis=0))
 
 
+def _loop_operands(like, wh, bias):
+    """The recurrent kernel and bias in the loops' arithmetic type (that of
+    ``like``, one step's ``[2, *batch, n]``), the bias shaped to broadcast
+    against ``[2, *batch, 4H]`` whatever the batch rank, and the zero
+    state."""
+    dt = like.dtype
+    bias = bias.astype(dt).reshape((2,) + (1,) * (like.ndim - 2) + (-1,))
+    h0 = jnp.zeros(like.shape[:-1] + (wh.shape[1],), dt)
+    return wh.astype(dt), bias, h0
+
+
+def _cell(h, c, x_fwd, x_bwd, keep_t, wh, bias):
+    """One step of both directions: the new ``h``, ``c`` and the activated
+    gates ``(i, f, g, o)`` as one ``[2, *batch, 4H]`` tensor."""
+    gates = (jnp.stack([x_fwd, x_bwd])
+             + jnp.einsum("d...h,dhg->d...g", h, wh) + bias)
+    gi, gf, gg, go = jnp.split(gates, 4, axis=-1)
+    i, f, g, o = nn.sigmoid(gi), nn.sigmoid(gf), jnp.tanh(gg), nn.sigmoid(go)
+    c = (f * c + i * g) * keep_t
+    h = o * jnp.tanh(c) * keep_t
+    return h, c, jnp.concatenate([i, f, g, o], axis=-1)
+
+
+def _recurrence(xs_fwd, xs_bwd, keep, wh, bias):
+    """Both directions of one BiLSTM layer as a single scan over time.
+
+    ``xs_fwd`` / ``xs_bwd`` ``[T, *batch, 4H]``: the hoisted input
+    projections, time-major, the second already reversed along time;
+    ``keep`` ``[T, 2, *batch, 1]``: what a step's new ``h`` and ``c`` are
+    multiplied by; ``wh`` ``[2, H, 4H]`` and ``bias`` ``[2, 4H]`` in the
+    parameters' own type.  Returns ``hs`` ``[T, 2, *batch, H]``.  This is
+    the whole definition: `bilstm_recurrence` is this function with a
+    hand-written reverse rule, and `jax.grad` of this one is what the tests
+    hold the rule to."""
+    wh, bias, h0 = _loop_operands(keep[0], wh, bias)
+
+    def step(carry, inp):
+        h, c, _ = _cell(*carry, *inp, wh, bias)
+        return (h, c), h
+
+    _, hs = jax.lax.scan(step, (h0, h0), (xs_fwd, xs_bwd, keep))
+    return hs
+
+
+bilstm_recurrence = jax.custom_vjp(_recurrence)
+
+
+def _recurrence_fwd(xs_fwd, xs_bwd, keep, wh, bias):
+    """The same scan under a gradient.  Stacked beside ``hs``: the cell
+    state each step STARTED from (so the reverse loop reads ``c_{t-1}``
+    where it reads everything else, at ``t``, and carries ``c_t`` along)
+    and the activated gates as one tensor."""
+    whc, bc, h0 = _loop_operands(keep[0], wh, bias)
+
+    def step(carry, inp):
+        h, c, acts = _cell(*carry, *inp, whc, bc)
+        return (h, c), (h, carry[1], acts)
+
+    (_, c_last), (hs, cs_prev, acts) = jax.lax.scan(
+        step, (h0, h0), (xs_fwd, xs_bwd, keep))
+    return hs, (hs, cs_prev, c_last, acts, keep, wh, bias)
+
+
+def _recurrence_bwd(res, dhs):
+    hs, cs_prev, c_last, acts, keep, wh, bias = res
+    whc, _, h0 = _loop_operands(c_last, wh, bias)
+
+    def step(carry, inp):
+        dh, dc, c = carry
+        dh_t, acts_t, c_prev, keep_t = inp
+        i, f, g, o = jnp.split(acts_t, 4, axis=-1)
+        tanh_c = jnp.tanh(c)
+        dh = (dh + dh_t) * keep_t
+        dc = (dc + dh * o * (1 - tanh_c * tanh_c)) * keep_t
+        dgates = jnp.concatenate(
+            [dc * g * i * (1 - i), dc * c_prev * f * (1 - f),
+             dc * i * (1 - g * g), dh * tanh_c * o * (1 - o)], axis=-1)
+        dh_prev = jnp.einsum("d...g,dhg->d...h", dgates, whc)
+        return (dh_prev, dc * f, c_prev), dgates
+
+    # the operations of a hand-written reverse pass need not carry the
+    # forward call's scope (PERF.md, PR 33): open the recurrence's own, so
+    # the trace reads the whole BiLSTM under `lstm`
+    with jax.named_scope("lstm_scan"):
+        _, dgates = jax.lax.scan(step, (h0, h0, c_last),
+                                 (dhs, acts, cs_prev, keep),
+                                 reverse=True)               # [T,2,B,4H]
+        # after the loop, not in it: one product with K = T x batch, and
+        # under `jax.grad` of a `jax.vmap` with the parameters closed over
+        # (train/loop.py) one a window, summed in float32 by custom_vjp's
+        # batching rule
+        hs_prev = jnp.concatenate([jnp.zeros_like(hs[:1]), hs[:-1]], axis=0)
+        dwh = jnp.einsum("td...h,td...g->dhg", hs_prev, dgates,
+                         preferred_element_type=jnp.float32)
+        dbias = dgates.sum(axis=(0,) + tuple(range(2, dgates.ndim - 1)),
+                           dtype=jnp.float32)
+    return (dgates[:, 0], dgates[:, 1], jnp.zeros_like(keep),
+            dwh.astype(wh.dtype), dbias.astype(bias.dtype))
+
+
+bilstm_recurrence.defvjp(_recurrence_fwd, _recurrence_bwd)
+
+
 class ImpactLSTM(nn.Module):
     """[B, T, F] event sequences → encrypt-probability logits [B] + embedding.
 
@@ -133,7 +256,7 @@ class ImpactLSTM(nn.Module):
         for d in range(2):
             ki, kh, bh = _CellParams(
                 H, name=f"OptimizedLSTMCell_{2 * layer + d}")(in_f)
-            cells.append((ki.astype(dt), kh.astype(dt), bh.astype(dt)))
+            cells.append((ki.astype(dt), kh, bh))
 
         # time-major from the narrow side: the H_in-wide input is re-laid
         # once, and the hoisted input projections (one matmul per direction
@@ -146,31 +269,14 @@ class ImpactLSTM(nn.Module):
                             axis=0)[..., None]              # [T,B,1]
         keep = jnp.stack([jnp.ones_like(keep_bwd), keep_bwd], axis=1)
         wh = jnp.stack([cells[0][1], cells[1][1]])          # [2,H,4H]
-
-        batch_shape = x.shape[:-2]  # [B] (or () for unbatched input)
-        # bias must broadcast against [2, *batch_shape, 4H] whatever the
-        # batch rank — a fixed [:, None, :] breaks the unbatched case
-        bias = jnp.stack([cells[0][2], cells[1][2]]).reshape(
-            (2,) + (1,) * len(batch_shape) + (-1,))
-        h0 = jnp.zeros((2,) + batch_shape + (H,), dt)
-        c0 = jnp.zeros_like(h0)
-
-        def step(carry, inp):
-            h, c = carry
-            x_fwd, x_bwd, keep_t = inp
-            gates = (jnp.stack([x_fwd, x_bwd])
-                     + jnp.einsum("d...h,dhg->d...g", h, wh) + bias)
-            gi, gf, gg, go = jnp.split(gates, 4, axis=-1)
-            c = (nn.sigmoid(gf) * c + nn.sigmoid(gi) * jnp.tanh(gg)) * keep_t
-            h = nn.sigmoid(go) * jnp.tanh(c) * keep_t
-            return (h, c), h
+        bias = jnp.stack([cells[0][2], cells[1][2]])        # [2,4H]
 
         # named scope mirrors the host tracing spine: the recurrence's XLA
         # trace rows appear as lstm_scan in Perfetto next to the
-        # train_step_call host span
+        # train_step_call host span (the reverse rule opens it itself)
         with jax.named_scope("lstm_scan"):
-            (_, _), hs = jax.lax.scan(
-                step, (h0, c0), (xs_fwd, xs_bwd, keep))     # [T,2,B,H]
+            hs = bilstm_recurrence(xs_fwd, xs_bwd, keep, wh,
+                                   bias)                    # [T,2,B,H]
         fwd = jnp.moveaxis(hs[:, 0], 0, -2)                 # [B,T,H]
         # back to original time order
         bwd = jnp.moveaxis(jnp.flip(hs[:, 1], axis=0), 0, -2)

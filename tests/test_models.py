@@ -355,6 +355,205 @@ def test_lstm_fused_bilayer_masks_the_reverse_direction_and_takes_no_batch():
                                rtol=0, atol=1e-6)
 
 
+_RAGGED = [20, 13, 7, 2, 1, 0, 19]
+_FULL = [20] * 7
+
+
+def _recurrence_case(lengths, lead=(), T=20, H=16, dtype=jnp.float32):
+    """Arguments of `lstm.bilstm_recurrence` as `_fused_bilayer` builds them
+    (`xs_fwd`, `xs_bwd` ``[T, *lead, B, 4H]``, `keep` whose direction 1
+    follows the reversed validity of prefix-first sequences of ``lengths``,
+    `wh`, `bias` in float32) and fixed random weights for every element of
+    `hs`; ``lengths`` an int: one unbatched sequence."""
+    rng = np.random.default_rng(3)
+    batch = lead + (() if isinstance(lengths, int) else (len(lengths),))
+    n = np.broadcast_to(np.asarray(lengths), batch)
+    valid = np.arange(T).reshape((T,) + (1,) * len(batch)) < n   # [T,*batch]
+    keep_bwd = valid[::-1].astype(np.float32)[..., None]
+    keep = np.stack([np.ones_like(keep_bwd), keep_bwd], axis=1)
+
+    def normal(shape, scale=1.0):
+        return (scale * rng.normal(size=shape)).astype(np.float32)
+
+    args = (jnp.asarray(normal((T,) + batch + (4 * H,)), dtype),
+            jnp.asarray(normal((T,) + batch + (4 * H,)), dtype),
+            jnp.asarray(keep, dtype),
+            jnp.asarray(normal((2, H, 4 * H), 0.3)),
+            jnp.asarray(normal((2, 4 * H), 0.3)))
+    return args, jnp.asarray(normal((T, 2) + batch + (H,)))
+
+
+def _recurrence_grads(fn, args, w, argnums=(0, 1, 3, 4)):
+    def loss(*a):
+        return (fn(*a).astype(jnp.float32) * w).sum()
+
+    return jax.grad(loss, argnums=argnums)(*args)
+
+
+@pytest.mark.parametrize("lengths,lead", [
+    (_RAGGED, ()), (_FULL, ()), (13, ()), (_RAGGED, (3,))],
+    ids=["ragged", "full", "unbatched", "two_batch_axes"])
+def test_lstm_recurrence_rule_matches_autodiff(lengths, lead):
+    """The hand-written reverse rule against `jax.grad` of the same
+    recurrence without it (the un-decorated function), float32: the same
+    `hs` and every cotangent (`xs_fwd`, `xs_bwd`, `wh`, `bias`) to 1e-5,
+    whatever the batch rank; `keep` gets zeros."""
+    from nerrf_tpu.models import lstm
+
+    args, w = _recurrence_case(lengths, lead)
+    np.testing.assert_allclose(np.asarray(lstm.bilstm_recurrence(*args)),
+                               np.asarray(lstm._recurrence(*args)),
+                               rtol=0, atol=1e-6)
+    rule = _recurrence_grads(lstm.bilstm_recurrence, args, w)
+    auto = _recurrence_grads(lstm._recurrence, args, w)
+    for name, a, b in zip(("xs_fwd", "xs_bwd", "wh", "bias"), rule, auto):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        assert float(jnp.max(jnp.abs(b))) > 1e-3, name      # alive
+        err = float(jnp.max(jnp.abs(a - b)))
+        assert err < 1e-5, (name, err)
+    dkeep, = _recurrence_grads(lstm.bilstm_recurrence, args, w, argnums=(2,))
+    assert float(jnp.max(jnp.abs(dkeep))) == 0.0
+
+
+@pytest.mark.parametrize("lengths", [_RAGGED, _FULL], ids=["ragged", "full"])
+def test_lstm_recurrence_rule_under_grad_of_vmap(lengths):
+    """`train/loop.py` takes `jax.grad` of a `jax.vmap` over the windows
+    with the parameters closed over: the rule's weight and bias gradients
+    then come out a window and are summed by `custom_vjp`'s batching rule.
+    They must be what autodiff gives, and what the windows give one by
+    one."""
+    from nerrf_tpu.models import lstm
+
+    (xs_fwd, xs_bwd, keep, wh, bias), w = _recurrence_case(lengths, (3,))
+
+    def grads(fn):
+        def loss(wh, bias):
+            hs = jax.vmap(lambda a, b, k: fn(a, b, k, wh, bias),
+                          in_axes=(1, 1, 2), out_axes=2)(xs_fwd, xs_bwd, keep)
+            return (hs * w).sum()
+
+        return jax.grad(loss, argnums=(0, 1))(wh, bias)
+
+    rule, auto = grads(lstm.bilstm_recurrence), grads(lstm._recurrence)
+    one_by_one = [
+        _recurrence_grads(lstm.bilstm_recurrence,
+                          (xs_fwd[:, i], xs_bwd[:, i], keep[:, :, i], wh,
+                           bias), w[:, :, i], argnums=(3, 4))
+        for i in range(3)]
+    for k, name in enumerate(("wh", "bias")):
+        assert rule[k].shape == auto[k].shape, name
+        err = float(jnp.max(jnp.abs(rule[k] - auto[k])))
+        assert err < 1e-5, (name, err)
+        summed = sum(g[k] for g in one_by_one)
+        err = float(jnp.max(jnp.abs(rule[k] - summed)))
+        assert err < 1e-5, (name, err)
+
+
+@pytest.mark.parametrize("lengths", [_RAGGED, _FULL], ids=["ragged", "full"])
+def test_lstm_recurrence_rule_bf16_within_autodiff_spread(lengths):
+    """bf16 loops, float32 parameters, as both cells train: the rule's
+    parameter gradients lie no further from the float32 rule's than
+    autodiff's bf16 gradients do (the weight gradient is one product
+    accumulated in float32 after the loop, where autodiff adds T bf16
+    products inside it)."""
+    from nerrf_tpu.models import lstm
+
+    args32, w = _recurrence_case(lengths)
+    args16 = tuple(a.astype(jnp.bfloat16) if i < 3 else a
+                   for i, a in enumerate(args32))
+    # the float32 yardstick reads the same (bf16-rounded) inputs
+    args32 = tuple(a.astype(jnp.float32) for a in args16)
+    want = _recurrence_grads(lstm.bilstm_recurrence, args32, w, (3, 4))
+    rule = _recurrence_grads(lstm.bilstm_recurrence, args16, w, (3, 4))
+    auto = _recurrence_grads(lstm._recurrence, args16, w, (3, 4))
+
+    def gap(got, ref):
+        return float(jnp.linalg.norm(got - ref) / jnp.linalg.norm(ref))
+
+    for name, r, a, ref in zip(("wh", "bias"), rule, auto, want):
+        assert r.dtype == ref.dtype == jnp.float32, name
+        assert gap(r, ref) < 0.05, (name, gap(r, ref))      # bf16, not wrong
+        assert gap(r, ref) <= 1.1 * gap(a, ref), (
+            name, gap(r, ref), gap(a, ref))
+
+
+@pytest.mark.parametrize("lengths", [_RAGGED, _FULL], ids=["ragged", "full"])
+def test_lstm_fused_gradients_with_and_without_the_rule(lengths, monkeypatch):
+    """The whole `fused` model, two layers: parameter and input gradients
+    through the rule equal those of autodiff through the un-decorated
+    recurrence to 1e-5."""
+    from nerrf_tpu.models import lstm
+
+    mf, p, feat, mask = _ragged_lstm_case(lengths)
+    grad = jax.grad(_weighted_loss(mf, mask), argnums=(0, 1))
+    rule = grad(p, feat)
+    monkeypatch.setattr(lstm, "bilstm_recurrence", lstm._recurrence)
+    auto = grad(p, feat)
+    errs = jax.tree_util.tree_map(
+        lambda a, b: float(jnp.max(jnp.abs(a - b))), rule, auto)
+    assert max(jax.tree_util.tree_leaves(errs)) < 1e-5, errs
+    for leaf in jax.tree_util.tree_leaves(rule[0]):
+        assert float(jnp.max(jnp.abs(leaf))) > 1e-3
+
+
+def _eqns_of(jaxpr):
+    """Every equation of a jaxpr, those of the sub-jaxprs that equations
+    hold (pjit, custom_vjp_call, a scan's body) included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns_of(sub)
+
+
+def _scans_of(fn, *args):
+    return [e for e in _eqns_of(jax.make_jaxpr(fn)(*args).jaxpr)
+            if e.primitive.name == "scan"]
+
+
+def test_lstm_fused_gradient_program_keeps_h_c_and_the_gates_only():
+    """The reverse rule's witness off the chip.  In the gradient program of
+    the `fused` route a layer's forward loop stacks at most three tensors
+    (`hs`, the cell states, the activated gates as one) where `jax.grad` of
+    `lax.scan` stacked thirteen; its backward loop stacks one (`dgates`,
+    which IS the cotangent of the hoisted projections) and holds no product
+    that yields the weight gradient `[2, H, 4H]`: that is one product after
+    the loop.  The forward-only program (serve, evaluation) keeps one
+    stacked output a layer and no residual."""
+    mf, p, feat, mask = _ragged_lstm_case(_RAGGED)
+    H, layers = mf.cfg.hidden, mf.cfg.num_layers
+    fwd = jax.jit(lambda p, x: mf.apply({"params": p}, x, mask))
+    grad = jax.jit(jax.grad(_weighted_loss(mf, mask), argnums=(0, 1)))
+
+    def stacked(eqn):
+        return len(eqn.outvars) - eqn.params["num_carry"]
+
+    scans = _scans_of(grad, p, feat)
+    forward = [e for e in scans if not e.params["reverse"]]
+    backward = [e for e in scans if e.params["reverse"]]
+    assert len(forward) == len(backward) == layers
+    assert all(stacked(e) <= 3 for e in forward), [stacked(e) for e in forward]
+    assert all(stacked(e) == 1 for e in backward)
+    assert all(e.params["num_carry"] == 3 for e in backward)  # dh, dc, c_t
+    for eqn in scans:
+        products = [e.outvars[0].aval.shape
+                    for e in _eqns_of(eqn.params["jaxpr"].jaxpr)
+                    if e.primitive.name == "dot_general"]
+        assert products, "the recurrent product is in the loop"
+        assert not {(2, H, 4 * H), (2, 4 * H, H)} & set(products), products
+    text = grad.lower(p, feat).as_text()
+    assert text.count("stablehlo.while") == 2 * layers
+    assert text.count("dynamic_update_slice") <= 4 * layers
+    assert f"tensor<2x{H}x{4 * H}xf32>" in text       # ... and after it
+
+    only = _scans_of(fwd, p, feat)
+    assert len(only) == layers and all(stacked(e) == 1 for e in only)
+    assert fwd.lower(p, feat).as_text().count(
+        "dynamic_update_slice") == layers
+
+
 def test_dense_adj_aggregate_is_scoped_like_the_fused_route():
     """On `dense_adj` the aggregate is ``adj @ msg``: that product and its
     transpose carry the `sage_aggregate` scope the fused route's op carries
