@@ -350,8 +350,39 @@ def _experiments() -> Dict[str, Experiment]:
         stream_data=PackConfig(doc_median=16384.0, doc_sigma=0.5,
                                doc_min=2048),
     )
+    stream_mla = Experiment(
+        name="stream-glm-4.7-flash",
+        description=(
+            "Stream-encoder pretraining on whole-document 8k event streams, "
+            "the next two tokens at a time: GLM-4.7-Flash's decoder (latent "
+            "attention, a leading dense layer, then 64 sigmoid-routed "
+            "experts, 4 a token, beside a shared one, and a multi-token-"
+            "prediction module) at its published widths, the dense layer and "
+            "four of 46 expert layers, experts 0-7 of 64 and an eighth of "
+            "both vocabulary matrices: what one chip of an 8-chip slice "
+            "holds (docs/stream-backbone.md; "
+            "chipbench/configs/glm-4.7-flash.json)"
+        ),
+        corpus=CorpusConfig(num_traces=6, attack_fraction=0.5,
+                            duration_sec=180.0, num_target_files=45,
+                            benign_rate_hz=550.0, eval_fraction=0.0),
+        # the long warm-up of the other routed stack, for its reason
+        train=TrainConfig(batch_size=1, num_steps=20000, learning_rate=3e-4,
+                          warmup_steps=2000, weight_decay=0.1, eval_every=20),
+        stream=StreamConfig(
+            dim=2048, num_heads=20, num_layers=5,
+            kinds=("mla_dense",) + ("mla_moe",) * 4, vocab_size=19360,
+            dropout=0.0, mlp_dim=10240, rope_theta=1e6, q_lora_rank=768,
+            kv_lora_rank=512, qk_nope_dim=192, qk_rope_dim=64,
+            v_head_dim=256, num_experts=64, experts_per_token=4,
+            expert_dim=1536, first_expert=0, held_experts=8,
+            router_scale=1.8, shared_dim=1536, rms_eps=1e-5, tie_head=False,
+            mtp_layers=1, mtp_loss_weight=0.3),
+        stream_data=PackConfig(doc_median=16384.0, doc_sigma=0.5,
+                               doc_min=2048),
+    )
     return {e.name: e for e in (toy, lstm, joint, dense, mcts, multihost,
-                                stream_lm, stream_moe)}
+                                stream_lm, stream_moe, stream_mla)}
 
 
 EXPERIMENTS: Dict[str, Experiment] = _experiments()

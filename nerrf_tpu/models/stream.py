@@ -39,12 +39,27 @@ The backbone is a stack built from a list of layer kinds
   RMSNorm and, with ``tie_head`` false, a head of its own; beside the
   residual it returns the indexer's loss and its routing and selection
   counts, which `StreamNet` sums into ``aux``.
+* ``mla_dense``, ``mla_moe`` — one decoder layer (`_LatentLayer`) with
+  latent attention (queries through a ``q_lora_rank`` bottleneck with a norm
+  inside it, keys and values rebuilt from one ``kv_lora_rank`` latent a
+  token, one rotary key shared by all heads: `ops/mla.py`) and, after it, a
+  dense SwiGLU of ``mlp_dim`` (``mla_dense``: the stack's leading layers)
+  or sigmoid-routed experts beside a shared expert every token passes
+  (``mla_moe``: `ops/moe.py::route_sigmoid` in front of the dispatch the
+  ``dsa_moe`` kind uses; the router's correction bias is a parameter no
+  gradient reaches and the optimizer never moves).  A stack of these is not
+  uniform: ``kinds`` lists it.  With ``mtp_layers`` 1 a multi-token-
+  prediction module follows the stack: the stack's output before the final
+  norm and the embedding of the NEXT token, each normalised, joined and
+  projected back to ``dim``, one more ``mla_moe`` layer under the same
+  masks, a norm, and the main model's head (`mtp_loss`: targets two ahead).
+  The embedding and the head are the main model's: two uses, one gradient.
 
 The ``block`` and hybrid kinds carry no positional encoding (event streams
 are irregularly sampled — wall-clock gaps carry signal, so Δt enters as a
-feature or a token, not a position index); ``dsa_moe`` carries its source's
-rotary embedding, counted inside a document.  bfloat16 compute, float32
-parameters.
+feature or a token, not a position index); ``dsa_moe`` and the latent kinds
+carry their source's rotary embedding, counted inside a document.  bfloat16
+compute, float32 parameters.
 """
 
 from __future__ import annotations
@@ -59,12 +74,15 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
-from nerrf_tpu.ops import dsa, moe
+from nerrf_tpu.ops import dsa, mla, moe
 from nerrf_tpu.ops.ssm import causal_conv1d, selective_scan
 from nerrf_tpu.parallel.ring import ring_self_attention
 
 HYBRID_KINDS = ("mamba", "swa", "full", "gmu", "cross")
 SPARSE_KIND = "dsa_moe"
+LATENT_KINDS = ("mla_dense", "mla_moe")
+# the kinds that end in routed experts
+ROUTED_KINDS = (SPARSE_KIND, "mla_moe")
 
 
 def layer_kinds(num_layers: int) -> Tuple[str, ...]:
@@ -135,6 +153,23 @@ class StreamConfig:
     # False: the output head is a matrix of its own (``lm_head``), not the
     # embedding
     tie_head: bool = True
+    # the latent kinds.  Attention: the queries' and the keys-and-values'
+    # latent widths, a head's un-rotated and rotary query/key widths, its
+    # value width.  (``mlp_dim`` is the dense layer's width, ``expert_dim``
+    # an expert's, ``num_experts`` .. ``held_experts`` as above)
+    q_lora_rank: int = 768
+    kv_lora_rank: int = 512
+    qk_nope_dim: int = 192
+    qk_rope_dim: int = 64
+    v_head_dim: int = 256
+    # the sigmoid router's weights are scaled by this; the shared expert's
+    # width
+    router_scale: float = 1.8
+    shared_dim: int = 1536
+    # multi-token-prediction modules behind the stack (0 or 1) and the
+    # weight of their loss term
+    mtp_layers: int = 0
+    mtp_loss_weight: float = 0.3
 
     def __post_init__(self):
         for name in ("kinds", "published_layers"):
@@ -155,6 +190,11 @@ class StreamConfig:
     def published_index(self, i: int) -> int:
         """Layer ``i`` of this stack's index in the published stack."""
         return self.published_layers[i] if self.published_layers else i
+
+    @property
+    def routed_layers(self) -> int:
+        """Layers that route tokens to experts, the MTP module's included."""
+        return sum(k in ROUTED_KINDS for k in self.stack) + self.mtp_layers
 
 
 class _Block(nn.Module):
@@ -399,6 +439,87 @@ class _SparseMoELayer(nn.Module):
             return h + y.reshape(b, t, cfg.dim).astype(cfg.dtype), aux
 
 
+def _swiglu(cfg: StreamConfig, z, width: int, prefix: str = ""):
+    g = nn.silu(_dense(width, cfg, prefix + "gate")(z))
+    return _dense(cfg.dim, cfg, prefix + "down")(
+        g * _dense(width, cfg, prefix + "up")(z))
+
+
+class _LatentLayer(nn.Module):
+    """One latent-attention decoder layer: ``h = x + W_o Attn(RMSNorm(x))``
+    with queries, keys and values rebuilt from their latents, then ``y = h +
+    SwiGLU(RMSNorm(h))`` (``dense``) or ``y = h + Experts(z) + Shared(z)``,
+    ``z = RMSNorm(h)``: the held experts' part under the sigmoid router and
+    a shared expert every token passes.  ``(x, seg) -> (y, aux)``; ``aux``
+    is empty for a dense layer, else the assignments to held experts and
+    their largest count over the mean (float32 scalars).  ``label`` names
+    the layer in a trace (``stream_layer_<i>``, ``mtp_block``)."""
+
+    cfg: StreamConfig
+    dense: bool
+    label: str
+
+    @nn.compact
+    def __call__(self, x, seg):
+        cfg = self.cfg
+        heads, nope, rot = cfg.num_heads, cfg.qk_nope_dim, cfg.qk_rope_dim
+        b, t, _ = x.shape
+        f32 = jnp.float32
+        norm = lambda name: nn.RMSNorm(epsilon=cfg.rms_eps, dtype=cfg.dtype,
+                                       name=name)
+        with jax.named_scope(self.label):
+            u = norm("attn_norm")(x)
+            with jax.named_scope("mla_latent"):
+                q = _dense(heads * (nope + rot), cfg, "wq_b")(
+                    norm("q_a_norm")(_dense(cfg.q_lora_rank, cfg, "wq_a")(u)))
+                latent = _dense(cfg.kv_lora_rank + rot, cfg, "wkv_a")(u)
+                kv = _dense(heads * (nope + cfg.v_head_dim), cfg, "wkv_b")(
+                    norm("kv_a_norm")(latent[..., :cfg.kv_lora_rank]))
+                q, k, v = jax.vmap(lambda q, k_r, kv, seg: mla.assemble(
+                    q, k_r, kv, dsa.doc_positions(seg), nope=nope,
+                    theta=cfg.rope_theta))(
+                        q.reshape(b, t, heads, nope + rot),
+                        latent[..., cfg.kv_lora_rank:],
+                        kv.reshape(b, t, heads, nope + cfg.v_head_dim), seg)
+            o = jax.vmap(mla.attention)(q, k, v, seg)
+            with jax.named_scope("mla_latent"):
+                h = x + _dense(cfg.dim, cfg, "wo")(
+                    o.reshape(b, t, heads * cfg.v_head_dim))
+
+            z = norm("mlp_norm")(h)
+            if self.dense:
+                with jax.named_scope("dense_mlp"):
+                    return h + _swiglu(cfg, z, cfg.mlp_dim), {}
+            z = z.reshape(b * t, cfg.dim)
+            logits = nn.Dense(cfg.num_experts, use_bias=False, dtype=f32,
+                              name="router",
+                              precision=jax.lax.Precision.HIGHEST)(
+                                  z.astype(f32))
+            # a buffer in the parameters' tree: seeded, read under
+            # `stop_gradient`, frozen by the trainer (`make_stream_tx`)
+            bias = self.param("router_bias", nn.initializers.normal(0.1),
+                              (cfg.num_experts,), f32)
+            # read only by `apply(..., mutable=["intermediates"])`
+            self.sow("intermediates", "router_logits", logits)
+            shape = (cfg.held_experts, cfg.dim, cfg.expert_dim)
+            init = lambda fan_in: nn.initializers.normal(fan_in ** -0.5)
+            w = [self.param(name, init(s[1]), s, f32)
+                 for name, s in (("w_gate", shape), ("w_up", shape),
+                                 ("w_down", (shape[0], shape[2], shape[1])))]
+            y, counts = moe.moe_share(
+                z, logits, *w, k=cfg.experts_per_token,
+                first=cfg.first_expert, router=partial(
+                    moe.route_sigmoid, bias=bias, scale=cfg.router_scale))
+            with jax.named_scope("moe_shared"):
+                y = y.astype(cfg.dtype) + _swiglu(cfg, z, cfg.shared_dim,
+                                                  "shared_")
+            counts = counts.astype(f32)
+            aux = {"held_assignments": jnp.sum(counts),
+                   "load_max_over_mean": jnp.max(counts) / jnp.maximum(
+                       jnp.mean(counts), 1.0)}
+            return h + y.reshape(b, t, cfg.dim), aux
+
+
 class StreamNet(nn.Module):
     """The event-stream encoder.
 
@@ -409,8 +530,10 @@ class StreamNet(nn.Module):
     0 = padding)`` -> ``hidden`` [B, T, dim] after the final norm; the
     logits are ``hidden @ embedding.T`` (``hidden @ lm_head.T`` where the
     head is untied) and are never built whole (`next_token_loss`).  A stack
-    with ``dsa_moe`` layers also returns ``aux``: their indexer losses
-    summed, their counts summed, their load imbalance averaged.
+    with routed layers also returns ``aux``: their counts summed, their
+    load imbalance averaged, and for ``dsa_moe`` layers their indexer
+    losses summed; with ``mtp_layers`` also ``mtp_hidden`` [B, T, dim], the
+    multi-token-prediction module's output after its norm (`mtp_loss`).
 
     ``mesh`` is a static module attribute: when it carries an ``sp`` axis of
     size > 1, every ``block`` layer runs as ring attention with T sharded
@@ -432,8 +555,9 @@ class StreamNet(nn.Module):
         dt = cfg.dtype
         if cfg.vocab_size:
             seg = mask
-            x = nn.Embed(cfg.vocab_size, cfg.dim, dtype=dt,
-                         name="tok_embed")(feat)
+            embed = nn.Embed(cfg.vocab_size, cfg.dim, dtype=dt,
+                             name="tok_embed")
+            x = embed(feat)
         else:
             seg = mask.astype(jnp.int32)
             x = nn.Dense(cfg.dim, dtype=dt, name="embed")(feat.astype(dt))
@@ -446,6 +570,10 @@ class StreamNet(nn.Module):
             _SparseMoELayer,
             policy=jax.checkpoint_policies.save_only_these_names(dsa.SAVED))
             if cfg.remat else _SparseMoELayer)
+        latent_cls = (nn.remat(
+            _LatentLayer,
+            policy=jax.checkpoint_policies.save_only_these_names(mla.SAVED))
+            if cfg.remat else _LatentLayer)
         m = kv = None
         sparse_aux = []
         for i, kind in enumerate(cfg.stack):
@@ -456,10 +584,30 @@ class StreamNet(nn.Module):
             elif kind == SPARSE_KIND:
                 x, aux = sparse_cls(cfg, i, name=f"layer_{i}")(x, seg)
                 sparse_aux.append(aux)
+            elif kind in LATENT_KINDS:
+                x, aux = latent_cls(cfg, kind == "mla_dense",
+                                    f"stream_layer_{i}",
+                                    name=f"layer_{i}")(x, seg)
+                if aux:
+                    sparse_aux.append(aux)
             else:
                 x, m, kv = layer_cls(cfg, kind, i, name=f"layer_{i}")(
                     x, seg, m, kv)
-        if sparse_aux:
+        out = {}
+        if cfg.mtp_layers:
+            # h'_i = W_eh [RMSNorm(Emb(t_{i+1})); RMSNorm(H_i)], H the
+            # stack's output before the final norm
+            rms = lambda name: nn.RMSNorm(epsilon=cfg.rms_eps, dtype=dt,
+                                          name=name)
+            with jax.named_scope("mtp_embed_proj"):
+                nxt = jnp.concatenate([feat[:, 1:], feat[:, :1]], axis=1)
+                y = _dense(cfg.dim, cfg, "mtp_eh_proj")(jnp.concatenate(
+                    [rms("mtp_enorm")(embed(nxt)), rms("mtp_hnorm")(x)], -1))
+            y, aux = latent_cls(cfg, False, "mtp_block",
+                                name="mtp_block")(y, seg)
+            sparse_aux.append(aux)
+            out["mtp_hidden"] = rms("mtp_norm")(y)
+        if sparse_aux or cfg.stack[-1] in LATENT_KINDS:
             x = nn.RMSNorm(epsilon=cfg.rms_eps, dtype=dt, name="final_norm")(x)
         else:
             x = nn.LayerNorm(epsilon=1e-5 if cfg.vocab_size else 1e-6,
@@ -472,7 +620,7 @@ class StreamNet(nn.Module):
             aux["load_max_over_mean"] /= len(sparse_aux)
             # every position is routed, padding too
             aux["routed_tokens"] = jnp.float32(x.shape[0] * x.shape[1])
-            return {"hidden": x, "aux": aux}
+            return {"hidden": x, "aux": aux, **out}
         if cfg.vocab_size:
             return {"hidden": x}
         logits = nn.Dense(1, dtype=jnp.float32, name="head")(x)[..., 0]
@@ -495,6 +643,17 @@ def next_token_targets(tokens, seg):
     return nxt, same.astype(jnp.float32)
 
 
+def mtp_targets(tokens, seg):
+    """-> (targets [B, T], weights [B, T] float32): position t predicts
+    token t + 2 where tokens t + 1 and t + 2 are real tokens of t's
+    document (the module read token t + 1's embedding at t)."""
+    two = jnp.concatenate([tokens[:, 2:], tokens[:, :2]], axis=1)
+    same = jnp.concatenate(
+        [(seg[:, 1:-1] == seg[:, :-2]) & (seg[:, 2:] == seg[:, :-2])
+         & (seg[:, :-2] > 0), jnp.zeros_like(seg[:, :2], bool)], axis=1)
+    return two, same.astype(jnp.float32)
+
+
 # positions of the next-token loss computed at a time (the logits of a whole
 # 8192-token sequence are 0.8 GB a copy in float32)
 LOSS_CHUNK = 1024
@@ -506,13 +665,29 @@ def next_token_loss(cfg: StreamConfig, params, hidden, tokens, seg,
     the head (the embedding itself, or ``lm_head`` where ``tie_head`` is
     false), ``chunk`` positions at a time: a chunk's logits live only
     inside its `jax.checkpoint`."""
+    return _vocab_loss(cfg, params, hidden, next_token_targets, tokens, seg,
+                       chunk, "lm_head_loss")[0]
+
+
+def mtp_loss(cfg: StreamConfig, params, mtp_hidden, tokens, seg,
+             chunk: int = LOSS_CHUNK):
+    """The multi-token-prediction term: mean cross-entropy of the token two
+    ahead over the positions that carry such a target, through the main
+    model's head -> (loss, the number of those positions)."""
+    return _vocab_loss(cfg, params, mtp_hidden, mtp_targets, tokens, seg,
+                       chunk, "mtp_head_loss")
+
+
+def _vocab_loss(cfg, params, hidden, targets_of, tokens, seg, chunk, scope):
+    """-> (the mean cross-entropy over ``targets_of(tokens, seg)``'s
+    weighted positions, the summed weights)."""
     emb = (params["tok_embed"]["embedding"] if cfg.tie_head
            else params["lm_head"]).astype(cfg.dtype)
     b, t, dim = hidden.shape
     chunk = min(chunk, b * t)
     if (b * t) % chunk:
         raise ValueError(f"{b * t} positions are not whole chunks of {chunk}")
-    targets, weights = next_token_targets(tokens, seg)
+    targets, weights = targets_of(tokens, seg)
 
     @partial(jax.checkpoint, prevent_cse=False)
     def chunk_nll(x, y, w):
@@ -522,11 +697,12 @@ def next_token_loss(cfg: StreamConfig, params, hidden, tokens, seg,
             logits, y[:, None], axis=-1)[:, 0]
         return jnp.sum(nll * w)
 
-    with jax.named_scope("lm_head_loss"):
+    with jax.named_scope(scope):
         cut = lambda v: v.reshape((b * t // chunk, chunk) + v.shape[2:])
         total = jax.lax.map(lambda a: chunk_nll(*a),
                             (cut(hidden), cut(targets), cut(weights)))
-        return jnp.sum(total) / jnp.maximum(jnp.sum(weights), 1.0)
+        total, count = jnp.sum(total), jnp.sum(weights)
+        return total / jnp.maximum(count, 1.0), count
 
 
 def stream_loss(outputs, labels, mask):

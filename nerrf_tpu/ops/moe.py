@@ -1,7 +1,14 @@
 """One chip's share of a routed mixture of experts.
 
-The router scores every token against ALL ``num_experts`` experts and keeps
-the ``k`` best, their weights renormalised over those ``k`` (`route`).  This
+A router scores every token against ALL ``num_experts`` experts and keeps
+``k`` of them.  There are two, and one dispatch behind both (`moe_share`
+takes either): `route` is the softmax router (probabilities over all
+experts, the ``k`` largest, renormalised over those ``k``: the ``dsa_moe``
+kind's), `route_sigmoid` the sigmoid router of the latent-attention kinds
+(each expert's score is a sigmoid of its own logit; the ``k`` are chosen by
+score PLUS a correction bias that no gradient reaches and that never enters
+a weight; the weights are the chosen scores over their sum, times a
+constant).  This
 chip holds the experts ``[first, first + held)`` and computes their part of
 the result for the tokens routed to them; what the absent experts would add
 is left out (under expert parallelism it arrives from the chips that hold
@@ -31,19 +38,39 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-# rows a tile.  256 rows against one expert's 2048 x 768 matrices is about
-# as many FLOPs as bytes of weights on a v5e; with 512 tokens an expert on
-# average, the padding to whole tiles is a fifth of the rows
+# rows a tile.  A tile's products are 2 x 256 FLOPs for every 2 bytes of
+# its expert's matrices, whatever their width: about the v5e's FLOPs a byte
+# (197 T / 819 G = 240), at 2048 x 768 (three matrices of 3.1 MB in bf16) as
+# at 2048 x 1536 (6.3 MB each).  With 512 tokens an expert on average, the
+# padding to whole tiles is a fifth of the rows at either width; what the
+# width changes is a tile's products (12 us against 25 at the peak) beside
+# the gathers and scatter-adds around them, which do not grow with it
 TILE = 256
 
 
 def route(logits, k: int):
-    """Router logits [N, E] float32 -> (weights [N, k] float32: the softmax
-    over all E, renormalised over the k kept, experts [N, k] int32).  Of two
-    equal probabilities the lower expert index is kept first."""
+    """The softmax router.  Logits [N, E] float32 -> (weights [N, k]
+    float32: the softmax over all E, renormalised over the k kept, experts
+    [N, k] int32).  Of two equal probabilities the lower expert index is
+    kept first."""
     gates = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     top, experts = jax.lax.top_k(gates, k)
     return top / jnp.sum(top, axis=-1, keepdims=True), experts
+
+
+def route_sigmoid(logits, k: int, *, bias, scale: float):
+    """The sigmoid router (DeepSeek-V3's ``noaux_tc`` with one group).
+    Logits [N, E] float32, the correction ``bias`` [E] -> (weights [N, k]
+    float32, experts [N, k] int32): scores ``s = sigmoid(logits)``; the k
+    experts of largest ``s + bias`` (of equal ones the lower index first);
+    weights ``scale * s_i / (sum of the chosen s + 1e-20)``.  The bias moves
+    choices and never weights, and no gradient reaches it."""
+    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+    _, experts = jax.lax.top_k(
+        scores + jax.lax.stop_gradient(bias.astype(jnp.float32)), k)
+    top = jnp.take_along_axis(scores, experts, axis=-1)
+    return (scale * top / (jnp.sum(top, axis=-1, keepdims=True) + 1e-20),
+            experts)
 
 
 class Plan(NamedTuple):
@@ -195,13 +222,15 @@ expert_ffn.defvjp(_ffn_fwd, _ffn_bwd)
 
 
 def moe_share(x, logits, w_gate, w_up, w_down, *, k: int, first: int,
-              tile: int = None):
+              tile: int = None, router=route):
     """The held experts' part of a routed expert layer.  ``x`` [N, H] in
     the compute type, router ``logits`` [N, E] float32 -> (y [N, H]
-    float32, counts [held] int32)."""
+    float32, counts [held] int32).  ``router(logits, k) -> (weights,
+    experts)`` is `route` or `route_sigmoid` with its bias and scale bound;
+    everything after it is shared."""
     held = w_gate.shape[0]
     with jax.named_scope("moe_router"):
-        weights, experts = route(logits, k)
+        weights, experts = router(logits, k)
     with jax.named_scope("moe_dispatch"):
         plan = dispatch_plan(experts, first, held, tile or TILE)
         # each buffer row's routing weight: the scatter's transpose gathers

@@ -145,7 +145,8 @@ def make_train_step(model: NerrfNet, cfg: TrainConfig):
     return train_step
 
 
-def make_flat_step(model: NerrfNet, cfg: TrainConfig, body, **jit_kwargs):
+def make_flat_step(model: NerrfNet, cfg: TrainConfig, body, tx=None,
+                   **jit_kwargs):
     """Jit ``body(state, *rest) -> (state, loss, aux, rng)`` behind a
     SERIALIZABLE pytree boundary: (params, opt_state, step, *rest) in,
     ((params, opt_state, step), loss, aux, rng) out.
@@ -163,8 +164,10 @@ def make_flat_step(model: NerrfNet, cfg: TrainConfig, body, **jit_kwargs):
 
     ``jit_kwargs`` extend the jit decoration (the sharded twin in
     parallel/train.py passes in/out_shardings over the FLAT slots) so the
-    boundary contract lives in exactly one body."""
-    tx = make_tx(cfg)
+    boundary contract lives in exactly one body.  ``tx`` is the optimizer of
+    the state the step will be called with, where that is not `make_tx`'s
+    (`train/stream.py::make_stream_tx`)."""
+    tx = tx or make_tx(cfg)
 
     @partial(jax.jit, donate_argnums=(0, 1), **jit_kwargs)
     def flat_step(params, opt_state, step_no, *rest):
@@ -327,15 +330,17 @@ def device_put_chunked(arrays, max_bytes: int = 64 << 20, block: bool = False,
 
 
 def make_train_step_scheduled(model: NerrfNet, cfg: TrainConfig, arrays,
-                              idx_table: np.ndarray, loss_fn=None):
+                              idx_table: np.ndarray, loss_fn=None, tx=None):
     """Fully device-driven training: the HBM-resident dataset *and* the whole
     batch-index schedule live on device, and each step picks its row with
     ``state.step`` — so a step issues zero host→device transfers and back-to-
     back steps pipeline instead of syncing on per-step input uploads.
     ``idx_table`` is [num_steps, batch] int32.  ``loss_fn(params, batch,
     dropout_rng) -> (loss, aux)`` replaces NerrfNet's joint loss (the stream
-    encoder trains through this same step: `train/stream.py`)."""
-    _, make_scheduled, _ = _make_resident_steps(model, cfg, arrays, loss_fn)
+    encoder trains through this same step: `train/stream.py`, with ``tx``
+    where its optimizer is not `make_tx`'s)."""
+    _, make_scheduled, _ = _make_resident_steps(model, cfg, arrays, loss_fn,
+                                                tx)
     return make_scheduled(idx_table)
 
 
@@ -350,7 +355,7 @@ def make_train_superstep(model: NerrfNet, cfg: TrainConfig, arrays,
 
 
 def _make_resident_steps(model: NerrfNet, cfg: TrainConfig, arrays,
-                         loss_fn=None):
+                         loss_fn=None, tx=None):
     """One factory for both resident flavors, sharing placement, the gather,
     and the step body (so fixes to any of them apply to both)."""
     loss_fn = loss_fn or make_loss_fn(model, cfg)
@@ -378,9 +383,9 @@ def _make_resident_steps(model: NerrfNet, cfg: TrainConfig, arrays,
 
     # the cacheable twin (see make_flat_step): dev stays a jit *parameter*
     # there too, bound as the StepCache tail
-    resident.flat_jit_fn = make_flat_step(model, cfg, gathered_step)
+    resident.flat_jit_fn = make_flat_step(model, cfg, gathered_step, tx)
     resident.tail = (dev,)
-    flat_by_schedule = make_flat_step(model, cfg, scheduled_body)
+    flat_by_schedule = make_flat_step(model, cfg, scheduled_body, tx)
 
     def make_scheduled(idx_table):
         table = jax.device_put(np.asarray(idx_table, np.int32))

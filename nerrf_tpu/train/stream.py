@@ -20,7 +20,9 @@ import jax
 import numpy as np
 from flax.training import train_state
 
-from nerrf_tpu.models.stream import (StreamConfig, StreamNet,
+import optax
+
+from nerrf_tpu.models.stream import (StreamConfig, StreamNet, mtp_loss,
                                      next_token_loss, stream_loss)
 from nerrf_tpu.train import loop
 
@@ -47,44 +49,79 @@ def make_stream_loss_fn(model: StreamNet):
                                batch["tokens"], batch["segments"])
         aux = out.get("aux", {})
         if aux:
+            aux = dict(aux, token_loss=loss)
+        if "index_loss" in aux:
             # the indexer's own term (`ops/dsa.py`): without it the
             # indexer's parameters would never receive a gradient
-            aux = dict(aux, token_loss=loss)
             loss = loss + scfg.index_loss_weight * aux["index_loss"]
+        if "mtp_hidden" in out:
+            # the token two ahead, through the multi-token-prediction
+            # module and the main model's head
+            extra, count = mtp_loss(scfg, params, out["mtp_hidden"],
+                                    batch["tokens"], batch["segments"])
+            aux.update(mtp_loss=extra, mtp_targets=count)
+            loss = loss + scfg.mtp_loss_weight * extra
         return loss, aux
 
     return loss_fn
 
 
 def count_sparse(aux: dict, scfg: StreamConfig, steps: int = 1) -> None:
-    """A ``dsa_moe`` step's ``aux`` -> the program's registry.  It floats
-    device scalars, so the loop calls it only where it already syncs;
-    ``steps`` is how many steps that sync stands for (the counters then
-    assume they routed alike)."""
+    """The ``aux`` of a step with routed layers -> the program's registry:
+    the routing of every such stack, the selection where the stack has an
+    indexer, the multi-token-prediction term where it has that module.  It
+    floats device scalars, so the loop calls it only where it already
+    syncs; ``steps`` is how many steps that sync stands for (the counters
+    then assume they routed alike)."""
     from nerrf_tpu.observability import DEFAULT_REGISTRY as reg
 
     if "held_assignments" not in aux:
         return
     held = float(aux["held_assignments"])
-    chosen, allowed = (float(aux[k]) for k in ("selected_pairs",
-                                               "allowed_pairs"))
-    layers = scfg.stack.count("dsa_moe")
-    total = float(aux["routed_tokens"]) * scfg.experts_per_token * layers
+    total = (float(aux["routed_tokens"]) * scfg.experts_per_token
+             * scfg.routed_layers)
     help_ = "token-to-expert assignments of the routed layers, by whether " \
             "this chip holds the expert"
     reg.counter_inc("moe_assignments_total", held * steps,
                     labels={"held": "true"}, help=help_)
     reg.counter_inc("moe_assignments_total", (total - held) * steps,
                     labels={"held": "false"}, help=help_)
-    reg.counter_inc("dsa_selected_pairs_total", chosen * steps,
-                    help="query-key pairs the indexer's selection kept")
     reg.gauge_set("moe_expert_load_max_over_mean",
                   float(aux["load_max_over_mean"]),
                   help="largest held expert's assignments over the mean, "
                        "averaged over layers, last synced step")
-    reg.gauge_set("dsa_selected_share", chosen / max(allowed, 1.0),
-                  help="selected over causal same-document pairs, last "
-                       "synced step")
+    if "selected_pairs" in aux:
+        chosen, allowed = (float(aux[k]) for k in ("selected_pairs",
+                                                   "allowed_pairs"))
+        reg.counter_inc("dsa_selected_pairs_total", chosen * steps,
+                        help="query-key pairs the indexer's selection kept")
+        reg.gauge_set("dsa_selected_share", chosen / max(allowed, 1.0),
+                      help="selected over causal same-document pairs, last "
+                           "synced step")
+    if "mtp_targets" in aux:
+        term = scfg.mtp_loss_weight * float(aux["mtp_loss"])
+        reg.counter_inc("mtp_targets_total",
+                        float(aux["mtp_targets"]) * steps,
+                        help="positions that carried a target two tokens "
+                             "ahead")
+        reg.gauge_set("stream_mtp_loss_share",
+                      term / max(float(aux["token_loss"]) + term, 1e-30),
+                      help="the weighted multi-token-prediction term over "
+                           "the whole loss, last synced step")
+
+
+def make_stream_tx(cfg: loop.TrainConfig, scfg: StreamConfig):
+    """The trainer's optimizer (`loop.make_tx`); for a stack with sigmoid
+    routers the update of every correction bias (``router_bias``) is zeroed
+    behind it, weight decay included: the bias is a buffer, held fixed (the
+    rule that would move it between steps is not here)."""
+    # nerrflint: ok[recompile-hazard] scfg is the model's STATIC configuration (a frozen dataclass that rides the AOT key, `stream_key_extra`), never a traced value
+    if "mla_moe" not in scfg.stack and not scfg.mtp_layers:
+        return loop.make_tx(cfg)
+    frozen = lambda tree: jax.tree_util.tree_map_with_path(
+        lambda path, _: getattr(path[-1], "key", None) == "router_bias", tree)
+    return optax.chain(loop.make_tx(cfg),
+                       optax.masked(optax.set_to_zero(), frozen))
 
 
 def stream_key_extra(scfg: StreamConfig) -> dict:
@@ -101,7 +138,8 @@ def init_stream_state(model: StreamNet, cfg: loop.TrainConfig, sample: dict,
     params = jax.jit(lambda r: model.init(
         r, sample[first], sample[second], deterministic=True)["params"])(rng)
     return train_state.TrainState.create(
-        apply_fn=model.apply, params=params, tx=loop.make_tx(cfg))
+        apply_fn=model.apply, params=params,
+        tx=make_stream_tx(cfg, model.cfg))
 
 
 def make_stream_step(model: StreamNet, cfg: loop.TrainConfig, arrays: dict,
@@ -110,7 +148,8 @@ def make_stream_step(model: StreamNet, cfg: loop.TrainConfig, arrays: dict,
     ``step(state, rng) -> (state, loss, aux, rng)``, behind the AOT cache
     where one is given."""
     step = loop.make_train_step_scheduled(
-        model, cfg, arrays, idx_table, loss_fn=make_stream_loss_fn(model))
+        model, cfg, arrays, idx_table, loss_fn=make_stream_loss_fn(model),
+        tx=make_stream_tx(cfg, model.cfg))
     if compile_cache is None:
         return loop.traced_step(step)
     return loop.cache_train_step(

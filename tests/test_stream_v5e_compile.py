@@ -88,6 +88,26 @@ def test_sparse_attention_runs_its_blocks_one_after_another(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 5 << 30
 
 
+def test_latent_attention_runs_its_blocks_one_after_another(one_chip):
+    """`ops/mla.py::attention` at 20 heads with 256-wide assembled keys and
+    256-wide values, the one rotary key broadcast by `assemble`, forward +
+    backward: a block of 256 queries holds a few [20, 256, 8192] float32
+    arrays (168 MB each), never the [20, T, T] scores (5.4 GB)."""
+    from nerrf_tpu.ops import dsa, mla
+
+    def loss(q, k_r, kv, seg):
+        o = mla.attention(*mla.assemble(q, k_r, kv, dsa.doc_positions(seg),
+                                        nope=192, theta=1e6), seg)
+        return jnp.sum(o.astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        shape(one_chip, (T, 20, 256), jnp.bfloat16),
+        shape(one_chip, (T, 64), jnp.bfloat16),
+        shape(one_chip, (T, 20, 448), jnp.bfloat16),
+        shape(one_chip, (T,), jnp.int32)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 << 30
+
+
 def test_expert_walk_never_copies_the_weights_per_tile(one_chip):
     """`ops/moe.py::moe_share` at 8192 tokens, 16 held experts of 2048 x
     768, 8 of 128 a token, forward + backward: the worst routing has 272
