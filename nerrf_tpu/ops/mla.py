@@ -15,15 +15,29 @@ query ``t`` and a key ``s <= t`` of the same document:
 The absorbed form (scores against the latent itself, what a serving cache
 wants) is not here: in training the latent is expanded.
 
-`attention` is `ops/dsa.py::sparse_attention`'s dense twin, plain XLA: a
-block of queries at a time against the keys before the block's end, rounded
-up to a span (one `lax.switch` branch per key length), a block's float32
-scores alive only while it is computed.  Its derivative is written by hand
-(`jax.custom_vjp`): the forward pass keeps ``o`` and each head's
-log-sum-exp under the name `SAVED`, the flash-style backward pass computes a
-block's scores once more from them.  Reverse mode through the scan would
-keep or recompute every block's scores, and the operations of a transposed
-`lax.switch` carry no scope of their own.
+`attention` has two routes, chosen by `attention_route` from the backend and
+the static shapes and by nothing else (docs/kernel-paths.md):
+
+* ``pallas_flash`` (a TPU, one head width for ``q``, ``k`` and ``v``): two
+  fused kernels in which a tile's scores, probabilities and their derivatives
+  live in the chip's vector memory only.  Forward: online softmax over the
+  key tiles at or below the diagonal.  Backward: ONE kernel that computes a
+  tile's scores once more from the saved log-sum-exp and takes all three
+  gradients from them.
+* ``xla_blocked`` (everywhere else; the fused route's oracle):
+  `ops/dsa.py::sparse_attention`'s dense twin, plain XLA: a block of queries
+  at a time against the keys before the block's end, rounded up to a span
+  (one `lax.switch` branch per key length), a block's float32 scores alive
+  only while it is computed, through HBM.
+
+Both round where the other rounds (bf16 products accumulated in float32,
+softmax in float32, probabilities and the scores' gradient cast to the
+compute type before their products).  Either way the derivative is written
+by hand (`jax.custom_vjp`): the forward pass keeps ``o`` and each head's
+log-sum-exp ``[H, T]`` under the name `SAVED`, the flash-style backward pass
+computes the scores once more from them.  Reverse mode through the XLA
+route's scan would keep or recompute every block's scores, and the
+operations of a transposed `lax.switch` carry no scope of their own.
 """
 
 from __future__ import annotations
@@ -180,6 +194,243 @@ def _attention_bwd(block, span, res, d_o):
 _attention.defvjp(_attention_fwd, _attention_bwd)
 
 
+# --------------------------------------------------------------------------
+# the fused route: a tile's scores never leave the chip's vector memory
+# --------------------------------------------------------------------------
+
+# queries and keys a tile of the fused kernels (chosen on a v5e in the whole
+# step: `docs/kernel-paths.md`), and the fast memory a kernel may plan
+# (a v5e core has 128 MiB; the compiler's own scoped limit is 16 MiB)
+FLASH_BLOCK_Q = 512
+FLASH_BLOCK_K = 512
+FLASH_VMEM_BYTES = 96 << 20
+# the backward kernel keeps one head's whole ``dq`` [T, d] in fast memory,
+# in float32 and, twice (the pipeline's two buffers), in the compute type:
+# 16 MiB at 8192 x 256
+FLASH_MAX_ROW_ELEMENTS = 4 << 20
+_LANES, _SUBLANES = 128, 8
+_NT = (((1,), (1,)), ((), ()))  # a @ b.T
+_TN = (((0,), (0,)), ((), ()))  # a.T @ b
+
+
+def attention_route(t: int, d: int, dv: int) -> str:
+    """Which form `attention` traces for a sequence of ``t`` tokens with
+    ``d``-wide keys and ``dv``-wide values: a function of the backend and
+    these static shapes, nothing else.  ``"pallas_flash"`` (the fused
+    kernels) on a TPU when one head width serves ``q``, ``k`` and ``v``, it
+    is whole lanes, and ``t`` is whole tiles whose ``dq`` rows fit;
+    ``"xla_blocked"`` otherwise."""
+    if (jax.default_backend() == "tpu" and d == dv and d % _LANES == 0
+            and t % FLASH_BLOCK_Q == 0 and t % FLASH_BLOCK_K == 0
+            and t * d <= FLASH_MAX_ROW_ELEMENTS):
+        return "pallas_flash"
+    return "xla_blocked"
+
+
+def _tile_mask(q_seg, k_seg, q0, k0, shape, q_axis: int):
+    """Which pairs of a tile attend: the same document (``q_seg``, ``k_seg``
+    broadcast against each other) and key position <= query position;
+    queries run along ``q_axis`` of ``shape`` from position ``q0``, keys
+    along the other axis from ``k0``."""
+    q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+    k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
+    return (q_seg == k_seg) & (k_pos <= q_pos)
+
+
+def _flash_fwd_kernel(q_ref, k_ref, v_ref, q_seg_ref, k_seg_ref, o_ref,
+                      lse_ref, m_ref, l_ref, acc_ref, *, scale, bq, bk):
+    """One (head, query tile, key tile) step of the online softmax: ``m``
+    the running maximum, ``l`` the running sum (both lane-replicated
+    [bq, 128]), ``acc`` the un-normalised output [bq, d]."""
+    from jax.experimental import pallas as pl
+
+    i, j = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full(m_ref.shape, -1e9, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    # a key tile wholly above the diagonal is skipped
+    @pl.when(j * bk < (i + 1) * bq)
+    def _():
+        s = jax.lax.dot_general(q_ref[...], k_ref[...], _NT,
+                                preferred_element_type=jnp.float32) * scale
+        s = jnp.where(_tile_mask(
+            jnp.tile(q_seg_ref[...], (1, bk // _LANES)), k_seg_ref[:1, :],
+            i * bq, j * bk, (bq, bk), 0), s, -1e9)
+        m_prev = m_ref[...]
+        m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - jnp.tile(m_next, (1, bk // _LANES)))
+        alpha = jnp.exp(m_prev - m_next)
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+        m_ref[...] = m_next
+        acc_ref[...] = (
+            acc_ref[...] * jnp.tile(alpha, (1, acc_ref.shape[1] // _LANES))
+            + jnp.dot(p.astype(v_ref.dtype), v_ref[...],
+                      preferred_element_type=jnp.float32))
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _():
+        l = l_ref[...]
+        o_ref[...] = (acc_ref[...] / jnp.tile(
+            l, (1, acc_ref.shape[1] // _LANES))).astype(o_ref.dtype)
+        # the log-sum-exp leaves as one row of the compact [H, 1, T]
+        lse_ref[...] = (m_ref[...] + jnp.log(l)).T[:1]
+
+
+def _flash_bwd_kernel(q_ref, k_ref, v_ref, d_o_ref, lse_ref, rows_ref,
+                      q_seg_ref, k_seg_ref, dq_ref, dk_ref, dv_ref, dq_acc,
+                      dk_acc, dv_acc, *, scale, bq, bk):
+    """One (head, key tile, query tile) step of the backward pass, scores
+    transposed ([bk, bq]: the queries' log-sum-exp and ``rows`` = sum(d_o *
+    o) are then plain rows).  The scores are computed once for all three
+    gradients: ``dk``, ``dv`` gather over the query tiles in ``dk_acc``,
+    ``dv_acc``; ``dq`` over the key tiles in ``dq_acc``, the head's whole
+    [T, d] float32, which like ``dq_ref``, the head's block of the output,
+    stays in fast memory while the head's tiles run."""
+    from jax.experimental import pallas as pl
+
+    j, i = pl.program_id(1), pl.program_id(2)
+    last_i = pl.num_programs(2) - 1
+
+    @pl.when((j == 0) & (i == 0))
+    def _():
+        dq_acc[...] = jnp.zeros(dq_acc.shape, jnp.float32)
+
+    @pl.when(i == 0)
+    def _():
+        dk_acc[...] = jnp.zeros(dk_acc.shape, jnp.float32)
+        dv_acc[...] = jnp.zeros(dv_acc.shape, jnp.float32)
+
+    @pl.when(j * bk < (i + 1) * bq)
+    def _():
+        q, k, d_o = q_ref[...], k_ref[...], d_o_ref[...]
+        f32 = dict(preferred_element_type=jnp.float32)
+        s = jax.lax.dot_general(k, q, _NT, **f32) * scale
+        prob = jnp.where(_tile_mask(
+            q_seg_ref[:1, :], jnp.tile(k_seg_ref[...], (1, bq // _LANES)),
+            i * bq, j * bk, (bk, bq), 1), jnp.exp(s - lse_ref[...]), 0.0)
+        dv_acc[...] += jnp.dot(prob.astype(d_o.dtype), d_o, **f32)
+        d_prob = jax.lax.dot_general(v_ref[...], d_o, _NT, **f32)
+        d_s = (prob * (d_prob - rows_ref[...]) * scale).astype(q.dtype)
+        dk_acc[...] += jnp.dot(d_s, q, **f32)
+        dq_acc[pl.ds(pl.multiple_of(i * bq, bq), bq), :] += (
+            jax.lax.dot_general(d_s, k, _TN, **f32))
+
+    @pl.when(i == last_i)
+    def _():
+        dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+
+    @pl.when((j == pl.num_programs(1) - 1) & (i == last_i))
+    def _():
+        dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
+
+
+def _seg_operands(seg):
+    """``seg`` [T] as the two small operands a tile reads it from: along the
+    sublanes [T, 128] and along the lanes [8, T]."""
+    t = seg.shape[0]
+    return (jnp.broadcast_to(seg[:, None], (t, _LANES)),
+            jnp.broadcast_to(seg[None, :], (_SUBLANES, t)))
+
+
+def _flash_params(semantics):
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.CompilerParams(dimension_semantics=semantics,
+                                vmem_limit_bytes=FLASH_VMEM_BYTES)
+
+
+def _flash_forward(q, k, v, seg):
+    """``q``, ``k``, ``v`` [H, T, d] -> (o [H, T, d], lse [H, T] float32)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    bq, bk = FLASH_BLOCK_Q, FLASH_BLOCK_K
+    h, t, d = q.shape
+    # a skipped step asks for the key tile it already holds: nothing is
+    # copied for it
+    held = lambda i, j: jnp.minimum(j, ((i + 1) * bq - 1) // bk)
+    q_spec = pl.BlockSpec((None, bq, d), lambda h, i, j: (h, i, 0))
+    k_spec = pl.BlockSpec((None, bk, d), lambda h, i, j: (h, held(i, j), 0))
+    o, lse = pl.pallas_call(
+        partial(_flash_fwd_kernel, scale=d ** -0.5, bq=bq, bk=bk),
+        grid=(h, t // bq, t // bk),
+        in_specs=[q_spec, k_spec, k_spec,
+                  pl.BlockSpec((bq, _LANES), lambda h, i, j: (i, 0)),
+                  pl.BlockSpec((_SUBLANES, bk),
+                               lambda h, i, j: (0, held(i, j)))],
+        out_specs=[q_spec,
+                   pl.BlockSpec((None, 1, bq), lambda h, i, j: (h, 0, i))],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct((h, 1, t), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bq, _LANES), jnp.float32),
+                        pltpu.VMEM((bq, _LANES), jnp.float32),
+                        pltpu.VMEM((bq, d), jnp.float32)],
+        compiler_params=_flash_params(("parallel", "parallel", "arbitrary")),
+        name="mla_flash_fwd",
+    )(q, k, v, *_seg_operands(seg))
+    return o, lse[:, 0]
+
+
+def _flash_backward(q, k, v, seg, o, lse, d_o):
+    """-> (dq, dk, dv) in the operands' type."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    bq, bk = FLASH_BLOCK_Q, FLASH_BLOCK_K
+    h, t, d = q.shape
+    rows = jnp.sum(d_o.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    seg_sublanes, seg_lanes = _seg_operands(seg)
+    # a skipped step asks for the query tile it will hold next: the first
+    # that meets the key tile
+    held = lambda j, i: jnp.maximum(i, (j * bk) // bq)
+    q_spec = pl.BlockSpec((None, bq, d), lambda h, j, i: (h, held(j, i), 0))
+    k_spec = pl.BlockSpec((None, bk, d), lambda h, j, i: (h, j, 0))
+    row_spec = pl.BlockSpec((None, 1, bq), lambda h, j, i: (h, 0, held(j, i)))
+    return tuple(pl.pallas_call(
+        partial(_flash_bwd_kernel, scale=d ** -0.5, bq=bq, bk=bk),
+        grid=(h, t // bk, t // bq),
+        in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec,
+                  pl.BlockSpec((_SUBLANES, bq),
+                               lambda h, j, i: (0, held(j, i))),
+                  pl.BlockSpec((bk, _LANES), lambda h, j, i: (j, 0))],
+        out_specs=[pl.BlockSpec((None, t, d), lambda h, j, i: (h, 0, 0)),
+                   k_spec, k_spec],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        scratch_shapes=[pltpu.VMEM((t, d), jnp.float32),
+                        pltpu.VMEM((bk, d), jnp.float32),
+                        pltpu.VMEM((bk, d), jnp.float32)],
+        compiler_params=_flash_params(("parallel", "arbitrary", "arbitrary")),
+        name="mla_flash_bwd",
+    )(q, k, v, d_o, lse[:, None], rows[:, None], seg_lanes, seg_sublanes))
+
+
+@jax.custom_vjp
+def _flash(q, k, v, seg):
+    return _flash_forward(q, k, v, seg)[0]
+
+
+def _flash_fwd(q, k, v, seg):
+    o, lse = _flash_forward(q, k, v, seg)
+    # the same residuals under the same name as the other route's
+    o, lse = checkpoint_name(o, SAVED), checkpoint_name(lse, SAVED)
+    return o, (q, k, v, seg, o, lse)
+
+
+def _flash_bwd(res, d_o):
+    with jax.named_scope("mla_attention"):
+        return _flash_backward(*res, d_o) + (None,)
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd)
+
+
 def attention(q, k, v, seg, *, block: int = None, span: int = None):
     """One packed sequence.  ``q``, ``k`` [T, H, d], ``v`` [T, H, dv] in
     the compute type, ``seg`` [T] -> o [T, H, dv]: causal softmax attention
@@ -192,8 +443,12 @@ def attention(q, k, v, seg, *, block: int = None, span: int = None):
         # matmuls (with the heads in the middle the TPU compiler writes
         # them as dilated convolutions)
         heads_first = lambda x: jnp.swapaxes(x, 0, 1)
-        o = _attention(heads_first(q), heads_first(k), heads_first(v), seg,
-                       block, span)
+        route = attention_route(q.shape[0], q.shape[-1], v.shape[-1])
+        q, k, v = heads_first(q), heads_first(k), heads_first(v)
+        if route == "pallas_flash":
+            o = _flash(q, k, v, seg)
+        else:
+            o = _attention(q, k, v, seg, block, span)
         # what reads ``o`` (the output projection's gradient) would
         # otherwise make a remat that keeps `SAVED` run the forward pass
         # again for it

@@ -22,8 +22,8 @@ from flax.training import train_state
 
 import optax
 
-from nerrf_tpu.models.stream import (StreamConfig, StreamNet, mtp_loss,
-                                     next_token_loss, stream_loss)
+from nerrf_tpu.models.stream import (LATENT_KINDS, StreamConfig, StreamNet,
+                                     mtp_loss, next_token_loss, stream_loss)
 from nerrf_tpu.train import loop
 
 
@@ -31,6 +31,10 @@ def _input_keys(scfg: StreamConfig):
     """The batch's two model inputs: tokens and segment ids (next token) or
     features and mask (per-event BCE)."""
     return ("tokens", "segments") if scfg.vocab_size else ("feat", "mask")
+
+
+def _seq_len(scfg: StreamConfig, arrays: dict) -> int:
+    return np.shape(arrays[_input_keys(scfg)[0]])[1]
 
 
 def make_stream_loss_fn(model: StreamNet):
@@ -124,9 +128,29 @@ def make_stream_tx(cfg: loop.TrainConfig, scfg: StreamConfig):
                        optax.masked(optax.set_to_zero(), frozen))
 
 
-def stream_key_extra(scfg: StreamConfig) -> dict:
-    """AOT key material of a stream step beside the training config's."""
-    return {"stream_cfg": repr(scfg)}
+def stream_kernel_path(scfg: StreamConfig,
+                       seq_len: Optional[int] = None) -> dict:
+    """Which of an op's routes a step over ``seq_len``-token sequences
+    traces on this backend, for the ops of ``scfg``'s stack that have two:
+    the latent attention core (`ops/mla.py::attention_route`) where the
+    stack or its multi-token-prediction module has a latent layer."""
+    if not (set(scfg.stack) & set(LATENT_KINDS) or scfg.mtp_layers):
+        return {}
+    if seq_len is None:
+        raise ValueError("a latent layer's route depends on the sequence "
+                         "length: pass seq_len")
+    from nerrf_tpu.ops import mla
+
+    return {"mla_attention": mla.attention_route(
+        seq_len, scfg.qk_nope_dim + scfg.qk_rope_dim, scfg.v_head_dim)}
+
+
+def stream_key_extra(scfg: StreamConfig,
+                     seq_len: Optional[int] = None) -> dict:
+    """AOT key material of a stream step beside the training config's: the
+    encoder's configuration and the routes its ops take (an executable
+    traced with one route is never served where the other would be)."""
+    return {"stream_cfg": repr(scfg), **stream_kernel_path(scfg, seq_len)}
 
 
 def init_stream_state(model: StreamNet, cfg: loop.TrainConfig, sample: dict,
@@ -154,7 +178,7 @@ def make_stream_step(model: StreamNet, cfg: loop.TrainConfig, arrays: dict,
         return loop.traced_step(step)
     return loop.cache_train_step(
         compile_cache, step, model, cfg, "stream_step_scheduled",
-        extra=stream_key_extra(model.cfg))
+        extra=stream_key_extra(model.cfg, _seq_len(model.cfg, arrays)))
 
 
 def train_stream(arrays: dict, scfg: StreamConfig, cfg: loop.TrainConfig,
@@ -170,6 +194,8 @@ def train_stream(arrays: dict, scfg: StreamConfig, cfg: loop.TrainConfig,
     rng, init_rng = jax.random.split(rng)
     sample = {k: v[:min(cfg.batch_size, n)] for k, v in arrays.items()}
     state = init_stream_state(model, cfg, sample, init_rng)
+    for op, route in stream_kernel_path(scfg, _seq_len(scfg, arrays)).items():
+        log(f"kernel_path: {op}: {route}")
     step = make_stream_step(model, cfg, arrays,
                             loop.make_idx_schedule(n, cfg), compile_cache)
     history = []

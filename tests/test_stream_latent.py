@@ -65,7 +65,7 @@ def test_an_edit_to_a_latent_field_changes_the_aot_key(field, value):
     from nerrf_tpu.train.stream import stream_key_extra
 
     other = dataclasses.replace(TOY, **{field: value})
-    assert stream_key_extra(other) != stream_key_extra(TOY)
+    assert stream_key_extra(other, 32) != stream_key_extra(TOY, 32)
 
 
 def test_the_experiment_trains_through_the_normal_path():
@@ -169,3 +169,178 @@ def test_the_other_stream_stacks_steps_lower_as_before(monkeypatch, name,
     assert jax.tree_util.tree_structure(
         make_stream_tx(TrainConfig(), cfg).init(w)) == \
         jax.tree_util.tree_structure(make_tx(TrainConfig()).init(w))
+
+
+# --------------------------------------------------------------------------
+# the latent core's two routes (`ops/mla.py::attention_route`)
+# --------------------------------------------------------------------------
+
+@pytest.fixture
+def tpu_routes(monkeypatch):
+    """Trace what a TPU traces, on the CPU: the backend says "tpu"; the
+    fused kernels' tiles are 128 x 128."""
+    from nerrf_tpu.ops import mla
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(mla, "FLASH_BLOCK_Q", 128)
+    monkeypatch.setattr(mla, "FLASH_BLOCK_K", 128)
+
+
+@pytest.fixture
+def fused_route(tpu_routes):
+    """... and run it: the kernels in Pallas' interpreter."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    with pltpu.force_tpu_interpret_mode():
+        yield
+
+
+@pytest.mark.parametrize("backend, t, d, dv, route", [
+    ("tpu", 8192, 256, 256, "pallas_flash"),
+    ("tpu", 1024, 128, 128, "pallas_flash"),
+    ("cpu", 8192, 256, 256, "xla_blocked"),
+    ("gpu", 8192, 256, 256, "xla_blocked"),
+    ("tpu", 8192, 192, 128, "xla_blocked"),    # DeepSeek-V3's widths
+    ("tpu", 8192, 192, 192, "xla_blocked"),    # not whole lanes
+    ("tpu", 8192 + 256, 256, 256, "xla_blocked"),   # not whole tiles
+    ("tpu", 32768, 256, 256, "xla_blocked"),   # a head's dq rows do not fit
+    ("tpu", 64, 12, 10, "xla_blocked"),        # the toy of this file
+])
+def test_attention_route_is_a_function_of_backend_and_shapes(
+        monkeypatch, backend, t, d, dv, route):
+    from nerrf_tpu.ops import mla
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert mla.attention_route(t, d, dv) == route
+
+
+def _packed_case(dtype, t=512, heads=2, nope=64, rot=64, dv=128):
+    """Documents of 300, 100 and 80 tokens and 32 of padding: with tiles of
+    128 the first document fills a tile below the diagonal (no mask), and
+    there are tiles above the diagonal (skipped), on it, and below it with
+    a document's edge inside."""
+    rng = np.random.default_rng(0)
+    draw = lambda *s: jnp.asarray(rng.normal(size=s), dtype)
+    seg = np.repeat([1, 2, 3, 0], [300, 100, 80, t - 480]).astype(np.int32)
+    return (draw(t, heads, nope + rot), draw(t, rot),
+            draw(t, heads, nope + dv), jnp.asarray(seg),
+            jnp.asarray(rng.normal(size=(t, heads, dv)), jnp.float32))
+
+
+def _core_loss(attend, nope=64):
+    from nerrf_tpu.ops import dsa, mla
+
+    def loss(q, k_r, kv, seg, w):
+        o = attend(*mla.assemble(q, k_r, kv, dsa.doc_positions(seg),
+                                 nope=nope, theta=1e4), seg)
+        return jnp.sum(o.astype(jnp.float32) * w)
+    return jax.value_and_grad(loss, argnums=(0, 1, 2))
+
+
+def _brute_force(q, k, v, seg):
+    t = q.shape[0]
+    logits = jnp.einsum("qhd,khd->hqk", q, k) * q.shape[-1] ** -0.5
+    valid = (seg[:, None] == seg[None, :]) & (
+        jnp.arange(t)[None, :] <= jnp.arange(t)[:, None])
+    prob = jax.nn.softmax(jnp.where(valid, logits, -jnp.inf), axis=-1)
+    return jnp.einsum("hqk,khd->qhd", prob, v)
+
+
+def test_the_fused_route_is_the_xla_route_and_brute_force(fused_route):
+    """Forward and the three gradients (``q``; ``k_r``, summed over the
+    heads it was broadcast to; ``kv``) in float32 against brute force, and
+    in bfloat16 against the XLA route, whose roundings the kernels keep."""
+    from nerrf_tpu.ops import mla
+
+    blocked = lambda *a: mla._attention(
+        *(jnp.swapaxes(x, 0, 1) for x in a[:3]), a[3], 128, 128
+    ).swapaxes(0, 1)
+    case = _packed_case(jnp.float32)
+    assert mla.attention_route(512, 128, 128) == "pallas_flash"
+    got = _core_loss(mla.attention)(*case)
+    for other in (_brute_force, blocked):
+        want = _core_loss(other)(*case)
+        for a, b in zip(jax.tree_util.tree_leaves(got),
+                        jax.tree_util.tree_leaves(want)):
+            np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4)
+    case = _packed_case(jnp.bfloat16)
+    got, want = _core_loss(mla.attention)(*case), _core_loss(blocked)(*case)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.abs(a - b).mean() < 2e-3 * np.abs(b).mean()
+
+
+def _kernel_calls(jaxpr, name):
+    """How many `pallas_call`s named ``name`` a jaxpr holds, in it and in
+    every jaxpr its equations carry."""
+    from jax.extend import core as jex
+
+    count = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call" and name in str(
+                eqn.params.get("name_and_src_info", eqn.params.get("name"))):
+            count += 1
+        for sub in jax.tree_util.tree_leaves(
+                eqn.params, is_leaf=lambda x: isinstance(
+                    x, (jex.Jaxpr, jex.ClosedJaxpr))):
+            if isinstance(sub, jex.ClosedJaxpr):
+                sub = sub.jaxpr
+            if isinstance(sub, jex.Jaxpr):
+                count += _kernel_calls(sub, name)
+    return count
+
+
+def test_a_remat_that_keeps_the_residuals_runs_the_forward_kernel_once(
+        tpu_routes):
+    """A layer under `save_only_these_names(mla.SAVED)`: its gradient holds
+    one forward kernel (the primal pass's) and one backward kernel; under a
+    policy that keeps nothing the forward kernel runs twice."""
+    from nerrf_tpu.ops import dsa, mla
+
+    q, k_r, kv, seg, w = _packed_case(jnp.float32)
+
+    def layer(q, k_r, kv):
+        o = mla.attention(*mla.assemble(q, k_r, kv, dsa.doc_positions(seg),
+                                        nope=64, theta=1e4), seg)
+        return jnp.sum(jnp.tanh(o) * w)
+
+    def calls(policy):
+        jaxpr = jax.make_jaxpr(jax.grad(jax.checkpoint(
+            layer, policy=policy), argnums=(0, 1, 2)))(q, k_r, kv).jaxpr
+        return (_kernel_calls(jaxpr, "mla_flash_fwd"),
+                _kernel_calls(jaxpr, "mla_flash_bwd"))
+
+    keep = jax.checkpoint_policies.save_only_these_names(mla.SAVED)
+    assert calls(keep) == (1, 1)
+    assert calls(jax.checkpoint_policies.nothing_saveable) == (2, 1)
+
+
+def test_the_route_rides_a_latent_steps_key_and_no_other(monkeypatch):
+    """`stream_key_extra` of a latent configuration differs between a
+    backend that traces the fused route and one that traces the XLA route;
+    the two other stream stacks' key material is what it was."""
+    from nerrf_tpu.train.stream import stream_kernel_path, stream_key_extra
+
+    wide = dataclasses.replace(TOY, qk_nope_dim=64, qk_rope_dim=64,
+                               v_head_dim=128)
+    keys = {}
+    for backend in ("cpu", "tpu"):
+        monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
+        keys[backend] = stream_key_extra(wide, 8192)
+        for other in (KEYE, PHI4):
+            assert stream_key_extra(other) == {"stream_cfg": repr(other)}
+            assert stream_key_extra(other, 8192) == stream_key_extra(other)
+        # the toy's widths take the XLA route on any backend
+        assert stream_kernel_path(TOY, 8192) == {
+            "mla_attention": "xla_blocked"}
+    assert keys["cpu"]["mla_attention"] == "xla_blocked"
+    assert keys["tpu"]["mla_attention"] == "pallas_flash"
+    assert keys["cpu"]["stream_cfg"] == keys["tpu"]["stream_cfg"]
+    # the module alone is a latent layer too
+    only_mtp = dataclasses.replace(PHI4, mtp_layers=1, qk_nope_dim=64,
+                                   qk_rope_dim=64, v_head_dim=128)
+    assert stream_kernel_path(only_mtp, 8192) == {
+        "mla_attention": "pallas_flash"}
+    with pytest.raises(ValueError):
+        stream_key_extra(wide)

@@ -108,6 +108,39 @@ def test_latent_attention_runs_its_blocks_one_after_another(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 3 << 30
 
 
+def test_latent_attention_fused_route_compiles_at_the_published_widths(
+        one_chip, monkeypatch):
+    """The same core on the route a TPU traces (`mla.attention_route` ->
+    ``pallas_flash``), forward + backward: the chip's compiler accepts both
+    kernels at the module's tile sizes (a [512, 512] float32 tile and a
+    head's whole ``dq`` [8192, 256] in its fast memory), the program holds
+    one custom call a pass, and no score ever reaches HBM: the plan of a
+    batch of two is their float32 ``dq`` and the rows' sums, not the XLA
+    route's gigabytes."""
+    from nerrf_tpu.ops import dsa, mla
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert mla.attention_route(T, 256, 256) == "pallas_flash"
+
+    def loss(q, k_r, kv, seg):
+        # under `vmap` as the layer calls it (`models/stream.py`): the
+        # kernels' batching rule adds the batch to their grids
+        o = jax.vmap(lambda q, k_r, kv, seg: mla.attention(*mla.assemble(
+            q, k_r, kv, dsa.doc_positions(seg), nope=192, theta=1e6), seg))(
+                q, k_r, kv, seg)
+        return jnp.sum(o.astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        shape(one_chip, (2, T, 20, 256), jnp.bfloat16),
+        shape(one_chip, (2, T, 64), jnp.bfloat16),
+        shape(one_chip, (2, T, 20, 448), jnp.bfloat16),
+        shape(one_chip, (2, T), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "mla_flash_fwd" in text and "mla_flash_bwd" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
 def test_expert_walk_never_copies_the_weights_per_tile(one_chip):
     """`ops/moe.py::moe_share` at 8192 tokens, 16 held experts of 2048 x
     768, 8 of 128 a token, forward + backward: the worst routing has 272
