@@ -13,6 +13,11 @@ is XLA-jit friendly, models as pure jitted functions, distributed execution via
 backend.
 """
 
+# first, and jax-free: the tracer's epoch IS the program's first import, so
+# the set-up timeline (docs/operations.md, "Time to first step") has one
+# origin whatever module of the package a caller reaches for first
+from nerrf_tpu import tracing as _tracing  # noqa: F401
+
 __version__ = "0.1.0"
 
 # Chip-side entry points (bench.py, train.run, the offline benchmarks)

@@ -34,6 +34,15 @@ Metrics: ``nerrf_compile_cache_{hits,misses,bytes}_total`` and
 ``nerrf_compile_seconds{program,source=cache|fresh}``.  Journal records of
 kind ``compile`` carry (program, fingerprint, source, seconds, reason) —
 `nerrf doctor <bundle>` reconstructs compile provenance from them offline.
+
+Spans: a resolution is one ``compile_resolve`` whose children are its
+stages (``compile_resolve.fingerprint`` / ``read`` / ``deserialize`` on a
+hit; ``lower`` / ``compile`` / ``serialize`` / ``persist`` after a miss).
+What JAX compiles on its own (every jitted function no `CompileCache`
+fronts) is recorded too: `install_jit_listener` turns each of JAX's
+trace / lower / backend-compile / persistent-cache-retrieval durations into
+a ``jit_compile`` span with the function's name and counts it in
+``nerrf_jit_compiles_total{stage}``.
 """
 
 from __future__ import annotations
@@ -45,11 +54,12 @@ import os
 import pickle
 import shutil
 import tempfile
+import threading
 import time
 from pathlib import Path
 from typing import Dict, List, Optional, Tuple
 
-from nerrf_tpu.tracing import span
+from nerrf_tpu.tracing import record, span
 
 PAYLOAD = "executable.bin"
 TREES = "trees.pkl"
@@ -196,6 +206,61 @@ def compute_fingerprint(program: str, avals: dict, extra: Optional[dict],
         material
 
 
+def _stage(name: str, **args):
+    """One stage of a resolution: a child span of the open
+    ``compile_resolve`` (on the profiler's host plane too, like it)."""
+    return span(f"compile_resolve.{name}", device=True, **args)
+
+
+# jax.monitoring's duration events -> the ``stage`` of a `jit_compile` span
+JIT_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_retrieval",
+}
+# An event shorter than this is counted and not spanned: tracing one step
+# function fires thousands of microsecond-long trace events for the jitted
+# library functions inside it (3,456 for a toy NerrfNet step, 96 % of them
+# under a millisecond), all inside their caller's own event, and a ring of
+# 65,536 spans must still hold the whole run for its readers.
+JIT_SPAN_MIN_SECONDS = 1e-3
+_jit_listener_lock = threading.Lock()
+_jit_listener_installed = False
+
+
+def _on_jit_duration(event: str, secs: float, **kwargs) -> None:
+    stage = JIT_STAGES.get(event)
+    if stage is None:
+        return
+    from nerrf_tpu.observability import DEFAULT_REGISTRY
+
+    if secs >= JIT_SPAN_MIN_SECONDS:
+        # told when it is over: the span ends now.  Inside an open
+        # `compile_resolve` stage it is that stage's child by the parent rule
+        record("jit_compile", secs, device=True, stage=stage,
+               fun=kwargs.get("fun_name"))
+    DEFAULT_REGISTRY.counter_inc(
+        "jit_compiles_total", labels={"stage": stage},
+        help="JAX trace / lower / backend-compile / persistent-cache "
+             "retrieval events; rising while a loop steps means a program "
+             "is being built in the hot path")
+
+
+def install_jit_listener() -> None:
+    """Record JAX's own compile-stage durations as ``jit_compile`` spans,
+    process-wide, once however often it is called.  A loaded executable's
+    call emits no such event, so a step costs nothing."""
+    global _jit_listener_installed
+    with _jit_listener_lock:
+        if _jit_listener_installed:
+            return
+        import jax.monitoring
+
+        jax.monitoring.register_event_duration_secs_listener(_on_jit_duration)
+        _jit_listener_installed = True
+
+
 def default_cache_dir() -> str:
     """The standard cache root: ``aot/`` under `utils.compile_cache_dir`
     ($JAX_COMPILATION_CACHE_DIR when set — the serve manifest mounts a
@@ -231,6 +296,7 @@ class CompileCache:
         self._journal = journal
         self._log = log or (lambda msg: None)
         self._env: Optional[dict] = None  # resolved lazily (needs a backend)
+        install_jit_listener()
 
     # -- wiring ---------------------------------------------------------------
 
@@ -294,33 +360,42 @@ class CompileCache:
 
     def get(self, fingerprint: str):
         """→ a loaded `jax.stages.Compiled`, or None (fail-open: any
-        unreadable/corrupt/foreign entry is a miss, never an error)."""
-        entry = self._find_entry(fingerprint)
-        if entry is None:
-            return None
+        unreadable/corrupt/foreign entry is a miss, never an error).  Two
+        stages: ``read`` (find, adopt from a seed, read the files) and
+        ``deserialize``; a lookup that finds nothing is a ``read`` of 0
+        bytes."""
+        entry = None
         try:
-            import jax
-            from jax.experimental import serialize_executable as se
+            with _stage("read", bytes=0) as sp:
+                entry, adopted = self._find_entry(fingerprint)
+                if entry is None:
+                    return None
+                if adopted:
+                    sp.args["adopted"] = True
+                from nerrf_tpu import chaos
 
-            from nerrf_tpu import chaos
+                # chaos fault point (no-op disarmed): bit rot / torn write
+                # in the entry payload — deserialize must fail below and
+                # take the evict-and-compile-live fail-open path, never
+                # serve a damaged executable
+                payload = chaos.mangle(
+                    "compilecache.corrupt_payload",
+                    (entry / PAYLOAD).read_bytes(), key=fingerprint)
+                trees = (entry / TREES).read_bytes()
+                sp.args["bytes"] = len(payload) + len(trees)
+            with _stage("deserialize"):
+                import jax
+                from jax.experimental import serialize_executable as se
 
-            # chaos fault point (no-op disarmed): bit rot / torn write in
-            # the entry payload — deserialize must fail here and take the
-            # evict-and-compile-live fail-open path below, never serve a
-            # damaged executable
-            payload = chaos.mangle(
-                "compilecache.corrupt_payload",
-                (entry / PAYLOAD).read_bytes(), key=fingerprint)
-            in_tree, out_tree, device_ids = pickle.loads(
-                (entry / TREES).read_bytes())
-            # the entry's own device assignment, in order: left to its
-            # default, jax 0.9 reloads every executable over ALL devices of
-            # the backend, and a single-device program then fails at call
-            # time on any host with more than one
-            by_id = {d.id: d for d in jax.devices()}
-            compiled = se.deserialize_and_load(
-                payload, in_tree, out_tree,
-                execution_devices=[by_id[i] for i in device_ids])
+                in_tree, out_tree, device_ids = pickle.loads(trees)
+                # the entry's own device assignment, in order: left to its
+                # default, jax 0.9 reloads every executable over ALL devices
+                # of the backend, and a single-device program then fails at
+                # call time on any host with more than one
+                by_id = {d.id: d for d in jax.devices()}
+                compiled = se.deserialize_and_load(
+                    payload, in_tree, out_tree,
+                    execution_devices=[by_id[i] for i in device_ids])
         except Exception as e:  # noqa: BLE001 — fail-open by contract
             self._log(f"compile cache: entry {fingerprint} unreadable "
                       f"({type(e).__name__}: {e}); compiling live")
@@ -338,15 +413,18 @@ class CompileCache:
             pass
         return compiled
 
-    def _find_entry(self, fingerprint: str) -> Optional[Path]:
+    def _find_entry(self, fingerprint: str) -> Tuple[Optional[Path], bool]:
+        """→ (the entry's directory or None, whether it was copied in from
+        a seed root just now)."""
         primary = self.entry_dir(fingerprint)
         if (primary / PAYLOAD).is_file() and (primary / TREES).is_file():
-            return primary
+            return primary, False
         for seed in self.seed_dirs:
             cand = seed / fingerprint
             if (cand / PAYLOAD).is_file() and (cand / TREES).is_file():
-                return self._adopt(cand, fingerprint) or cand
-        return None
+                adopted = self._adopt(cand, fingerprint)
+                return adopted or cand, adopted is not None
+        return None, False
 
     def _adopt(self, seed_entry: Path, fingerprint: str) -> Optional[Path]:
         """Copy a seed entry into the primary root (atomic, best-effort) so
@@ -387,12 +465,13 @@ class CompileCache:
         serialization) vs "unwritable" (read-only volume, disk full) —
         the distinction operators need to diagnose which; never raises."""
         try:
-            from jax.experimental import serialize_executable as se
+            with _stage("serialize"):
+                from jax.experimental import serialize_executable as se
 
-            payload, in_tree, out_tree = se.serialize(compiled)
-            device_ids = [d.id for d in
-                          compiled.runtime_executable().local_devices()]
-            trees = pickle.dumps((in_tree, out_tree, device_ids))
+                payload, in_tree, out_tree = se.serialize(compiled)
+                device_ids = [d.id for d in
+                              compiled.runtime_executable().local_devices()]
+                trees = pickle.dumps((in_tree, out_tree, device_ids))
         except Exception as e:  # noqa: BLE001 — fail-open by contract
             self._log(f"compile cache: cannot serialize {program} "
                       f"({type(e).__name__}: {e}); running uncached")
@@ -406,37 +485,44 @@ class CompileCache:
             "compile_seconds": round(compile_seconds, 3),
             "created_at": time.time(),
         }
-        try:
-            self.root.mkdir(parents=True, exist_ok=True)
-            tmp = Path(tempfile.mkdtemp(prefix=".put-", dir=self.root))
+        with _stage("persist", bytes=len(payload) + len(trees)):
             try:
-                (tmp / PAYLOAD).write_bytes(payload)
-                (tmp / TREES).write_bytes(trees)
-                (tmp / META).write_text(json.dumps(meta, indent=2))
-                target = self.entry_dir(fingerprint)
-                if (target / PAYLOAD).is_file() and \
-                        (target / TREES).is_file():
-                    # concurrent writer won with a complete entry; keep it
-                    shutil.rmtree(tmp, ignore_errors=True)
-                else:
-                    # absent, or an invalid husk (partial delete, missing
-                    # trees) that _find_entry skips — replace so a damaged
-                    # entry is repaired by the very compile it caused
-                    if target.exists():
-                        shutil.rmtree(target, ignore_errors=True)
-                    os.rename(tmp, target)
-            except OSError:
-                shutil.rmtree(tmp, ignore_errors=True)
-                raise
-        except OSError as e:
-            self._log(f"compile cache: cannot persist {program} "
-                      f"({type(e).__name__}: {e}); result stays in-process")
-            return "unwritable"
-        self._reg().counter_inc(
-            "compile_cache_bytes_total", float(len(payload)),
-            help="serialized executable bytes written into the cache")
-        self.prune()
+                self._write_entry(fingerprint, payload, trees, meta)
+            except OSError as e:
+                self._log(f"compile cache: cannot persist {program} "
+                          f"({type(e).__name__}: {e}); result stays "
+                          "in-process")
+                return "unwritable"
+            self._reg().counter_inc(
+                "compile_cache_bytes_total", float(len(payload)),
+                help="serialized executable bytes written into the cache")
+            self.prune()
         return None
+
+    def _write_entry(self, fingerprint: str, payload: bytes, trees: bytes,
+                     meta: dict) -> None:
+        """One entry into the root, atomically (tmp directory, then
+        rename); raises OSError where the root cannot take it."""
+        self.root.mkdir(parents=True, exist_ok=True)
+        tmp = Path(tempfile.mkdtemp(prefix=".put-", dir=self.root))
+        try:
+            (tmp / PAYLOAD).write_bytes(payload)
+            (tmp / TREES).write_bytes(trees)
+            (tmp / META).write_text(json.dumps(meta, indent=2))
+            target = self.entry_dir(fingerprint)
+            if (target / PAYLOAD).is_file() and (target / TREES).is_file():
+                # concurrent writer won with a complete entry; keep it
+                shutil.rmtree(tmp, ignore_errors=True)
+            else:
+                # absent, or an invalid husk (partial delete, missing
+                # trees) that _find_entry skips — replace so a damaged
+                # entry is repaired by the very compile it caused
+                if target.exists():
+                    shutil.rmtree(target, ignore_errors=True)
+                os.rename(tmp, target)
+        except OSError:
+            shutil.rmtree(tmp, ignore_errors=True)
+            raise
 
     # -- the one entry point --------------------------------------------------
 
@@ -450,11 +536,12 @@ class CompileCache:
         time.  Total failure (lower/compile/serialize machinery broken):
         the live ``jit_fn`` itself, source="live" — serving always works.
 
-        The whole resolution (fingerprint, read, deserialize or compile,
-        persist) is one ``compile_resolve`` span; ``CompileInfo.seconds``
-        stays the read's or the compile's time alone.
+        The whole resolution is one ``compile_resolve`` span and each
+        stage (fingerprint, read, deserialize, or lower, compile,
+        serialize, persist) a child of it; ``CompileInfo.seconds`` stays
+        the read's or the compile's time alone.
         """
-        with span("compile_resolve", program=program) as sp:
+        with span("compile_resolve", device=True, program=program) as sp:
             fn, info = self._load_or_compile(jit_fn, args, kwargs or {},
                                              program, extra)
             sp.args.update(source=info.source, reason=info.reason)
@@ -463,10 +550,11 @@ class CompileCache:
     def _load_or_compile(self, jit_fn, args: tuple, kwargs: dict,
                          program: str, extra: Optional[dict]):
         try:
-            avals = aval_signature(args, kwargs)
-            env = {**self.env(), "devices": call_devices(args, kwargs)}
-            fp, material = compute_fingerprint(program, avals, extra,
-                                               env=env)
+            with _stage("fingerprint"):
+                avals = aval_signature(args, kwargs)
+                env = {**self.env(), "devices": call_devices(args, kwargs)}
+                fp, material = compute_fingerprint(program, avals, extra,
+                                                   env=env)
         except Exception as e:  # noqa: BLE001 — fail-open by contract
             info = CompileInfo(program=program, fingerprint="",
                                source="live", seconds=0.0,
@@ -532,7 +620,10 @@ class CompileCache:
             jax.config.update("jax_enable_compilation_cache", False)
             reset_cache()  # drop the memoized verdict so the flag is re-read
         try:
-            return jit_fn.lower(*args, **kwargs).compile()
+            with _stage("lower"):
+                lowered = jit_fn.lower(*args, **kwargs)
+            with _stage("compile"):
+                return lowered.compile()
         finally:
             if suspend:
                 jax.config.update("jax_enable_compilation_cache", True)

@@ -27,25 +27,55 @@ of its thread when it started (None at the top of a thread), so a span's
 **self time** — its duration less what its children cover — can be read
 (`self_time`, the ``self_ms`` column of `nerrf trace`).
 
-Span naming scheme (dot-separated, coarse → fine):
+Span naming scheme (dot-separated, coarse → fine; `docs/operations.md`,
+"Time to first step", reads the set-up names as one timeline):
 
     ingest_decode      EventBatch frame → native decode (ingest client)
+    tracker_stream     one StreamEvents subscription, start → drain (ingest)
     graph_lower        one window of events → padded GraphBatch (builder)
+    trace_lower        one trace → all its padded window samples: labels,
+                       the windows' graph_lower children, the per-file
+                       sequences (train.data.windows_of_trace)
     store_compact      trace-store delta → bucket segments
     store_query        trace-store window read
     bucket_pad         trace → capacity-bucketed padded window samples
+    detect_score       one chunk of padded windows through the eval program
     calibrate          held-out file-threshold calibration
     data_wait          host blocked waiting for input data
     corpus_simulate    one synthetic trace simulated (data.make_corpus)
+    stream_tokenize    one trace's events → token ids (data.stream)
+    stream_pack        documents → packed [num_seqs, seq_len] sequences
     dataset_upload     host arrays → device, chunked (device_put_chunked)
+    module_import      one of the program's heavy modules imported (what it
+                       brings in after the tracer's epoch); args: module
     compile_resolve    one executable obtained: fingerprint, cache read,
-                       deserialize or compile, persist (CompileCache)
+                       deserialize or compile, persist (CompileCache);
+                       args: program, source, reason.  Its stages are its
+                       children:
+      compile_resolve.fingerprint   avals, environment_key() with its
+                                    source_digest(), the key's hash
+      compile_resolve.read          entry found and read; args: bytes,
+                                    adopted (a seed entry was copied in)
+      compile_resolve.deserialize   deserialize_and_load
+      compile_resolve.lower         jit_fn.lower(...)
+      compile_resolve.compile       lowered.compile()
+      compile_resolve.serialize     serialize_executable.serialize
+      compile_resolve.persist       write + rename + prune; args: bytes
+    jit_compile        one of JAX's own trace / lower / backend-compile /
+                       persistent-cache-retrieval durations, recorded when
+                       it ended (`record`); args: stage, fun
+    train_setup        the loop's state built (model.init) and, with
+                       phase="step_fns", its step functions made
+    step_build         the resident step made: its dataset_upload child
+                       and the jit wrappers (make_train_step_scheduled)
+    train_loop         the stepping loop, first call to last wait
     train_step_call    one call of a train step: the program's Python
                        plus the runtime's call; args: call (0-based)
     train_step_execute the resolved executable's call alone (child of
                        train_step_call)
     train_step_wait    the loop blocked on a device result at a sync it
                        has anyway (step-0 barrier, logged step, end)
+    devtime_cost       the analytic cost trace behind the MFU gauges
     eval               held-out evaluation pass
     checkpoint         full-state checkpoint save
     mcts_plan          one planner search; mcts_leaf_eval = device batch
@@ -53,6 +83,8 @@ Span naming scheme (dot-separated, coarse → fine):
     serve_batch_close  a bucket's shared batch assembled (occupancy/deadline)
     serve_device_score one shared padded batch through the eval program
     serve_demux        scored batch fanned back to streams + alert sink
+    registry_shadow_score  a shadow version scored beside the live one
+    registry_swap      the live parameters swapped for a registry version
 
 The ring buffer records unconditionally (bounded memory, ~µs overhead)
 and there is no switch: no span syncs with the device, so recording never
@@ -102,6 +134,36 @@ class Span:
         self.parent = parent
 
 
+def _process_age() -> Optional[float]:
+    """Seconds since this process started, from the kernel's own record:
+    ``/proc/self/stat``'s start time (clock ticks since boot, 10 ms) against
+    ``CLOCK_BOOTTIME``.  None where the platform has no such record: never
+    a guess."""
+    try:
+        with open("/proc/self/stat") as f:
+            stat = f.read()
+        # the fields after "(comm)", which may itself hold spaces
+        start_ticks = int(stat[stat.rindex(")") + 2:].split()[19])
+        return (time.clock_gettime(time.CLOCK_BOOTTIME)
+                - start_ticks / os.sysconf("SC_CLK_TCK"))
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
+
+
+def _annotation(name: str, stats: Dict):
+    """An entered `jax.profiler.TraceAnnotation`, or None where jax is not
+    imported yet (this module must not force backend init) or refuses."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    try:
+        ann = jax.profiler.TraceAnnotation(name, **stats)
+        ann.__enter__()
+        return ann
+    except Exception:
+        return None
+
+
 class Tracer:
     """Thread-safe ring-buffered span recorder with Chrome-trace export."""
 
@@ -115,6 +177,10 @@ class Tracer:
         # aligned offline
         self._t0_perf = time.perf_counter()
         self._t0_epoch = time.time()
+        # when the process started, in seconds relative to the epoch above
+        # (negative); what lies between is not the tracer's to span
+        age = _process_age()
+        self.process_start: Optional[float] = None if age is None else -age
         self._ids = itertools.count(1)
         self._open = threading.local()  # .stack: ids of this thread's open spans
 
@@ -126,6 +192,22 @@ class Tracer:
 
             self._registry = DEFAULT_REGISTRY
         return self._registry
+
+    def _open_stack(self) -> list:
+        stack = getattr(self._open, "stack", None)
+        if stack is None:
+            stack = self._open.stack = []
+        return stack
+
+    def _append(self, sp: Span) -> None:
+        with self._lock:
+            self._spans.append(sp)
+            # latest name wins: CPython recycles thread idents, so a
+            # cached dead thread's name must not label a new thread
+            self._thread_names[sp.tid] = threading.current_thread().name
+        self._reg().histogram_observe(
+            STAGE_HISTOGRAM, sp.dur, buckets=STAGE_BUCKETS,
+            labels={"stage": sp.name}, help=_STAGE_HELP)
 
     @contextlib.contextmanager
     def span(self, stage: str, device: bool = False, **args):
@@ -140,20 +222,10 @@ class Tracer:
         events' clock; a ``call`` argument travels with it as a stat, so a
         host call and the execution it started can be matched there.
         """
-        stack = getattr(self._open, "stack", None)
-        if stack is None:
-            stack = self._open.stack = []
+        stack = self._open_stack()
         sp = Span(stage, args, next(self._ids), stack[-1] if stack else None)
-        ann = None
-        if device:
-            jax = sys.modules.get("jax")
-            if jax is not None:
-                try:
-                    stats = {"call": args["call"]} if "call" in args else {}
-                    ann = jax.profiler.TraceAnnotation(stage, **stats)
-                    ann.__enter__()
-                except Exception:
-                    ann = None
+        stats = {"call": args["call"]} if "call" in args else {}
+        ann = _annotation(stage, stats) if device else None
         stack.append(sp.id)
         t0 = time.perf_counter()
         sp.t0 = t0 - self._t0_perf
@@ -165,14 +237,28 @@ class Tracer:
             if ann is not None:
                 with contextlib.suppress(Exception):
                     ann.__exit__(None, None, None)
-            with self._lock:
-                self._spans.append(sp)
-                # latest name wins: CPython recycles thread idents, so a
-                # cached dead thread's name must not label a new thread
-                self._thread_names[sp.tid] = threading.current_thread().name
-            self._reg().histogram_observe(
-                STAGE_HISTOGRAM, sp.dur, buckets=STAGE_BUCKETS,
-                labels={"stage": stage}, help=_STAGE_HELP)
+            self._append(sp)
+
+    def record(self, name: str, dur: float, device: bool = False,
+               **args) -> Span:
+        """Append a span that ended now and lasted ``dur`` seconds: for an
+        event the program is told of only when it is over (a `jax.monitoring`
+        duration).  Ids, the parent (the innermost span open on this thread
+        now) and the dual-write are `span`'s.  ``device=True`` leaves an
+        instant `jax.profiler.TraceAnnotation` of the name at the event's
+        end, its duration beside it as the stat ``dur_us`` (an annotation
+        cannot be dated back)."""
+        stack = self._open_stack()
+        sp = Span(name, args, next(self._ids), stack[-1] if stack else None)
+        sp.dur = max(float(dur), 0.0)
+        sp.t0 = time.perf_counter() - self._t0_perf - sp.dur
+        if device:
+            ann = _annotation(name, {"dur_us": int(sp.dur * 1e6)})
+            if ann is not None:
+                with contextlib.suppress(Exception):
+                    ann.__exit__(None, None, None)
+        self._append(sp)
+        return sp
 
     # -- inspection / export -------------------------------------------------
 
@@ -213,6 +299,9 @@ class Tracer:
             "otherData": {
                 "producer": "nerrf_tpu.tracing",
                 "epoch_anchor_unix_sec": self._t0_epoch,
+                # seconds from the epoch back to the process's start
+                # (negative), None where the platform does not say
+                "process_start_sec": self.process_start,
             },
         }
 
@@ -235,6 +324,11 @@ DEFAULT_TRACER = Tracer()
 def span(stage: str, device: bool = False, **args):
     """``DEFAULT_TRACER.span`` — the one-import instrumentation point."""
     return DEFAULT_TRACER.span(stage, device=device, **args)
+
+
+def record(name: str, dur: float, device: bool = False, **args) -> Span:
+    """``DEFAULT_TRACER.record``: a span that has already ended."""
+    return DEFAULT_TRACER.record(name, dur, device=device, **args)
 
 
 # -- trace-file analysis (the `nerrf trace` subcommand's engine) -------------

@@ -24,6 +24,7 @@ from nerrf_tpu.graph.builder import (
     build_window_graph,
     snapshot_windows,
 )
+from nerrf_tpu.tracing import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -157,20 +158,27 @@ def windows_of_trace(trace: Trace, cfg: DatasetConfig,
     sample so callers (corpus generation) can account for capacity overflow —
     the r2 corpus was silently truncating attack-burst windows at the
     256n/512e defaults, which is exactly the signal a detector needs.
+
+    One ``trace_lower`` span a trace: the labels, every window's
+    ``graph_lower`` (its children) and the per-file sequences around them,
+    which are three quarters of the lowering's time and no span's otherwise.
     """
-    labels = derive_event_labels(trace)
-    ev = trace.events
-    if ev.num_valid == 0:
-        return []
-    valid_ts = ev.ts_ns[ev.valid]
-    out = []
-    for lo, hi in snapshot_windows(int(valid_ts.min()), int(valid_ts.max()), cfg.graph):
-        sample, stats = window_sample(trace, lo, hi, cfg, labels=labels)
-        if sample is None:
-            continue
-        if stats_out is not None:
-            stats_out.append(stats)
-        out.append(sample)
+    with span("trace_lower") as sp:
+        labels = derive_event_labels(trace)
+        ev = trace.events
+        if ev.num_valid == 0:
+            return []
+        valid_ts = ev.ts_ns[ev.valid]
+        out = []
+        for lo, hi in snapshot_windows(int(valid_ts.min()),
+                                       int(valid_ts.max()), cfg.graph):
+            sample, stats = window_sample(trace, lo, hi, cfg, labels=labels)
+            if sample is None:
+                continue
+            if stats_out is not None:
+                stats_out.append(stats)
+            out.append(sample)
+        sp.args["windows"] = len(out)
     return out
 
 

@@ -22,19 +22,27 @@ from functools import partial
 from pathlib import Path
 from typing import Any, Dict, Optional
 
-import jax
-import jax.numpy as jnp
-import numpy as np
+_T_IMPORT = time.perf_counter()   # for the `module_import` span below
 
-from nerrf_tpu.utils import sync_result
-import optax
-from flax.training import train_state
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
 
-from nerrf_tpu.models.joint import JointConfig, NerrfNet
-from nerrf_tpu.observability import DEFAULT_REGISTRY
-from nerrf_tpu.tracing import DEFAULT_TRACER
-from nerrf_tpu.train.data import WindowDataset, padding_waste_fractions
-from nerrf_tpu.train.metrics import best_f1, roc_auc
+from nerrf_tpu.utils import sync_result  # noqa: E402
+import optax  # noqa: E402
+from flax.training import train_state  # noqa: E402
+
+from nerrf_tpu.models.joint import JointConfig, NerrfNet  # noqa: E402
+from nerrf_tpu.observability import DEFAULT_REGISTRY  # noqa: E402
+from nerrf_tpu.tracing import DEFAULT_TRACER  # noqa: E402
+from nerrf_tpu.train.data import (  # noqa: E402
+    WindowDataset, padding_waste_fractions)
+from nerrf_tpu.train.metrics import best_f1, roc_auc  # noqa: E402
+
+# what this module brought in after the tracer's epoch (jax, flax and optax
+# where nothing had imported them yet: seconds of `train.run`'s start)
+DEFAULT_TRACER.record("module_import", time.perf_counter() - _T_IMPORT,
+                      module=__name__)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -297,7 +305,7 @@ def device_put_chunked(arrays, max_bytes: int = 64 << 20, block: bool = False,
     total = 0
     # the span is the host's part of the upload (the transfers are async
     # unless block=True, whose barrier lies outside it)
-    with DEFAULT_TRACER.span("dataset_upload") as sp:
+    with DEFAULT_TRACER.span("dataset_upload", device=True) as sp:
         for k, v in arrays.items():
             v = np.asarray(v)
             nbytes = v.nbytes
@@ -338,10 +346,14 @@ def make_train_step_scheduled(model: NerrfNet, cfg: TrainConfig, arrays,
     ``idx_table`` is [num_steps, batch] int32.  ``loss_fn(params, batch,
     dropout_rng) -> (loss, aux)`` replaces NerrfNet's joint loss (the stream
     encoder trains through this same step: `train/stream.py`, with ``tx``
-    where its optimizer is not `make_tx`'s)."""
-    _, make_scheduled, _ = _make_resident_steps(model, cfg, arrays, loss_fn,
-                                                tx)
-    return make_scheduled(idx_table)
+    where its optimizer is not `make_tx`'s).  One ``step_build`` span: the
+    `dataset_upload` it causes (its child) and the jit wrappers; nothing is
+    traced or compiled here."""
+    with DEFAULT_TRACER.span("step_build", device=True,
+                             flavor="scheduled"):
+        _, make_scheduled, _ = _make_resident_steps(model, cfg, arrays,
+                                                    loss_fn, tx)
+        return make_scheduled(idx_table)
 
 
 def make_train_superstep(model: NerrfNet, cfg: TrainConfig, arrays,
@@ -470,6 +482,16 @@ RESIDENT_MAX_BYTES = 2 << 30
 # ``full_history=True``; everyone else gets the newest HISTORY_LIMIT
 # entries (TrainResult.history stays a plain list either way).
 HISTORY_LIMIT = 512
+
+
+def gauge_host_blocked(blocked_s: float, elapsed: float) -> None:
+    """``train_host_blocked_fraction``: the `train_step_wait` seconds of a
+    loop's steady state (after step 0) over that steady state's wall."""
+    DEFAULT_REGISTRY.gauge_set(
+        "train_host_blocked_fraction", blocked_s / elapsed,
+        help="fraction of steady-state train wall spent blocked on "
+             "device results (train_step_wait spans: the syncs the "
+             "loop has anyway)")
 
 
 def _history(full_history: bool) -> deque:
@@ -819,11 +841,7 @@ def train_nerrfnet(
         # same denominator as steps_per_sec (post-step-0 steady state), so
         # the fractions attribute the time the headline number measures —
         # dividing by the whole loop would dilute them with compile time
-        DEFAULT_REGISTRY.gauge_set(
-            "train_host_blocked_fraction", blocked_s / elapsed,
-            help="fraction of steady-state train wall spent blocked on "
-                 "device results (train_step_wait spans: the syncs the "
-                 "loop has anyway)")
+        gauge_host_blocked(blocked_s, elapsed)
         DEFAULT_REGISTRY.gauge_set(
             "train_data_wait_fraction", data_wait_s / elapsed,
             help="fraction of steady-state train wall spent assembling or "

@@ -466,6 +466,11 @@ def main(argv=None) -> int:
                          "workload sketches) into a crash-safe segmented "
                          "archive here — `nerrf report` reconstructs the "
                          "run's health offline (docs/archive.md)")
+    ap.add_argument("--trace-out", default=None, metavar="FILE",
+                    help="write the run's host spans (the set-up timeline "
+                         "before the first step included) as Chrome-trace "
+                         "JSON at exit; `nerrf trace --file FILE` reads it "
+                         "(docs/operations.md, \"Time to first step\")")
     args = ap.parse_args(argv)
     from nerrf_tpu.utils import enable_compilation_cache
 
@@ -486,13 +491,19 @@ def main(argv=None) -> int:
 
         compile_cache = CompileCache(root=args.aot_cache, log=_log)
         _log(f"compile cache at {compile_cache.root}")
-    report = run_experiment(args.experiment, args.out, args.steps,
-                            args.ckpt_every, publish_to=args.publish,
-                            lineage=args.lineage,
-                            compile_cache=compile_cache,
-                            metrics_port=args.metrics_port,
-                            flight_dir=args.flight_dir,
-                            archive_dir=args.archive_dir)
+    try:
+        report = run_experiment(args.experiment, args.out, args.steps,
+                                args.ckpt_every, publish_to=args.publish,
+                                lineage=args.lineage,
+                                compile_cache=compile_cache,
+                                metrics_port=args.metrics_port,
+                                flight_dir=args.flight_dir,
+                                archive_dir=args.archive_dir)
+    finally:
+        if args.trace_out:
+            from nerrf_tpu.tracing import DEFAULT_TRACER
+
+            _log(f"trace written to {DEFAULT_TRACER.write(args.trace_out)}")
     return 0 if all(report["gates"].values()) else 1
 
 

@@ -24,6 +24,7 @@ import optax
 
 from nerrf_tpu.models.stream import (LATENT_KINDS, StreamConfig, StreamNet,
                                      mtp_loss, next_token_loss, stream_loss)
+from nerrf_tpu.tracing import DEFAULT_TRACER
 from nerrf_tpu.train import loop
 
 
@@ -190,29 +191,43 @@ def train_stream(arrays: dict, scfg: StreamConfig, cfg: loop.TrainConfig,
     steps (the loop's only sync) and at the end."""
     n = len(next(iter(arrays.values())))
     model = StreamNet(scfg)
-    rng = jax.random.PRNGKey(cfg.seed)
-    rng, init_rng = jax.random.split(rng)
-    sample = {k: v[:min(cfg.batch_size, n)] for k, v in arrays.items()}
-    state = init_stream_state(model, cfg, sample, init_rng)
+    tracer = DEFAULT_TRACER
+    with tracer.span("train_setup", device=True):
+        # the keys too: a process's first PRNG call compiles
+        rng = jax.random.PRNGKey(cfg.seed)
+        rng, init_rng = jax.random.split(rng)
+        sample = {k: v[:min(cfg.batch_size, n)] for k, v in arrays.items()}
+        state = init_stream_state(model, cfg, sample, init_rng)
     for op, route in stream_kernel_path(scfg, _seq_len(scfg, arrays)).items():
         log(f"kernel_path: {op}: {route}")
-    step = make_stream_step(model, cfg, arrays,
-                            loop.make_idx_schedule(n, cfg), compile_cache)
+    with tracer.span("train_setup", device=True, phase="step_fns"):
+        step = make_stream_step(model, cfg, arrays,
+                                loop.make_idx_schedule(n, cfg), compile_cache)
     history = []
     t_first = None
     synced = -1
-    for i in range(cfg.num_steps):
-        state, loss, aux, rng = step(state, rng)
-        if i == 0 or (i + 1) % cfg.eval_every == 0 or i == cfg.num_steps - 1:
-            # the loop's only sync: logged steps (eval_every)
-            history.append({"step": i, "loss": float(loss)})
-            log(f"step {i}: loss {history[-1]['loss']:.4f}")
-            count_sparse(aux, scfg, steps=i - synced)
-            synced = i
-            if t_first is None:      # step 0 holds the compile
-                t_first = time.perf_counter()
-    steps_per_sec = max(cfg.num_steps - 1, 1) / max(
-        time.perf_counter() - (t_first or 0.0), 1e-9)
+    blocked_s = 0.0
+    with tracer.span("train_loop", steps=cfg.num_steps, resident=True,
+                     seq_len=_seq_len(scfg, arrays)):
+        for i in range(cfg.num_steps):
+            state, loss, aux, rng = step(state, rng)
+            if (i == 0 or (i + 1) % cfg.eval_every == 0
+                    or i == cfg.num_steps - 1):
+                # the loop's only sync: logged steps (eval_every)
+                with tracer.span("train_step_wait", step=i) as wait:
+                    history.append({"step": i, "loss": float(loss)})
+                log(f"step {i}: loss {history[-1]['loss']:.4f}")
+                count_sparse(aux, scfg, steps=i - synced)
+                synced = i
+                if t_first is None:      # step 0 holds the compile
+                    t_first = time.perf_counter()
+                else:
+                    blocked_s += wait.dur
+    elapsed = time.perf_counter() - (t_first or 0.0)
+    steps_per_sec = max(cfg.num_steps - 1, 1) / max(elapsed, 1e-9)
+    if cfg.num_steps > 1:
+        # the steady state after step 0, as `train_nerrfnet` counts it
+        loop.gauge_host_blocked(blocked_s, max(elapsed, 1e-9))
     metrics = {"final_loss": history[-1]["loss"]}
     if tokens_per_row:
         metrics["tokens_per_sec"] = (steps_per_sec * min(cfg.batch_size, n)

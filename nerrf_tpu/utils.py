@@ -65,7 +65,15 @@ def enable_compilation_cache() -> None:
     JAX_COMPILATION_CACHE_DIR set JAX reads the variable itself and this
     function touches nothing; NERRF_NO_COMPILE_CACHE=1 is the tests' off
     switch.  Only compiles above jax's default time threshold are
-    persisted, so CPU test runs don't spray sub-second entries onto disk."""
+    persisted, so CPU test runs don't spray sub-second entries onto disk.
+
+    It is also an entry point's first word to the compile layer, so JAX's
+    own compiles are recorded as ``jit_compile`` spans from here on
+    (`compilecache.cache.install_jit_listener`; a `CompileCache` built
+    later finds the listener installed)."""
+    from nerrf_tpu.compilecache.cache import install_jit_listener
+
+    install_jit_listener()
     if os.environ.get("NERRF_NO_COMPILE_CACHE") == "1":
         return
     if os.environ.get("JAX_COMPILATION_CACHE_DIR"):

@@ -359,6 +359,201 @@ def test_seed_dir_adoption(tmp_path):
                                   np.asarray(fn(*_args())))
 
 
+# -- the set-up timeline: stages and JAX's own compiles as spans ---------------
+
+def _resolution_spans(n0):
+    """(the `compile_resolve` recorded since ring position ``n0``, its
+    stage children in the order they ran)."""
+    from nerrf_tpu.tracing import DEFAULT_TRACER
+
+    recs = DEFAULT_TRACER.records()[n0:]
+    (resolve,) = [r for r in recs if r.name == "compile_resolve"]
+    stages = sorted((r for r in recs if r.parent == resolve.id
+                     and r.name.startswith("compile_resolve.")),
+                    key=lambda r: r.t0)
+    return resolve, stages
+
+
+class _NoLower:
+    """A jit function whose AOT path is broken: `lower` raises, calling it
+    still works (the live fallback)."""
+
+    def __init__(self):
+        self._fn = _tiny_jit()
+
+    def lower(self, *a, **k):
+        raise RuntimeError("no AOT here")
+
+    def __call__(self, *a):
+        return self._fn(*a)
+
+
+_FRESH = ["fingerprint", "read", "lower", "compile", "serialize", "persist"]
+
+
+@pytest.mark.parametrize("case, want, source, reason", [
+    ("miss", _FRESH, "fresh", "absent"),
+    ("hit", ["fingerprint", "read", "deserialize"], "cache", None),
+    ("adopted", ["fingerprint", "read", "deserialize"], "cache", None),
+    ("corrupt_payload", ["fingerprint", "read", "deserialize"] + _FRESH[2:],
+     "fresh", "absent"),
+    ("unwritable", _FRESH, "fresh", "unwritable"),
+    ("unserializable", _FRESH[:-1], "fresh", "unserializable"),
+    ("lower_fails", ["fingerprint", "read", "lower"], "live", "lower/compile"),
+    ("fingerprint_fails", ["fingerprint"], "live", "fingerprint"),
+])
+def test_resolution_stages_are_children_of_compile_resolve(
+        tmp_path, monkeypatch, case, want, source, reason):
+    """Every resolution is one `compile_resolve` whose children are the
+    stages it went through, in order, each closed where a fail-open path
+    left it; the miss is recorded with its cause as before."""
+    from nerrf_tpu.compilecache import cache as cc
+    from nerrf_tpu.tracing import DEFAULT_TRACER
+
+    reg = MetricsRegistry(namespace="test")
+    fn = _tiny_jit()
+    kw = dict(registry=reg, journal=EventJournal(registry=reg))
+    # what primes the root counts in a registry of its own
+    if case in ("hit", "corrupt_payload"):
+        _, first = _cache(tmp_path).load_or_compile(
+            fn, _args(), program="tiny")
+        if case == "corrupt_payload":
+            (tmp_path / "aot" / first.fingerprint / PAYLOAD).write_bytes(
+                b"garbage")
+    if case == "adopted":
+        _cache(tmp_path / "sidecar").load_or_compile(
+            fn, _args(), program="tiny")
+        kw["seed_dirs"] = (tmp_path / "sidecar" / "aot",)
+    if case == "unwritable":
+        (tmp_path / "aot").write_text("occupied")
+    if case == "unserializable":
+        from jax.experimental import serialize_executable as se
+
+        def refuse(_compiled):
+            raise ValueError("this backend does not serialize")
+        monkeypatch.setattr(se, "serialize", refuse)
+    if case == "lower_fails":
+        fn = _NoLower()
+    if case == "fingerprint_fails":
+        def broken(*_a, **_k):
+            raise TypeError("no avals")
+        monkeypatch.setattr(cc, "aval_signature", broken)
+
+    n0 = len(DEFAULT_TRACER.records())
+    g, info = _cache(tmp_path, **kw).load_or_compile(fn, _args(),
+                                                     program="tiny")
+    np.testing.assert_array_equal(np.asarray(g(*_args())),
+                                  np.asarray(_tiny_jit()(*_args())))
+    resolve, stages = _resolution_spans(n0)
+    assert [s.name.split(".", 1)[1] for s in stages] == want
+    assert info.source == source
+    assert (info.reason or "").startswith(reason or "")
+    assert resolve.args["program"] == "tiny"
+    assert resolve.args["source"] == source
+    assert resolve.args["reason"] == info.reason
+    # the miss is recorded as before, with its cause
+    assert reg.value("compile_cache_hits_total",
+                     labels={"program": "tiny"}) == (
+        1 if source == "cache" else 0)
+    assert reg.value("compile_cache_misses_total", labels={
+        "program": "tiny", "reason": info.reason or "absent"}) == (
+        0 if source == "cache" else 1)
+    # inside their parent, one after the other
+    lo, hi = resolve.t0, resolve.t0 + resolve.dur
+    for a, b in zip(stages, stages[1:]):
+        assert a.t0 + a.dur <= b.t0
+    assert all(lo <= s.t0 and s.t0 + s.dur <= hi for s in stages)
+    # `CompileInfo.seconds` keeps its meaning: the read's or the compile's
+    by_name = {s.name.split(".", 1)[1]: s for s in stages}
+    if source == "cache":
+        assert info.seconds >= by_name["read"].dur + by_name["deserialize"].dur
+    if source == "fresh":
+        assert info.seconds >= by_name["lower"].dur + by_name["compile"].dur
+        assert info.seconds < resolve.dur
+    if "read" in by_name:
+        read = by_name["read"].args
+        on_disk = case in ("hit", "adopted", "corrupt_payload")
+        assert (read["bytes"] > 0) == on_disk
+        assert read.get("adopted", False) == (case == "adopted")
+    if "persist" in by_name:
+        assert by_name["persist"].args["bytes"] > 0
+
+
+def test_jit_listener_is_installed_once_however_many_caches(tmp_path):
+    from jax._src import monitoring as mon
+
+    from nerrf_tpu.compilecache import cache as cc
+
+    for k in range(3):
+        _cache(tmp_path / str(k))
+    cc.install_jit_listener()
+    mine = [f for f in mon.get_event_duration_listeners()
+            if f is cc._on_jit_duration]
+    assert len(mine) == 1
+
+
+def test_jit_compile_spans_name_the_function_and_loaded_calls_emit_none(
+        tmp_path):
+    """What JAX compiles on its own becomes a `jit_compile` span carrying
+    the function's name and a count in ``jit_compiles_total{stage}``; an
+    event under the floor is counted and not spanned; calling a loaded
+    executable emits nothing at all (no per-step cost)."""
+    import jax.monitoring as mon
+
+    from nerrf_tpu.compilecache import cache as cc
+    from nerrf_tpu.observability import DEFAULT_REGISTRY as reg
+    from nerrf_tpu.tracing import DEFAULT_TRACER
+
+    cache = _cache(tmp_path)          # its construction installs the listener
+    jits = lambda: [r for r in DEFAULT_TRACER.records()       # noqa: E731
+                    if r.name == "jit_compile"]
+    count = lambda stage: reg.value(                          # noqa: E731
+        "jit_compiles_total", labels={"stage": stage}) or 0.0
+
+    n0, c0 = len(jits()), count("backend_compile")
+    # an eager op on a shape nothing else uses: one implicit compile
+    jnp.arctanh(np.full((3, 7, 11), 0.5, np.float32)).block_until_ready()
+    backend = [r for r in jits()[n0:]
+               if r.args["stage"] == "backend_compile"]
+    (mine,) = [r for r in backend if "arctanh" in r.args["fun"]]
+    assert mine.dur >= cc.JIT_SPAN_MIN_SECONDS and mine.parent is None
+    assert count("backend_compile") == c0 + len(backend)
+
+    # the floor: counted, not spanned; other events: neither
+    n1, t0 = len(jits()), count("trace")
+    event = "/jax/core/compile/jaxpr_trace_duration"
+    mon.record_event_duration_secs(event, 1e-5, fun_name="tiny")
+    mon.record_event_duration_secs(event, 0.5, fun_name="slow")
+    mon.record_event_duration_secs("/jax/some/other_duration", 0.5)
+    assert count("trace") == t0 + 2
+    (slow,) = jits()[n1:]
+    assert slow.args == {"stage": "trace", "fun": "slow"} and slow.dur == 0.5
+
+    # inside a resolution the events are the open stage's children
+    n2 = len(DEFAULT_TRACER.records())
+    fn = jax.jit(lambda x: jnp.tanh(x) * 3.0)
+    g, info = cache.load_or_compile(fn, _args(5), program="tiny5")
+    assert info.source == "fresh"
+    resolve, stages = _resolution_spans(n2)
+    stage_ids = {s.id: s.name for s in stages}
+    inside = [r for r in DEFAULT_TRACER.records()[n2:]
+              if r.name == "jit_compile"]
+    assert inside and all(r.parent in stage_ids for r in inside)
+    assert {stage_ids[r.parent] for r in inside
+            if r.args["stage"] == "backend_compile"} == {
+                "compile_resolve.compile"}
+
+    # calls of the loaded executable: no event, no span, no count
+    n3 = len(jits())
+    totals = [count(s) for s in cc.JIT_STAGES.values()]
+    x = jax.device_put(np.arange(5, dtype=np.float32))
+    for _ in range(5):
+        x = g(x)
+    x.block_until_ready()
+    assert len(jits()) == n3
+    assert [count(s) for s in cc.JIT_STAGES.values()] == totals
+
+
 # -- StepCache ----------------------------------------------------------------
 
 def test_seed_adoption_replaces_husk(tmp_path):

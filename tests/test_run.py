@@ -77,3 +77,31 @@ def test_kernel_path_is_a_function_of_backend_and_bucket(monkeypatch, backend,
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     assert _kernel_path(JointConfig(), nodes) == dict(
         zip(("gather_rows", "gnn_aggregation", "lstm_impl"), want))
+
+
+def test_trace_out_writes_the_runs_spans_even_when_the_run_fails(
+        tmp_path, monkeypatch):
+    """`train.run --trace-out FILE` writes the span ring at exit (the
+    set-up timeline of docs/operations.md), whatever the run's end."""
+    from nerrf_tpu import tracing
+    from nerrf_tpu.train import run as train_run
+
+    def fake_run(name, out, *_a, **_k):
+        with tracing.span("train_setup"):
+            if name == "boom":
+                raise RuntimeError("diverged")
+        return {"gates": {"ok": True}}
+
+    monkeypatch.setattr(train_run, "run_experiment", fake_run)
+    path = tmp_path / "t" / "trace.json"
+    argv = ["--out", str(tmp_path), "--no-aot-cache", "--trace-out",
+            str(path)]
+    assert train_run.main(["--experiment", "toy"] + argv) == 0
+    events = tracing.load_chrome_trace(path)
+    assert "train_setup" in {e["name"] for e in events}
+    other = json.loads(path.read_text())["otherData"]
+    assert "process_start_sec" in other
+    path.unlink()
+    with pytest.raises(RuntimeError):
+        train_run.main(["--experiment", "boom"] + argv)
+    assert path.exists()

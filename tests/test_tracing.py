@@ -33,6 +33,98 @@ def test_span_records_and_dual_writes():
     assert not hasattr(tr, "enabled") and not hasattr(tracing, "set_enabled")
 
 
+def test_process_start_against_a_subprocess_own_clock(repo_root):
+    """`Tracer.process_start` is the kernel's record of the process's start
+    on the tracer's clock: negative, before the child's first statement,
+    after the moment the parent spawned it (10 ms resolution), exported in
+    the Chrome trace; jax stays unimported by the package's import."""
+    import subprocess
+    import sys
+
+    code = (
+        "import time; first = time.time()\n"
+        "import sys, json\n"
+        "import nerrf_tpu\n"
+        "from nerrf_tpu.tracing import DEFAULT_TRACER as t\n"
+        "print(json.dumps({'first': first, 'start': t.process_start,\n"
+        "    'epoch': t._t0_epoch, 'jax': 'jax' in sys.modules,\n"
+        "    'other': t.chrome_trace()['otherData']}))\n")
+    spawned = time.time()
+    out = subprocess.run([sys.executable, "-c", code], cwd=repo_root,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+    got = json.loads(out.stdout.strip().splitlines()[-1])
+    assert got["jax"] is False
+    assert got["start"] < 0
+    started = got["epoch"] + got["start"]        # on the wall clock
+    assert spawned - 0.02 <= started <= got["first"] + 0.011
+    assert got["other"]["process_start_sec"] == got["start"]
+    assert got["other"]["epoch_anchor_unix_sec"] == got["epoch"]
+
+
+def test_process_start_is_none_where_the_platform_does_not_say(monkeypatch):
+    def no_proc(*_a, **_k):
+        raise FileNotFoundError("/proc/self/stat")
+
+    monkeypatch.setattr(tracing, "open", no_proc, raising=False)
+    tr = tracing.Tracer(registry=MetricsRegistry())
+    assert tr.process_start is None                 # never a guess
+    assert tr.chrome_trace()["otherData"]["process_start_sec"] is None
+
+
+def test_record_appends_an_ended_span_with_parent_id_and_dual_write():
+    """`record` is `span` for an event that is already over: it ended now,
+    lasted ``dur``, its parent is the innermost span open on the thread."""
+    reg = MetricsRegistry(namespace="t")
+    tr = tracing.Tracer(registry=reg)
+    with tr.span("compile_resolve") as outer:
+        with tr.span("compile_resolve.lower") as stage:
+            time.sleep(0.003)
+            before = time.perf_counter() - tr._t0_perf
+            got = tr.record("jit_compile", 0.002, stage="trace", fun="f")
+            after = time.perf_counter() - tr._t0_perf
+    top = tr.record("jit_compile", 0.5, device=True, stage="lower", fun="g")
+    assert got.parent == stage.id and stage.parent == outer.id
+    assert top.parent is None
+    assert len({outer.id, stage.id, got.id, top.id}) == 4
+    assert got.dur == 0.002 and before <= got.t0 + got.dur <= after
+    assert stage.t0 <= got.t0 and got.t0 + got.dur <= stage.t0 + stage.dur
+    assert got.args == {"stage": "trace", "fun": "f"}
+    # recorded in the order things ended, like every span
+    assert [r.name for r in tr.records()] == [
+        "jit_compile", "compile_resolve.lower", "compile_resolve",
+        "jit_compile"]
+    assert reg.value(tracing.STAGE_HISTOGRAM, labels={"stage": "jit_compile"},
+                     stat="count") == 2
+    assert reg.value(tracing.STAGE_HISTOGRAM, labels={"stage": "jit_compile"},
+                     stat="sum") == pytest.approx(0.502)
+    events = [e for e in tr.chrome_trace()["traceEvents"] if e["ph"] == "X"]
+    assert {e["id"]: e["parent"] for e in events}[got.id] == stage.id
+    # a negative duration (a clock that stepped) is no span of negative length
+    assert tr.record("jit_compile", -1.0).dur == 0.0
+
+
+def test_the_naming_scheme_lists_every_span_the_package_opens(repo_root):
+    """`tracing.py`'s docstring is the scheme of record: a span opened
+    anywhere in the package (``span("...")``, a `compile_resolve` stage,
+    a ``record("...")`` of the tracer) has its line there."""
+    import re
+
+    scheme = tracing.__doc__
+    pkg = repo_root / "nerrf_tpu"
+    opened = {"jit_compile", "module_import"}          # the `record`ed ones
+    for path in pkg.rglob("*.py"):
+        text = path.read_text()
+        opened |= set(re.findall(r'span\(\s*"([a-z_]+)"', text))
+        opened |= {f"compile_resolve.{stage}" for stage in
+                   re.findall(r'\b_stage\("([a-z]+)"', text)}
+    assert {"train_step_call", "compile_resolve.deserialize", "trace_lower",
+            "step_build", "serve_admit"} <= opened
+    missing = sorted(n for n in opened if not re.search(
+        rf"(?<![a-z_.]){re.escape(n)}(?![a-z_.])", scheme))
+    assert not missing, missing
+
+
 def test_spans_carry_id_and_parent_per_thread():
     """``parent`` is the innermost span open on the SAME thread: a span on
     another thread started while this thread's span is open is a root."""
@@ -294,6 +386,71 @@ def test_train_loop_emits_covering_trace(tmp_path, monkeypatch):
     assert DEFAULT_REGISTRY.value("train_data_wait_fraction") == 0.0
     assert 'nerrf_train_padding_waste_fraction{bucket="64n/128e",kind="node"}' \
         in text
+
+
+def test_train_stream_emits_covering_trace(tmp_path):
+    """The stream loop's twin of `test_train_loop_emits_covering_trace`: a
+    toy `train_stream` run's spans cover >= 95 % of its wall clock, with
+    `train_setup` around the state and the step functions, `step_build`
+    and its `dataset_upload`, `train_loop` around the calls, and
+    `train_step_wait` around the floats the loop does anyway."""
+    import numpy as np
+
+    import jax.numpy as jnp
+    from nerrf_tpu.data.stream import STREAM_FEATURE_DIM
+    from nerrf_tpu.models.stream import StreamConfig
+    from nerrf_tpu.observability import DEFAULT_REGISTRY
+    from nerrf_tpu.tracing import DEFAULT_TRACER
+    from nerrf_tpu.train.loop import TrainConfig
+    from nerrf_tpu.train.stream import train_stream
+
+    r = np.random.default_rng(0)
+    arrays = {"feat": r.normal(size=(8, 32, STREAM_FEATURE_DIM)
+                               ).astype(np.float32),
+              "mask": np.ones((8, 32), bool),
+              "label": (r.random((8, 32)) < 0.3).astype(np.float32)}
+    scfg = StreamConfig(dim=32, num_layers=2, mlp_mult=2, dropout=0.0,
+                        dtype=jnp.float32)
+    DEFAULT_TRACER.clear()
+    t0 = time.perf_counter()
+    res = train_stream(arrays, scfg, TrainConfig(
+        batch_size=4, num_steps=20, eval_every=10, warmup_steps=2),
+        log=lambda _: None)
+    wall_us = (time.perf_counter() - t0) * 1e6
+    assert res.steps_per_sec > 0 and len(res.history) == 3
+
+    path = DEFAULT_TRACER.write(tmp_path / "stream_trace.json")
+    events = tracing.load_chrome_trace(path)
+    by_name = {}
+    for e in events:
+        by_name.setdefault(e["name"], []).append(e)
+    assert {"train_setup", "step_build", "dataset_upload", "train_loop",
+            "train_step_call", "train_step_wait"} <= set(by_name)
+    setups = by_name["train_setup"]
+    assert [e.get("args", {}).get("phase") for e in setups] == [
+        None, "step_fns"]
+    (build,), (upload,) = by_name["step_build"], by_name["dataset_upload"]
+    assert build["parent"] == setups[1]["id"]
+    assert upload["parent"] == build["id"]
+    assert upload["args"]["bytes"] == sum(v.nbytes for v in arrays.values())
+    (loop_ev,) = by_name["train_loop"]
+    calls, waits = by_name["train_step_call"], by_name["train_step_wait"]
+    assert [e["args"]["call"] for e in calls] == list(range(20))
+    # step 0, every eval_every, the last: the floats the loop already did
+    assert [e["args"]["step"] for e in waits] == [0, 9, 19]
+    assert all(e["parent"] == loop_ev["id"] for e in calls + waits)
+    # no span was opened inside a step call but its own children
+    inside = [e for e in events if e["parent"] in {c["id"] for c in calls}]
+    assert {e["name"] for e in inside} <= {"train_step_execute",
+                                           "compile_resolve", "jit_compile"}
+    assert tracing.coverage(events) >= 0.95, tracing.format_stage_table(events)
+    # ... and of the call's own wall clock, not only of the spans' extent
+    assert tracing.wall_clock_us(events) >= 0.95 * wall_us
+    leaf_cov = tracing.coverage(
+        calls + waits, lo_us=loop_ev["ts"],
+        hi_us=loop_ev["ts"] + loop_ev["dur"])
+    assert leaf_cov >= 0.9, tracing.format_stage_table(events)
+    assert 0.0 <= DEFAULT_REGISTRY.value("train_host_blocked_fraction") <= 1.0
 
 
 def _toy_cached_step(tmp_path):
