@@ -133,17 +133,27 @@ def stream_kernel_path(scfg: StreamConfig,
                        seq_len: Optional[int] = None) -> dict:
     """Which of an op's routes a step over ``seq_len``-token sequences
     traces on this backend, for the ops of ``scfg``'s stack that have two:
-    the latent attention core (`ops/mla.py::attention_route`) where the
-    stack or its multi-token-prediction module has a latent layer."""
-    if not (set(scfg.stack) & set(LATENT_KINDS) or scfg.mtp_layers):
+    the chosen-set attention (`ops/dsa.py::attention_route`) where the stack
+    has a ``dsa_moe`` layer, the latent attention core
+    (`ops/mla.py::attention_route`) where the stack or its
+    multi-token-prediction module has a latent layer."""
+    sparse = "dsa_moe" in scfg.stack
+    latent = bool(set(scfg.stack) & set(LATENT_KINDS) or scfg.mtp_layers)
+    if not (sparse or latent):
         return {}
     if seq_len is None:
-        raise ValueError("a latent layer's route depends on the sequence "
+        raise ValueError("an attention core's route depends on the sequence "
                          "length: pass seq_len")
-    from nerrf_tpu.ops import mla
+    from nerrf_tpu.ops import dsa, mla
 
-    return {"mla_attention": mla.attention_route(
-        seq_len, scfg.qk_nope_dim + scfg.qk_rope_dim, scfg.v_head_dim)}
+    path = {}
+    if sparse:
+        path["dsa_attention"] = dsa.attention_route(
+            seq_len, scfg.num_heads, scfg.num_kv_heads, scfg.head_dim)
+    if latent:
+        path["mla_attention"] = mla.attention_route(
+            seq_len, scfg.qk_nope_dim + scfg.qk_rope_dim, scfg.v_head_dim)
+    return path
 
 
 def stream_key_extra(scfg: StreamConfig,

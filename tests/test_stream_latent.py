@@ -319,7 +319,7 @@ def test_a_remat_that_keeps_the_residuals_runs_the_forward_kernel_once(
 def test_the_route_rides_a_latent_steps_key_and_no_other(monkeypatch):
     """`stream_key_extra` of a latent configuration differs between a
     backend that traces the fused route and one that traces the XLA route;
-    the two other stream stacks' key material is what it was."""
+    the two other stream stacks' key material carries no latent route."""
     from nerrf_tpu.train.stream import stream_kernel_path, stream_key_extra
 
     wide = dataclasses.replace(TOY, qk_nope_dim=64, qk_rope_dim=64,
@@ -328,9 +328,11 @@ def test_the_route_rides_a_latent_steps_key_and_no_other(monkeypatch):
     for backend in ("cpu", "tpu"):
         monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
         keys[backend] = stream_key_extra(wide, 8192)
-        for other in (KEYE, PHI4):
-            assert stream_key_extra(other) == {"stream_cfg": repr(other)}
-            assert stream_key_extra(other, 8192) == stream_key_extra(other)
+        assert stream_key_extra(PHI4) == {"stream_cfg": repr(PHI4)}
+        assert stream_key_extra(PHI4, 8192) == stream_key_extra(PHI4)
+        # the chosen-set stack's key names ITS core's route, not this one's
+        assert stream_key_extra(KEYE, 8192) == {
+            "stream_cfg": repr(KEYE), "dsa_attention": "xla_blocked"}
         # the toy's widths take the XLA route on any backend
         assert stream_kernel_path(TOY, 8192) == {
             "mla_attention": "xla_blocked"}

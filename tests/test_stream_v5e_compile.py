@@ -88,6 +88,39 @@ def test_sparse_attention_runs_its_blocks_one_after_another(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 5 << 30
 
 
+def test_sparse_attention_fused_route_compiles_at_the_published_widths(
+        one_chip, monkeypatch):
+    """The same op on the route a TPU traces (`dsa.attention_route` ->
+    ``pallas_flash``), under `vmap` as the layer calls it, forward +
+    backward: the chip's compiler accepts the forward kernel at each of the
+    four key lengths (every head of a block of 256 queries resident, the
+    block's position a prefetched scalar that `vmap` leaves alone) and the
+    one backward kernel (a key-value head's ``dk``, ``dv`` [8192, 128]
+    float32 in its fast memory), and no [heads, 256, L] score reaches HBM:
+    the plan of a batch of two is the indexer's blocks (0.78 GB; 1.74 GB on
+    the XLA route), not the attention's."""
+    from nerrf_tpu.ops import dsa
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert dsa.attention_route(T, 32, 4, 128) == "pallas_flash"
+
+    def loss(q, k, v, qi, ki, wi, seg):
+        o, kl, _ = jax.vmap(lambda *a: dsa.sparse_attention(*a, topk=2048))(
+            q, k, v, qi, ki, wi, seg)
+        return jnp.sum(o.astype(jnp.float32) ** 2) + jnp.sum(kl)
+
+    kv = shape(one_chip, (2, T, 4, 128), jnp.bfloat16)
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4, 5))).lower(
+        shape(one_chip, (2, T, 32, 128), jnp.bfloat16), kv, kv,
+        shape(one_chip, (2, T, 16, 64)), shape(one_chip, (2, T, 64)),
+        shape(one_chip, (2, T, 16)),
+        shape(one_chip, (2, T), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 5
+    assert "dsa_flash_fwd" in text and "dsa_flash_bwd" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
 def test_latent_attention_runs_its_blocks_one_after_another(one_chip):
     """`ops/mla.py::attention` at 20 heads with 256-wide assembled keys and
     256-wide values, the one rotary key broadcast by `assemble`, forward +
