@@ -25,8 +25,8 @@ wrong calls.
 Span -> metric: `train_step_call` -> ``step_call_ms.train`` and, less its
 children (`train_step_execute`, a `compile_resolve` if one happens),
 ``step_call_self_ms.train``; `compile_resolve` ->
-``step_resolves_in_window.train`` and ``setup_resolve_s.train``; `corpus_simulate`, `graph_lower`,
-`dataset_upload` -> ``setup_data_s.train``.
+``step_resolves_in_window.train``.  Set-up's spans are read by
+`chipbench/setup_timeline.py`.
 """
 
 from __future__ import annotations
@@ -35,7 +35,6 @@ from chipbench.trace_reduce import union_length
 
 STEP_CALL = "train_step_call"
 RESOLVE = "compile_resolve"
-DATA_SPANS = ("corpus_simulate", "graph_lower", "dataset_upload")
 WARMUP_CALLS = 3
 
 
@@ -110,17 +109,3 @@ def resolves_in_window(run: dict):
         return None
     return float(sum(1 for s in parts["after"] if s.name == RESOLVE))
 
-
-def setup_resolve_s(run: dict):
-    parts = of_run(run)
-    if parts is None:
-        return None
-    return float(sum(s.dur for s in parts["setup"] if s.name == RESOLVE))
-
-
-def setup_data_s(run: dict):
-    parts = of_run(run)
-    if parts is None:
-        return None
-    return float(union_length((s.t0, s.t0 + s.dur) for s in parts["setup"]
-                              if s.name in DATA_SPANS))

@@ -3,9 +3,8 @@
 One family of five readers (`layer_metrics/setup_timeline_<part>_s.train.py`;
 all would move ``setup_s``), read in the program's process after the window
 like `chipbench/program_spans.py`'s (whose `split_run` says which spans of the
-ring are set-up's).  `BENCHMARK.json` does not list them yet (an appended
-entry fails an accepted test: `PERF.md` section 7); until a benchmark PR does,
-`chipbench/probes/setup_timeline.py` prints them.  Set-up's extent runs
+ring are set-up's); `chipbench/probes/setup_timeline.py` prints the same
+set-up span by span.  Set-up's extent runs
 from the process's start (`Tracer.process_start`: the kernel's record of it,
 on the tracer's clock) to the start of the window's first `train_step_call`:
 

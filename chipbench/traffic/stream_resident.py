@@ -387,7 +387,6 @@ def run(ctx) -> dict:
             "window_s": rec["elapsed"], "windows_per_s": rate,
             "dispatch_s": rec["dispatch"],
             "compiles_in_window": rec["compiles_in_window"],
-            "setup_compile_s": compile_log.seconds(0.0, rec["window_start"]),
             "memory_peak_bytes": rec["memory_peak_bytes"],
             "train_flops_per_window": work.train_flops(config, packing),
             "train_work_per_window": work.train_work(config, packing),

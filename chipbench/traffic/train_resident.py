@@ -46,33 +46,26 @@ import numpy as np
 from chipbench import compare, datagen
 
 WARMUP_STEPS = 3          # the steps the reference follows
-_STAGES = {"/jax/core/compile/jaxpr_trace_duration": "trace",
-           "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
-           "/jax/core/compile/backend_compile_duration": "backend"}
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 
 
 class CompileLog:
-    """JAX's own compile-stage events with the time each ended."""
+    """When each of JAX's own backend compilations ended."""
 
     def __init__(self) -> None:
-        self.events = []   # (perf_counter at end, stage, seconds)
+        self.ends = []   # perf_counter at the end of each
 
     def listen(self) -> None:
         import jax.monitoring as mon
 
         def on_duration(event, secs, **_):
-            stage = _STAGES.get(event)
-            if stage is not None:
-                self.events.append((time.perf_counter(), stage, secs))
+            if event == _BACKEND_COMPILE:
+                self.ends.append(time.perf_counter())
 
         mon.register_event_duration_secs_listener(on_duration)
 
-    def seconds(self, lo: float, hi: float) -> float:
-        return sum(s for t, _, s in self.events if lo <= t < hi)
-
     def backend_compiles(self, lo: float, hi: float) -> int:
-        return sum(1 for t, stage, _ in self.events
-                   if stage == "backend" and lo <= t < hi)
+        return sum(1 for t in self.ends if lo <= t < hi)
 
 
 def seed_words(seed: int):
@@ -382,7 +375,6 @@ def run(ctx) -> dict:
             "window_s": rec["elapsed"], "windows_per_s": rate,
             "dispatch_s": rec["dispatch"],
             "compiles_in_window": rec["compiles_in_window"],
-            "setup_compile_s": compile_log.seconds(0.0, rec["window_start"]),
             "memory_peak_bytes": rec["memory_peak_bytes"],
             "train_flops_per_window": work.train_flops(config),
             "train_work_per_window": work.train_work(config),

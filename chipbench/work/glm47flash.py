@@ -28,14 +28,18 @@ not count.  A "block" is a stack layer or the MTP module's layer:
 Training is 3 x forward throughout.  Required bytes are the least a kernel
 has to move if only its inputs and outputs ever left the chip, in the
 compute type, once a pass: attention reads ``Q, K, V`` and writes ``O``; the
-projections and the shared expert read and write their rows and read their
-matrices.  FLOPs bound all three rooflines.
+projections, the shared expert and the held experts read and write their
+rows and read their matrices; the head reads its rows and its matrix.  FLOPs
+bound all five rooflines.
 
-The three rooflines count the STACK's layers only, as their scopes do: in a
+The five rooflines count the STACK's layers only, as their scopes do: in a
 trace the MTP module's layer is one group (``mtp_block``, told apart before
-its inner scopes) beside ``mtp_embed_proj`` and ``mtp_head``, so that
+its inner scopes, its experts' walk among them) beside ``mtp_embed_proj`` and
+``mtp_head`` (its head pass, scope ``mtp_head_loss``), so that
 `mtp_step_share.train` can read the module's whole time; its required work
-is in `train_flops` under ``mtp`` and in the step's total.
+is in `train_flops` under ``mtp`` and in the step's total.  So ``lm_head``
+(scope ``lm_head_loss``) is the next-token pass alone, one pass of the head,
+and ``moe_experts`` the stack's routed layers' walks.
 
 `packing_of(segments)` counts, over the resident sequences, the real tokens
 and the attending pairs a sequence has on average: every seed trains the
@@ -60,7 +64,9 @@ SCOPE_GROUPS = [["mtp_embed_proj", ["mtp_embed_proj"]],
                 ["optimizer", ["optimizer_update"]]]
 ROOFLINES = {"mla_attention": ["mla_attention"],
              "mla_latent": ["mla_latent"],
-             "moe_shared": ["moe_shared"]}
+             "moe_shared": ["moe_shared"],
+             "moe_experts": ["moe_experts"],
+             "lm_head": ["lm_head"]}
 MTP_GROUPS = ("mtp_embed_proj", "mtp_block", "mtp_head")
 _BYTES = {"bfloat16": 2, "float16": 2, "float32": 4}
 
@@ -185,6 +191,10 @@ def train_bytes(config: dict, packing: dict) -> dict:
             + latent_params(d)),
         "moe_shared": 3 * d["routed"] * a * (2 * t * d["H"]
                                              + 3 * d["H"] * d["S"]),
+        "moe_experts": 3 * d["routed"] * a * (
+            2 * assignments_of(d, packing) * d["H"]
+            + d["held"] * 3 * d["H"] * d["F"]),
+        "lm_head": 3 * a * (t * d["H"] + d["V"] * d["H"]),
     }
 
 

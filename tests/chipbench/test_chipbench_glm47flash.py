@@ -26,6 +26,9 @@ CELL = "stream-lm-8k-mla-longdoc"
 NAME = "glm-4.7-flash"
 NEW_METRICS = ["mla_attention_roofline.train", "mla_latent_roofline.train",
                "moe_shared_roofline.train", "mtp_step_share.train"]
+# metrics of another cell's that list this one too (PR 39)
+LISTED_METRICS = ["moe_experts_roofline.train", "moe_dispatch_ms.train",
+                  "moe_load_imbalance.train", "lm_head_roofline.train"]
 
 # https://huggingface.co/zai-org/GLM-4.7-Flash/blob/main/config.json as the
 # architectures' catalog holds it
@@ -256,6 +259,15 @@ def test_required_work_by_hand(full):
     assert moved["moe_shared"]["bytes"] == 3 * 4 * 2 * (
         2 * 8192 * 2048 + 3 * 2048 * 1536)
     assert moved["mla_latent"]["groups"] == ["mla_latent"]
+    # the stack's four routed walks at the even split (8192 x 4 x 8 / 64)
+    assert moved["moe_experts"]["flops"] == flops["moe_experts"] == \
+        3 * 4 * 4096 * 3 * 2 * 2048 * 1536
+    assert moved["moe_experts"]["bytes"] == 3 * 4 * 2 * (
+        2 * 4096 * 2048 + 8 * 3 * 2048 * 1536)
+    # ONE pass of the head: the next-token pass under ``lm_head_loss``; the
+    # MTP module's pass is under ``mtp_head_loss``, in ``mtp``
+    assert moved["lm_head"]["flops"] == 3 * 8192 * 2 * 2048 * 19360
+    assert moved["lm_head"]["bytes"] == 3 * 2 * (8192 + 19360) * 2048
     from chipbench import roofline
 
     peaks = json.loads((ROOT / "chipbench/peaks.json").read_text())
@@ -278,6 +290,16 @@ def test_packing_counts_pairs_and_scopes_are_told_apart():
     assert max(groups.index(g) for g in groups if g.startswith(
         ("moe_", "mla_", "dense_"))) < groups.index("stream_layer")
     assert set(sum(work.ROOFLINES.values(), [])) <= set(groups)
+    # the head's two passes fall in two groups: the next token's alone in
+    # ``lm_head``, which its roofline's one pass of required work holds
+    from chipbench.trace_reduce import group_of
+
+    assert group_of(["stream/lm_head_loss/dot"], work.SCOPE_GROUPS) == \
+        "lm_head"
+    assert group_of(["stream/mtp_head_loss/dot"], work.SCOPE_GROUPS) == \
+        "mtp_head"
+    assert group_of(["stream/mtp_block/moe_experts/dot"],
+                    work.SCOPE_GROUPS) == "mtp_block"
     # the names both expert models share
     from chipbench.work import keyevl2
 
@@ -653,24 +675,24 @@ def test_command_line_prints_the_contracts_last_line(toy, capsys, trace):
     if not trace:
         assert set(res["metrics"]) == {m["name"] for m in bench["end_to_end"]}
         return
-    for m in bench["per_layer"]:
-        if m["name"] in NEW_METRICS:
-            assert m["workloads"] == [CELL]
-            assert m["moves"] == "train_windows_per_s"
-    assert [m["name"] for m in bench["per_layer"]][-4:] == NEW_METRICS
-    for name in NEW_METRICS:
+    # this cell's entries, by name: others may be appended, and may list it
+    by_name = {m["name"]: m for m in bench["per_layer"]}
+    for name in NEW_METRICS + LISTED_METRICS:
+        assert CELL in by_name[name]["workloads"], name
+        assert by_name[name]["moves"] == "train_windows_per_s"
         assert res["metrics"][name]["value"] > 0, name
     assert res["metrics"]["mtp_step_share.train"]["value"] < 100
-    # the readers that have no list of cells read here too
+    assert res["metrics"]["moe_load_imbalance.train"]["value"] >= 1.0
+    # the readers that list every cell, or none, read here too
     # (a CPU trace has no step executions to time and no allocator peak)
     for name in ("step_mfu.train", "host_dispatch_ms.train",
-                 "device_idle_share.train", "setup_compile_s.train"):
+                 "device_idle_share.train"):
         assert res["metrics"][name]["value"] > 0, name
+    assert res["metrics"]["setup_timeline_jit_s.train"]["value"] >= 0
     assert res["metrics"]["compiles_in_window.train"]["value"] == 0
-    # none of the other cells' own metrics is read here
-    assert not {"lstm_roofline.train", "ssm_scan_roofline.train",
-                "moe_experts_roofline.train", "dsa_topk_ms.train",
-                "lm_head_roofline.train"} & set(res["metrics"])
+    # no metric whose list leaves this cell out is read here
+    assert not {m["name"] for m in bench["per_layer"]
+                if CELL not in m.get("workloads", [CELL])} & set(res["metrics"])
     scope_s = extras["scope_s"]
     for g in ("mtp_embed_proj", "mtp_block", "mtp_head", "moe_router",
               "moe_dispatch", "moe_experts", "moe_combine", "moe_shared",

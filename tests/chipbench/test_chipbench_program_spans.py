@@ -15,8 +15,7 @@ from chipbench import rehearse, run
 from chipbench.work import nerrfnet_aggregate
 
 SPAN_METRICS = ("step_call_ms.train", "step_call_self_ms.train",
-                "step_resolves_in_window.train", "setup_resolve_s.train",
-                "setup_data_s.train")
+                "step_resolves_in_window.train")
 
 
 class Ring:
@@ -127,8 +126,6 @@ def test_each_reader_on_a_synthetic_run(monkeypatch):
         "step_call_ms.train": (5 * 8.0 + 108.0) / 6,
         "step_call_self_ms.train": 2.0,       # every call: 2 ms of its own
         "step_resolves_in_window.train": 1.0,
-        "setup_resolve_s.train": 1.5,
-        "setup_data_s.train": 4.0 + 0.5,      # [0, 4] overlapping, [5, 5.5]
     }
     for name, value in want.items():
         assert run.read_metric(name, run_) == pytest.approx(value), name
@@ -210,7 +207,9 @@ def test_rehearsal_prints_the_span_metrics_in_the_traced_run_alone(
     assert 0 < got["step_call_self_ms.train"] < got["step_call_ms.train"]
     # the program's clock inside the benchmark's: the same calls
     assert got["step_call_ms.train"] <= got["host_dispatch_ms.train"]
+    # set-up's spans, as the timeline reads them
     setup_s = res["extras"]["end_to_end"]["setup_s"]
-    assert 0 < got["setup_resolve_s.train"] < setup_s
-    assert 0 < got["setup_data_s.train"] < setup_s
-    assert got["setup_resolve_s.train"] + got["setup_data_s.train"] < setup_s
+    data, resolve = (got[f"setup_timeline_{part}_s.train"]
+                     for part in ("data", "resolve"))
+    assert 0 < data < setup_s and 0 < resolve < setup_s
+    assert data + resolve < setup_s

@@ -1,9 +1,10 @@
 """The set-up timeline's readers (`chipbench/setup_timeline.py`): each of the
 five on hand-built rings (overlaps counted once, what a `compile_resolve`
 holds kept out of ``jit``, None on a parent's ring, None after an earlier run
-in the process), the identity ``preprogram + spanned + unspanned = extent``
-on a real rehearsal run in a process of its own, the five readers after the
-rehearsal runs of all five cells, and the probe's report."""
+in the process), what the three readers they replaced read, each one's entry
+in `BENCHMARK.json` by name, the identity ``preprogram + spanned + unspanned
+= extent`` on a real rehearsal run in a process of its own, the five in the
+traced rehearsal line of all five cells, and the probe's report."""
 
 import copy
 import importlib
@@ -17,6 +18,7 @@ import pytest
 from chipbench import program_spans as ps
 from chipbench import rehearse, run
 from chipbench import setup_timeline as st
+from chipbench.trace_reduce import union_length
 
 FAMILY = tuple(f"setup_timeline_{part}_s.train" for part in
                ("preprogram", "data", "resolve", "jit", "unspanned"))
@@ -120,7 +122,7 @@ def test_a_parents_ring_gives_none_five_times(monkeypatch):
     monkeypatch.setattr(st, "program_start", lambda: (False, None))
     assert [run.read_metric(name, run_) for name in FAMILY] == [None] * 5
     # PR 26's readers still read that ring
-    assert run.read_metric("setup_resolve_s.train", run_) == 3.5
+    assert run.read_metric("step_call_ms.train", run_) == pytest.approx(8.0)
     # a program with the timeline but a ring without a single jit_compile
     # (no listener ever fired): ``jit`` alone is silent
     monkeypatch.setattr(st, "program_start", lambda: (True, -7.5))
@@ -156,16 +158,54 @@ def test_after_an_earlier_run_in_the_process(monkeypatch):
     assert got["setup_timeline_jit_s.train"] == pytest.approx(JIT + 30.0)
 
 
-def test_benchmark_json_waits_for_a_benchmark_pr():
-    """The five readers are files without entries: an entry appended to
-    ``per_layer`` fails `test_chipbench_glm47flash.py`, which holds the
-    list's last four names to PR 35's, and that file is not this PR's to
-    edit (`PERF.md` section 7).  Whoever adds the entries deletes this
-    test."""
+def _setup_and_timeline():
+    ring = Ring()
+    one_run(ring, 0.0, steps=5)
+    return (ps.split_run(ring.spans, 5)["setup"],
+            st.timeline(ring.spans, 5, process_start=-7.5))
+
+
+def test_resolve_is_what_setup_resolve_s_summed():
+    """PR 26's retired `setup_resolve_s.train` summed set-up's
+    `compile_resolve` spans; they do not overlap, so the union is the sum."""
+    setup, got = _setup_and_timeline()
+    assert got["resolve"] == pytest.approx(
+        sum(s.dur for s in setup if s.name == ps.RESOLVE))
+
+
+def test_data_holds_what_setup_data_s_read():
+    """PR 26's retired `setup_data_s.train` was the union of
+    `corpus_simulate`, `graph_lower` and `dataset_upload`; ``data`` holds
+    it and `trace_lower` past its `graph_lower`, [3.5, 5.5]."""
+    setup, got = _setup_and_timeline()
+    old = union_length((s.t0, s.t0 + s.dur) for s in setup if s.name in (
+        "corpus_simulate", "graph_lower", "dataset_upload"))
+    assert old == pytest.approx(2.0 + 0.5 + 0.3)
+    assert got["data"] == pytest.approx(old + 2.0) == pytest.approx(DATA)
+
+
+CELLS = ("train-1024", "train-4096", "stream-lm-8k-packed",
+         "stream-lm-8k-longdoc", "stream-lm-8k-mla-longdoc")
+# the layer each part of set-up is told under in `PERF.md` section 3
+LAYERS = {"preprogram": "train loop", "data": "train loop",
+          "resolve": "compile and caches", "jit": "compile and caches",
+          "unspanned": "train loop"}
+
+
+@pytest.mark.parametrize("part", list(LAYERS))
+def test_each_part_has_its_entry_its_reader_and_its_cells(part):
+    """Each of the five by name, wherever in ``per_layer`` it stands: its
+    reader, the fields `ROADMAP.md` Queue 1 item 14 gives, and a list that
+    holds the five cells of PR 39 (a later cell may append itself)."""
     bench = json.loads((run.ROOT / "BENCHMARK.json").read_text())
-    assert not set(FAMILY) & {m["name"] for m in bench["per_layer"]}
-    for name in FAMILY:
-        assert (run.HERE / "layer_metrics" / f"{name}.py").is_file()
+    name = f"setup_timeline_{part}_s.train"
+    entry = next(m for m in bench["per_layer"] if m["name"] == name)
+    assert (run.HERE / "layer_metrics" / f"{name}.py").is_file()
+    assert {k: entry[k] for k in ("unit", "better", "source", "moves")} == {
+        "unit": "s", "better": "lower", "source": "program_counter",
+        "moves": "setup_s"}
+    assert entry["layer"] == LAYERS[part]
+    assert set(CELLS) <= set(entry["workloads"])
 
 
 # --- real rehearsal runs -------------------------------------------------------
@@ -227,9 +267,6 @@ def test_the_identity_holds_on_a_real_run_in_its_own_process(fresh):
     # the benchmark's own clock starts at its first line, a little after
     # the process: the extent is `setup_s` and the interpreter's start
     assert 0 <= extent - fresh["setup_s"] < 2.0
-    # the old readers' spans are among the new ones'
-    assert parts["data"] >= got["setup_data_s.train"]
-    assert parts["resolve"] == pytest.approx(got["setup_resolve_s.train"])
     assert {"corpus_simulate", "trace_lower", "graph_lower", "step_build",
             "dataset_upload", "compile_resolve", "compile_resolve.lower",
             "compile_resolve.compile", "compile_resolve.persist",
@@ -282,10 +319,12 @@ def test_every_cells_traced_rehearsal_prints_the_family(
     assert rc == 0
     res = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert res["correct"] is True
-    # the readers, as `run.py` would call them once `BENCHMARK.json` lists
-    # them: in the program's process, after the window
+    # the readers, as `run.py` calls them: in the program's process, after
+    # the window; where a reader reads, the line has its number
     run_ = {"counters": {"steps": res["attempted"]}}
     got = {name: run.read_metric(name, run_) for name in FAMILY}
+    assert {k: v["value"] for k, v in res["metrics"].items()
+            if k in FAMILY} == {k: v for k, v in got.items() if v is not None}
     setup_s = res["extras"]["end_to_end"]["setup_s"]
     # the three that an earlier run in this process cannot silence
     assert 0 < got["setup_timeline_data_s.train"] < setup_s
@@ -293,8 +332,10 @@ def test_every_cells_traced_rehearsal_prints_the_family(
     # (after an earlier run in the process its reference's compiles are
     # in ``jit``: the rule "after that run's last call")
     assert 0 <= got["setup_timeline_jit_s.train"] < setup_s
-    assert got["setup_timeline_resolve_s.train"] == pytest.approx(
-        ps.setup_resolve_s(run_))
+    # the summed `compile_resolve` spans of set-up (PR 26's retired rule)
+    split = ps.split_run(ps.program_ring(), res["attempted"])
+    assert got["setup_timeline_resolve_s.train"] == pytest.approx(sum(
+        s.dur for s in split["setup"] if s.name == ps.RESOLVE))
     for name in ("setup_timeline_preprogram_s.train",
                  "setup_timeline_unspanned_s.train"):
         assert got[name] is None or 0 <= got[name]
