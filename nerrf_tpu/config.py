@@ -39,7 +39,7 @@ from nerrf_tpu.graph.builder import GraphConfig
 from nerrf_tpu.models.graphsage import GraphSAGEConfig
 from nerrf_tpu.models.joint import JointConfig
 from nerrf_tpu.models.lstm import LSTMConfig
-from nerrf_tpu.models.stream import StreamConfig, layer_kinds
+from nerrf_tpu.models.stream import Rotary, StreamConfig, layer_kinds
 from nerrf_tpu.parallel.mesh import MeshConfig
 from nerrf_tpu.planner.mcts import MCTSConfig
 from nerrf_tpu.train.data import DatasetConfig
@@ -381,8 +381,44 @@ def _experiments() -> Dict[str, Experiment]:
         stream_data=PackConfig(doc_median=16384.0, doc_sigma=0.5,
                                doc_min=2048),
     )
+    stream_gqa = Experiment(
+        name="stream-laguna-s-2.1",
+        description=(
+            "Stream-encoder pretraining on packed 8k event-token sequences: "
+            "Laguna-S-2.1's decoder (grouped-query attention with a sigmoid "
+            "gate a head, three rotary window layers of 72 query heads to "
+            "one YaRN full-attention layer of 48, a leading dense layer, "
+            "then 256 softmax-routed experts, 10 a token, beside a shared "
+            "one) at its published widths, the dense layer and one period "
+            "of four, experts 0-7 of 256 and an eighth of both vocabulary "
+            "matrices: what one chip of a 32-chip expert-parallel slice "
+            "holds (docs/stream-backbone.md; "
+            "chipbench/configs/laguna-s-2.1.json)"
+        ),
+        corpus=CorpusConfig(num_traces=4, attack_fraction=0.5,
+                            duration_sec=180.0, num_target_files=45,
+                            benign_rate_hz=550.0, eval_fraction=0.0),
+        # the long warm-up of the other routed stacks, for their reason
+        train=TrainConfig(batch_size=1, num_steps=20000, learning_rate=3e-4,
+                          warmup_steps=2000, weight_decay=0.1, eval_every=20),
+        stream=StreamConfig(
+            dim=3072, num_heads=48, num_kv_heads=8, head_dim=128,
+            window_heads=72, window=512, num_layers=5,
+            kinds=("gqa_full_dense",) + ("gqa_swa_moe",) * 3
+            + ("gqa_full_moe",), vocab_size=12544, dropout=0.0,
+            mlp_dim=12288, num_experts=256, experts_per_token=10,
+            expert_dim=1024, first_expert=0, held_experts=8,
+            router_scale=2.5, shared_dim=1024, rms_eps=1e-6, tie_head=False,
+            rope_full=Rotary(theta=5e5, fraction=0.5, yarn_factor=128.0,
+                             yarn_original=8192, beta_fast=32.0,
+                             beta_slow=1.0,
+                             attention_factor=1.4852030263919618),
+            rope_window=Rotary(theta=1e4, fraction=1.0)),
+        stream_data=PackConfig(),
+    )
     return {e.name: e for e in (toy, lstm, joint, dense, mcts, multihost,
-                                stream_lm, stream_moe, stream_mla)}
+                                stream_lm, stream_moe, stream_mla,
+                                stream_gqa)}
 
 
 EXPERIMENTS: Dict[str, Experiment] = _experiments()

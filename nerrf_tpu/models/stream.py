@@ -54,12 +54,23 @@ The backbone is a stack built from a list of layer kinds
   projected back to ``dim``, one more ``mla_moe`` layer under the same
   masks, a norm, and the main model's head (`mtp_loss`: targets two ahead).
   The embedding and the head are the main model's: two uses, one gradient.
+* ``gqa_full_dense``, ``gqa_full_moe``, ``gqa_swa_moe`` — one grouped-query
+  decoder layer (`_GroupedLayer`): ``num_kv_heads`` key-value heads shared
+  by groups of query heads, a sigmoid gate a query head on the attention's
+  output, the rotary embedding of its kind (``rope_full``: a share of a
+  head's dimensions, YaRN's frequencies; ``rope_window``), over the whole
+  document with ``num_heads`` query heads (``gqa_full_*``) or inside
+  ``window`` with ``window_heads`` (``gqa_swa_*``), all through
+  `ops/mla.py::attention`; then a dense SwiGLU (``_dense``) or the softmax
+  router's held experts, times ``router_scale``, beside a shared expert
+  (``_moe``).  Beside the routing counts its ``aux`` carries the pairs each
+  kind attended (``window_pairs``, ``full_pairs``).
 
 The ``block`` and hybrid kinds carry no positional encoding (event streams
 are irregularly sampled — wall-clock gaps carry signal, so Δt enters as a
-feature or a token, not a position index); ``dsa_moe`` and the latent kinds
-carry their source's rotary embedding, counted inside a document.  bfloat16
-compute, float32 parameters.
+feature or a token, not a position index); ``dsa_moe``, the latent and the
+grouped-query kinds carry their source's rotary embedding, counted inside a
+document.  bfloat16 compute, float32 parameters.
 """
 
 from __future__ import annotations
@@ -81,8 +92,12 @@ from nerrf_tpu.parallel.ring import ring_self_attention
 HYBRID_KINDS = ("mamba", "swa", "full", "gmu", "cross")
 SPARSE_KIND = "dsa_moe"
 LATENT_KINDS = ("mla_dense", "mla_moe")
+# grouped-query attention over the whole document or inside the window, then
+# a dense SwiGLU or routed experts beside a shared one
+GQA_KINDS = ("gqa_full_dense", "gqa_full_moe", "gqa_swa_moe")
+WINDOW_KINDS = ("gqa_swa_moe",)
 # the kinds that end in routed experts
-ROUTED_KINDS = (SPARSE_KIND, "mla_moe")
+ROUTED_KINDS = (SPARSE_KIND, "mla_moe", "gqa_full_moe", "gqa_swa_moe")
 
 
 def layer_kinds(num_layers: int) -> Tuple[str, ...]:
@@ -98,6 +113,36 @@ def layer_kinds(num_layers: int) -> Tuple[str, ...]:
         return ("mamba", "swa", "mamba", "full", "gmu", "cross")
     raise ValueError(f"no decoder-hybrid-decoder stack of {num_layers} "
                      "layers is defined (32 as published, 6 as cut)")
+
+
+@dataclasses.dataclass(frozen=True)
+class Rotary:
+    """One attention kind's rotary embedding: its base, the share of a
+    head's dimensions that turn (the first ones), and YaRN where
+    ``yarn_factor`` > 1: the frequencies blended over ``yarn_original``
+    positions between ``beta_fast`` and ``beta_slow`` turns
+    (`ops/dsa.py::yarn_frequencies`), the cosines and sines times
+    ``attention_factor``."""
+
+    theta: float = 1e4
+    fraction: float = 1.0
+    yarn_factor: float = 1.0
+    yarn_original: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+    def apply(self, x, pos):
+        """``x`` [T, heads, d], positions ``pos`` [T] -> ``x`` turned."""
+        d = x.shape[-1]
+        rotary = int(d * self.fraction)
+        freq = None
+        if self.yarn_factor > 1.0:
+            freq = dsa.yarn_frequencies(rotary, self.theta, self.yarn_factor,
+                                        self.yarn_original, self.beta_fast,
+                                        self.beta_slow)
+        return dsa.rope(x, pos, self.theta, rotary=rotary, freq=freq,
+                        mscale=self.attention_factor)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -162,7 +207,8 @@ class StreamConfig:
     qk_nope_dim: int = 192
     qk_rope_dim: int = 64
     v_head_dim: int = 256
-    # the sigmoid router's weights are scaled by this; the shared expert's
+    # the router's weights are scaled by this (the sigmoid router's; the
+    # softmax router's in the ``gqa_*_moe`` kinds); the shared expert's
     # width
     router_scale: float = 1.8
     shared_dim: int = 1536
@@ -170,12 +216,23 @@ class StreamConfig:
     # weight of their loss term
     mtp_layers: int = 0
     mtp_loss_weight: float = 0.3
+    # the ``gqa_*`` kinds (``num_heads``, ``num_kv_heads`` and ``head_dim``
+    # are the full kind's; ``window`` the window kind's reach): the window
+    # kind's query heads, each kind's rotary, and the softmax router's
+    # scale (``router_scale``)
+    window_heads: int = 0
+    rope_full: Rotary = Rotary()
+    rope_window: Rotary = Rotary()
 
     def __post_init__(self):
         for name in ("kinds", "published_layers"):
             v = getattr(self, name)
             if isinstance(v, list):  # from JSON
                 object.__setattr__(self, name, tuple(v))
+        for name in ("rope_full", "rope_window"):
+            v = getattr(self, name)
+            if isinstance(v, dict):  # from JSON
+                object.__setattr__(self, name, Rotary(**v))
         for name in ("kinds", "published_layers"):
             v = getattr(self, name)
             if v and len(v) != self.num_layers:
@@ -490,34 +547,102 @@ class _LatentLayer(nn.Module):
             if self.dense:
                 with jax.named_scope("dense_mlp"):
                     return h + _swiglu(cfg, z, cfg.mlp_dim), {}
-            z = z.reshape(b * t, cfg.dim)
-            logits = nn.Dense(cfg.num_experts, use_bias=False, dtype=f32,
-                              name="router",
-                              precision=jax.lax.Precision.HIGHEST)(
-                                  z.astype(f32))
             # a buffer in the parameters' tree: seeded, read under
             # `stop_gradient`, frozen by the trainer (`make_stream_tx`)
             bias = self.param("router_bias", nn.initializers.normal(0.1),
                               (cfg.num_experts,), f32)
-            # read only by `apply(..., mutable=["intermediates"])`
-            self.sow("intermediates", "router_logits", logits)
-            shape = (cfg.held_experts, cfg.dim, cfg.expert_dim)
-            init = lambda fan_in: nn.initializers.normal(fan_in ** -0.5)
-            w = [self.param(name, init(s[1]), s, f32)
-                 for name, s in (("w_gate", shape), ("w_up", shape),
-                                 ("w_down", (shape[0], shape[2], shape[1])))]
-            y, counts = moe.moe_share(
-                z, logits, *w, k=cfg.experts_per_token,
-                first=cfg.first_expert, router=partial(
-                    moe.route_sigmoid, bias=bias, scale=cfg.router_scale))
-            with jax.named_scope("moe_shared"):
-                y = y.astype(cfg.dtype) + _swiglu(cfg, z, cfg.shared_dim,
-                                                  "shared_")
-            counts = counts.astype(f32)
-            aux = {"held_assignments": jnp.sum(counts),
-                   "load_max_over_mean": jnp.max(counts) / jnp.maximum(
-                       jnp.mean(counts), 1.0)}
+            y, aux = _beside_shared(self, cfg, z.reshape(b * t, cfg.dim),
+                                    partial(moe.route_sigmoid, bias=bias,
+                                            scale=cfg.router_scale))
             return h + y.reshape(b, t, cfg.dim), aux
+
+
+def _beside_shared(module: nn.Module, cfg: StreamConfig, z, router):
+    """The held experts under ``router`` (`ops/moe.py`) beside a shared
+    expert every token passes, inside ``module`` (their parameters are
+    its): ``z`` [N, dim] -> (y [N, dim], aux: the assignments to held
+    experts and their largest count over the mean, float32 scalars)."""
+    f32 = jnp.float32
+    logits = nn.Dense(cfg.num_experts, use_bias=False, dtype=f32,
+                      name="router", precision=jax.lax.Precision.HIGHEST)(
+                          z.astype(f32))
+    # read only by `apply(..., mutable=["intermediates"])`
+    module.sow("intermediates", "router_logits", logits)
+    shape = (cfg.held_experts, cfg.dim, cfg.expert_dim)
+    init = lambda fan_in: nn.initializers.normal(fan_in ** -0.5)
+    w = [module.param(name, init(s[1]), s, f32)
+         for name, s in (("w_gate", shape), ("w_up", shape),
+                         ("w_down", (shape[0], shape[2], shape[1])))]
+    y, counts = moe.moe_share(z, logits, *w, k=cfg.experts_per_token,
+                              first=cfg.first_expert, router=router)
+    with jax.named_scope("moe_shared"):
+        y = y.astype(cfg.dtype) + _swiglu(cfg, z, cfg.shared_dim, "shared_")
+    counts = counts.astype(f32)
+    return y, {"held_assignments": jnp.sum(counts),
+               "load_max_over_mean": jnp.max(counts) / jnp.maximum(
+                   jnp.mean(counts), 1.0)}
+
+
+class _GroupedLayer(nn.Module):
+    """One grouped-query decoder layer: ``h = x + W_o (g * Attn(RMSNorm(x)))``
+    with rotary queries and keys, ``num_kv_heads`` key-value heads shared by
+    groups of query heads, and a sigmoid gate a query head, ``g_h =
+    sigmoid(u . w_g,h)`` of the attention's input ``u``; the
+    attention over the whole document (``gqa_full_*``: ``num_heads`` query
+    heads, ``rope_full``) or inside ``window`` (``gqa_swa_*``:
+    ``window_heads``, ``rope_window``).  Then ``y = h + SwiGLU(RMSNorm(h))``
+    (``_dense``) or the softmax router's held experts (times
+    ``router_scale``) beside a shared expert (``_moe``).  ``(x, seg) -> (y,
+    aux)``: ``aux`` holds the pairs the attention attended
+    (``attention_pairs``) and, after experts, `_beside_shared`'s counts."""
+
+    cfg: StreamConfig
+    kind: str
+    label: str
+
+    @nn.compact
+    def __call__(self, x, seg):
+        cfg = self.cfg
+        window = self.kind in WINDOW_KINDS
+        heads = cfg.window_heads if window else cfg.num_heads
+        hk, d = cfg.num_kv_heads, cfg.head_dim
+        rotary = cfg.rope_window if window else cfg.rope_full
+        b, t, _ = x.shape
+        norm = lambda name: nn.RMSNorm(epsilon=cfg.rms_eps, dtype=cfg.dtype,
+                                       name=name)
+        with jax.named_scope(self.label):
+            u = norm("attn_norm")(x)
+            with jax.named_scope("gqa_proj"):
+                q = _dense(heads * d, cfg, "wq")(u).reshape(b, t, heads, d)
+                k = _dense(hk * d, cfg, "wk")(u).reshape(b, t, hk, d)
+                v = _dense(hk * d, cfg, "wv")(u).reshape(b, t, hk, d)
+                # ``wg``: ``gate`` is the dense SwiGLU's
+                gate = jax.nn.sigmoid(_dense(heads, cfg, "wg")(u).astype(
+                    jnp.float32))
+
+                def turn(q, k, seg):
+                    pos = dsa.doc_positions(seg)
+                    return rotary.apply(q, pos), rotary.apply(k, pos)
+
+                q, k = jax.vmap(turn)(q, k, seg)
+            reach = cfg.window if window else None
+            o = jax.vmap(partial(
+                mla.attention, window=reach,
+                scope="gqa_window_attention" if window
+                else "gqa_full_attention"))(q, k, v, seg)
+            with jax.named_scope("gqa_proj"):
+                o = (o * gate[..., None]).astype(cfg.dtype)
+                h = x + _dense(cfg.dim, cfg, "wo")(o.reshape(b, t, heads * d))
+            aux = {"attention_pairs": jnp.sum(jax.vmap(
+                partial(dsa.causal_pairs, window=reach))(seg))}
+            z = norm("mlp_norm")(h)
+            if self.kind.endswith("_dense"):
+                with jax.named_scope("dense_mlp"):
+                    return h + _swiglu(cfg, z, cfg.mlp_dim), aux
+            y, routed = _beside_shared(
+                self, cfg, z.reshape(b * t, cfg.dim),
+                partial(moe.route, scale=cfg.router_scale))
+            return h + y.reshape(b, t, cfg.dim), {**aux, **routed}
 
 
 class StreamNet(nn.Module):
@@ -574,8 +699,14 @@ class StreamNet(nn.Module):
             _LatentLayer,
             policy=jax.checkpoint_policies.save_only_these_names(mla.SAVED))
             if cfg.remat else _LatentLayer)
+        grouped_cls = (nn.remat(
+            _GroupedLayer,
+            policy=jax.checkpoint_policies.save_only_these_names(mla.SAVED))
+            if cfg.remat else _GroupedLayer)
         m = kv = None
         sparse_aux = []
+        # the pairs each attention kind attended, summed over its layers
+        pairs = {}
         for i, kind in enumerate(cfg.stack):
             if kind == "block":
                 x = block_cls(cfg, self.mesh, name=f"block_{i}")(
@@ -588,6 +719,15 @@ class StreamNet(nn.Module):
                 x, aux = latent_cls(cfg, kind == "mla_dense",
                                     f"stream_layer_{i}",
                                     name=f"layer_{i}")(x, seg)
+                if aux:
+                    sparse_aux.append(aux)
+            elif kind in GQA_KINDS:
+                x, aux = grouped_cls(cfg, kind, f"stream_layer_{i}",
+                                     name=f"layer_{i}")(x, seg)
+                which = ("window_pairs" if kind in WINDOW_KINDS
+                         else "full_pairs")
+                pairs[which] = pairs.get(which, 0.0) + aux.pop(
+                    "attention_pairs")
                 if aux:
                     sparse_aux.append(aux)
             else:
@@ -607,7 +747,7 @@ class StreamNet(nn.Module):
                                 name="mtp_block")(y, seg)
             sparse_aux.append(aux)
             out["mtp_hidden"] = rms("mtp_norm")(y)
-        if sparse_aux or cfg.stack[-1] in LATENT_KINDS:
+        if sparse_aux or cfg.stack[-1] in LATENT_KINDS + GQA_KINDS:
             x = nn.RMSNorm(epsilon=cfg.rms_eps, dtype=dt, name="final_norm")(x)
         else:
             x = nn.LayerNorm(epsilon=1e-5 if cfg.vocab_size else 1e-6,
@@ -620,7 +760,7 @@ class StreamNet(nn.Module):
             aux["load_max_over_mean"] /= len(sparse_aux)
             # every position is routed, padding too
             aux["routed_tokens"] = jnp.float32(x.shape[0] * x.shape[1])
-            return {"hidden": x, "aux": aux, **out}
+            return {"hidden": x, "aux": {**aux, **pairs}, **out}
         if cfg.vocab_size:
             return {"hidden": x}
         logits = nn.Dense(1, dtype=jnp.float32, name="head")(x)[..., 0]

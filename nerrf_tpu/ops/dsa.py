@@ -56,6 +56,7 @@ the name `SAVED`.
 
 from __future__ import annotations
 
+import math
 from functools import partial
 
 import jax
@@ -89,17 +90,57 @@ def doc_positions(seg):
     return idx - jax.lax.cummax(jnp.where(first, idx, 0))
 
 
-def rope(x, pos, theta: float):
+def rope(x, pos, theta: float, *, rotary: int = None, freq=None,
+         mscale: float = 1.0):
     """Rotary embedding, rotate-half convention: ``x`` [T, heads, d] with
     the pairs ``(i, i + d / 2)`` turned by ``pos * theta^(-2 i / d)``.
-    float32 inside, the input's type out."""
+    float32 inside, the input's type out.  ``rotary`` r < d turns the first
+    r dimensions alone (pairs ``(i, i + r / 2)``) and passes the rest;
+    ``freq`` [r / 2] replaces theta's frequencies (`yarn_frequencies`);
+    ``mscale`` multiplies the cosines and sines (YaRN's attention factor:
+    the turned dimensions alone are scaled)."""
     d = x.shape[-1]
-    freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    angle = pos.astype(jnp.float32)[:, None] * freq            # [T, d / 2]
+    r = rotary or d
+    # nerrflint: ok[recompile-hazard] freq is None or a numpy constant (`yarn_frequencies` of the static configuration), never a traced value
+    if freq is None:
+        freq = theta ** (-jnp.arange(0, r, 2, dtype=jnp.float32) / r)
+    angle = pos.astype(jnp.float32)[:, None] * freq            # [T, r / 2]
     cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
-    a, b = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
-                           axis=-1).astype(x.dtype)
+    # nerrflint: ok[recompile-hazard] mscale is a Python float of the static configuration (`Rotary.attention_factor`), never a traced value
+    if mscale != 1.0:
+        cos, sin = cos * mscale, sin * mscale
+    a, b = jnp.split(x[..., :r].astype(jnp.float32), 2, axis=-1)
+    turned = [a * cos - b * sin, b * cos + a * sin]
+    if r < d:
+        turned.append(x[..., r:].astype(jnp.float32))
+    return jnp.concatenate(turned, axis=-1).astype(x.dtype)
+
+
+def yarn_frequencies(rotary: int, theta: float, factor: float,
+                     original: int, beta_fast: float, beta_slow: float):
+    """YaRN's per-frequency blend of ``rotary`` / 2 rotary frequencies
+    (`transformers`' ``_compute_yarn_parameters``, ``truncate`` on): the
+    frequencies that turn fewer than ``beta_slow`` times over ``original``
+    positions are divided by ``factor``, those that turn more than
+    ``beta_fast`` times are kept, and a linear ramp between the floor and the
+    ceiling of the two dimensions where that happens blends the rest ->
+    float32 [rotary / 2] (a numpy constant)."""
+    import numpy as np
+
+    def dim_of(turns):
+        return (rotary * math.log(original / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(dim_of(beta_fast)), 0)
+    high = min(math.ceil(dim_of(beta_slow)), rotary - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(rotary // 2, dtype=np.float32) - low)
+                   / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp
+    base = theta ** (np.arange(0, rotary, 2, dtype=np.float32) / rotary)
+    return ((1.0 / (factor * base)) * (1.0 - keep)
+            + (1.0 / base) * keep).astype(np.float32)
 
 
 def _order_key(x):
@@ -665,8 +706,13 @@ def selection(qi, ki, wi, seg, *, topk: int, block: int = None):
         (cut(qi), cut(wi), cut(seg), jnp.arange(0, t, block))).reshape(t, t)
 
 
-def causal_pairs(seg):
+def causal_pairs(seg, window: int = None):
     """Query-key pairs of one packed sequence that the causal and document
-    masks allow (float32: 33.5 M at 8192 tokens)."""
+    masks allow (float32: 33.5 M at 8192 tokens), real queries only; with a
+    ``window`` w, those no more than w - 1 positions back."""
     pos = doc_positions(seg)
-    return jnp.sum(jnp.where(seg > 0, pos + 1, 0).astype(jnp.float32))
+    real, inside = seg > 0, pos + 1
+    # nerrflint: ok[recompile-hazard] window is None or a Python int of the static configuration (`StreamConfig.window`), never a traced value
+    if window is not None:
+        inside = jnp.minimum(inside, window)
+    return jnp.sum(jnp.where(real, inside, 0).astype(jnp.float32))

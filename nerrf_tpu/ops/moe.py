@@ -3,8 +3,9 @@
 A router scores every token against ALL ``num_experts`` experts and keeps
 ``k`` of them.  There are two, and one dispatch behind both (`moe_share`
 takes either): `route` is the softmax router (probabilities over all
-experts, the ``k`` largest, renormalised over those ``k``: the ``dsa_moe``
-kind's), `route_sigmoid` the sigmoid router of the latent-attention kinds
+experts, the ``k`` largest, renormalised over those ``k``, times a constant
+where the model has one: the ``dsa_moe`` and ``gqa_*_moe`` kinds'),
+`route_sigmoid` the sigmoid router of the latent-attention kinds
 (each expert's score is a sigmoid of its own logit; the ``k`` are chosen by
 score PLUS a correction bias that no gradient reaches and that never enters
 a weight; the weights are the chosen scores over their sum, times a
@@ -48,14 +49,15 @@ import jax.numpy as jnp
 TILE = 256
 
 
-def route(logits, k: int):
+def route(logits, k: int, *, scale: float = 1.0):
     """The softmax router.  Logits [N, E] float32 -> (weights [N, k]
-    float32: the softmax over all E, renormalised over the k kept, experts
-    [N, k] int32).  Of two equal probabilities the lower expert index is
-    kept first."""
+    float32: the softmax over all E, renormalised over the k kept, times
+    ``scale``; experts [N, k] int32).  Of two equal probabilities the lower
+    expert index is kept first."""
     gates = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     top, experts = jax.lax.top_k(gates, k)
-    return top / jnp.sum(top, axis=-1, keepdims=True), experts
+    weights = top / jnp.sum(top, axis=-1, keepdims=True)
+    return (weights if scale == 1.0 else scale * weights), experts
 
 
 def route_sigmoid(logits, k: int, *, bias, scale: float):

@@ -22,8 +22,9 @@ from flax.training import train_state
 
 import optax
 
-from nerrf_tpu.models.stream import (LATENT_KINDS, StreamConfig, StreamNet,
-                                     mtp_loss, next_token_loss, stream_loss)
+from nerrf_tpu.models.stream import (GQA_KINDS, LATENT_KINDS, WINDOW_KINDS,
+                                     StreamConfig, StreamNet, mtp_loss,
+                                     next_token_loss, stream_loss)
 from nerrf_tpu.tracing import DEFAULT_TRACER
 from nerrf_tpu.train import loop
 
@@ -74,7 +75,8 @@ def make_stream_loss_fn(model: StreamNet):
 def count_sparse(aux: dict, scfg: StreamConfig, steps: int = 1) -> None:
     """The ``aux`` of a step with routed layers -> the program's registry:
     the routing of every such stack, the selection where the stack has an
-    indexer, the multi-token-prediction term where it has that module.  It
+    indexer, the multi-token-prediction term where it has that module, the
+    pairs its grouped-query layers attended where it has those.  It
     floats device scalars, so the loop calls it only where it already
     syncs; ``steps`` is how many steps that sync stands for (the counters
     then assume they routed alike)."""
@@ -113,6 +115,14 @@ def count_sparse(aux: dict, scfg: StreamConfig, steps: int = 1) -> None:
                       term / max(float(aux["token_loss"]) + term, 1e-30),
                       help="the weighted multi-token-prediction term over "
                            "the whole loss, last synced step")
+    for kind in ("window", "full"):
+        if f"{kind}_pairs" in aux:
+            reg.counter_inc(
+                "attention_pairs_total", float(aux[f"{kind}_pairs"]) * steps,
+                labels={"kind": kind},
+                help="query-key pairs the grouped-query attention layers "
+                     "attended (real queries), by kind: inside the window, "
+                     "or the whole document")
 
 
 def make_stream_tx(cfg: loop.TrainConfig, scfg: StreamConfig):
@@ -136,10 +146,13 @@ def stream_kernel_path(scfg: StreamConfig,
     the chosen-set attention (`ops/dsa.py::attention_route`) where the stack
     has a ``dsa_moe`` layer, the latent attention core
     (`ops/mla.py::attention_route`) where the stack or its
-    multi-token-prediction module has a latent layer."""
+    multi-token-prediction module has a latent layer, and the same core
+    under each grouped-query kind's scope where the stack has that kind."""
     sparse = "dsa_moe" in scfg.stack
     latent = bool(set(scfg.stack) & set(LATENT_KINDS) or scfg.mtp_layers)
-    if not (sparse or latent):
+    # whether each grouped-query kind of the stack attends inside a window
+    grouped = {k in WINDOW_KINDS for k in scfg.stack if k in GQA_KINDS}
+    if not (sparse or latent or grouped):
         return {}
     if seq_len is None:
         raise ValueError("an attention core's route depends on the sequence "
@@ -153,6 +166,9 @@ def stream_kernel_path(scfg: StreamConfig,
     if latent:
         path["mla_attention"] = mla.attention_route(
             seq_len, scfg.qk_nope_dim + scfg.qk_rope_dim, scfg.v_head_dim)
+    for window in sorted(grouped):
+        path["gqa_window_attention" if window else "gqa_full_attention"] = \
+            mla.attention_route(seq_len, scfg.head_dim, scfg.head_dim)
     return path
 
 

@@ -21,7 +21,7 @@ def test_registry_matches_baseline_configs():
         "toy-graphsage", "lstm-impact", "joint-100h", "joint-dense",
         "mcts-lockbit", "multihost-online", "stream-phi4-mini-flash",
         "stream-keye-vl2-30b-a3b",
-        "stream-glm-4.7-flash",
+        "stream-glm-4.7-flash", "stream-laguna-s-2.1",
     }
 
 

@@ -191,3 +191,34 @@ def test_expert_walk_never_copies_the_weights_per_tile(one_chip):
         shape(one_chip, (T, 2048), jnp.bfloat16), shape(one_chip, (T, 128)),
         w, w, shape(one_chip, (16, 768, 2048))).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
+
+
+@pytest.mark.parametrize("heads, window", [(72, 512), (48, None)])
+def test_grouped_cores_fused_route_compiles_at_the_published_widths(
+        one_chip, monkeypatch, heads, window):
+    """`ops/mla.py::attention` as the grouped-query kinds call it: 72 query
+    heads over 8 key-value heads of 128 inside a window of 512, and 48 over
+    8 over the whole document, forward + backward under `vmap`, on the route
+    a TPU traces: the chip's compiler accepts both kernels with the key-value
+    head read at ``h // group`` and the window's short walk, and the plan of
+    a batch of two holds the query heads' shares of ``dk``, ``dv`` (0.6 GB at
+    72 heads), their sums and the operands turned heads-first (1.22 GB in
+    all), never the scores (19 GB at 72 heads in float32)."""
+    from nerrf_tpu.ops import mla
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert mla.attention_route(T, 128, 128) == "pallas_flash"
+
+    def loss(q, k, v, seg):
+        o = jax.vmap(lambda q, k, v, seg: mla.attention(
+            q, k, v, seg, window=window, scope="gqa_attention"))(q, k, v, seg)
+        return jnp.sum(o.astype(jnp.float32) ** 2)
+
+    kv = shape(one_chip, (2, T, 8, 128), jnp.bfloat16)
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        shape(one_chip, (2, T, heads, 128), jnp.bfloat16), kv, kv,
+        shape(one_chip, (2, T), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "mla_flash_fwd" in text and "mla_flash_bwd" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 << 29
