@@ -416,9 +416,41 @@ def _experiments() -> Dict[str, Experiment]:
             rope_window=Rotary(theta=1e4, fraction=1.0)),
         stream_data=PackConfig(),
     )
+    stream_kda = Experiment(
+        name="stream-kimi-linear-48b-a3b",
+        description=(
+            "Stream-encoder pretraining on packed 8k event-token sequences: "
+            "Kimi-Linear-48B-A3B's decoder (channel-gated delta-rule linear "
+            "attention, three layers to one of position-free latent "
+            "attention with a direct query projection, a leading dense "
+            "layer, then 256 sigmoid-routed experts, 8 a token, beside a "
+            "shared one) at its published widths, published layers 1-5, "
+            "experts 0-7 of 256 and an eighth of both vocabulary matrices: "
+            "what one chip of a 32-chip expert-parallel slice holds "
+            "(docs/stream-backbone.md; "
+            "chipbench/configs/kimi-linear-48b-a3b.json)"
+        ),
+        corpus=CorpusConfig(num_traces=4, attack_fraction=0.5,
+                            duration_sec=180.0, num_target_files=45,
+                            benign_rate_hz=550.0, eval_fraction=0.0),
+        # the long warm-up of the other routed stacks, for their reason
+        train=TrainConfig(batch_size=1, num_steps=20000, learning_rate=3e-4,
+                          warmup_steps=2000, weight_decay=0.1, eval_every=20),
+        stream=StreamConfig(
+            dim=2304, num_heads=32, head_dim=128, num_layers=5,
+            kinds=("kda_dense", "kda_moe", "kda_moe", "mla_moe", "kda_moe"),
+            vocab_size=20480, dropout=0.0, mlp_dim=9216, rope_theta=1e4,
+            q_lora_rank=0, kv_lora_rank=512, qk_nope_dim=128,
+            qk_rope_dim=64, v_head_dim=128, mla_rope=False, d_conv=4,
+            num_experts=256,
+            experts_per_token=8, expert_dim=1024, first_expert=0,
+            held_experts=8, router_scale=2.446, shared_dim=1024,
+            rms_eps=1e-5, tie_head=False),
+        stream_data=PackConfig(),
+    )
     return {e.name: e for e in (toy, lstm, joint, dense, mcts, multihost,
                                 stream_lm, stream_moe, stream_mla,
-                                stream_gqa)}
+                                stream_gqa, stream_kda)}
 
 
 EXPERIMENTS: Dict[str, Experiment] = _experiments()

@@ -65,12 +65,25 @@ The backbone is a stack built from a list of layer kinds
   router's held experts, times ``router_scale``, beside a shared expert
   (``_moe``).  Beside the routing counts its ``aux`` carries the pairs each
   kind attended (``window_pairs``, ``full_pairs``).
+* ``kda_dense``, ``kda_moe`` — one channel-gated delta-rule decoder layer
+  (`_DeltaLayer`, Kimi Delta Attention): linear attention whose state, a
+  ``[head_dim, head_dim]`` matrix a head, decays channel by channel
+  and is written by the delta rule (`ops/kda.py`), zeroed at each packed
+  document's start, behind short convolutions, L2-normalised queries and
+  keys, low-rank decay and output gates and a gated head norm; then a dense
+  SwiGLU (``_dense``) or the sigmoid router's held experts beside a shared
+  expert (``_moe``, as ``mla_moe``).  Its ``aux`` carries the real tokens
+  the rule ran and the document starts its state was zeroed at
+  (``delta_tokens``, ``delta_resets``).  Beside them a stack may hold
+  ``mla_moe`` layers with the queries projected directly (``q_lora_rank``
+  0) and nothing rotated (``mla_rope`` false).
 
 The ``block`` and hybrid kinds carry no positional encoding (event streams
 are irregularly sampled — wall-clock gaps carry signal, so Δt enters as a
 feature or a token, not a position index); ``dsa_moe``, the latent and the
 grouped-query kinds carry their source's rotary embedding, counted inside a
-document.  bfloat16 compute, float32 parameters.
+document (a latent stack with ``mla_rope`` false, and the delta-rule kinds,
+none).  bfloat16 compute, float32 parameters.
 """
 
 from __future__ import annotations
@@ -85,7 +98,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
-from nerrf_tpu.ops import dsa, mla, moe
+from nerrf_tpu.ops import dsa, kda, mla, moe
 from nerrf_tpu.ops.ssm import causal_conv1d, selective_scan
 from nerrf_tpu.parallel.ring import ring_self_attention
 
@@ -96,8 +109,15 @@ LATENT_KINDS = ("mla_dense", "mla_moe")
 # a dense SwiGLU or routed experts beside a shared one
 GQA_KINDS = ("gqa_full_dense", "gqa_full_moe", "gqa_swa_moe")
 WINDOW_KINDS = ("gqa_swa_moe",)
+# the channel-gated delta rule, then a dense SwiGLU or routed experts beside
+# a shared one
+DELTA_KINDS = ("kda_dense", "kda_moe")
 # the kinds that end in routed experts
-ROUTED_KINDS = (SPARSE_KIND, "mla_moe", "gqa_full_moe", "gqa_swa_moe")
+ROUTED_KINDS = (SPARSE_KIND, "mla_moe", "gqa_full_moe", "gqa_swa_moe",
+                "kda_moe")
+# of those, the kinds whose router is the sigmoid one with a frozen
+# correction bias
+SIGMOID_ROUTED_KINDS = ("mla_moe", "kda_moe")
 
 
 def layer_kinds(num_layers: int) -> Tuple[str, ...]:
@@ -199,14 +219,17 @@ class StreamConfig:
     # embedding
     tie_head: bool = True
     # the latent kinds.  Attention: the queries' and the keys-and-values'
-    # latent widths, a head's un-rotated and rotary query/key widths, its
-    # value width.  (``mlp_dim`` is the dense layer's width, ``expert_dim``
-    # an expert's, ``num_experts`` .. ``held_experts`` as above)
+    # latent widths (a query latent of 0: the queries projected directly),
+    # a head's un-rotated and rotary query/key widths, its value width, and
+    # whether the rotary part turns (False: position-free, its columns plain
+    # columns).  (``mlp_dim`` is the dense layer's width, ``expert_dim`` an
+    # expert's, ``num_experts`` .. ``held_experts`` as above)
     q_lora_rank: int = 768
     kv_lora_rank: int = 512
     qk_nope_dim: int = 192
     qk_rope_dim: int = 64
     v_head_dim: int = 256
+    mla_rope: bool = True
     # the router's weights are scaled by this (the sigmoid router's; the
     # softmax router's in the ``gqa_*_moe`` kinds); the shared expert's
     # width
@@ -223,6 +246,8 @@ class StreamConfig:
     window_heads: int = 0
     rope_full: Rotary = Rotary()
     rope_window: Rotary = Rotary()
+    # (the delta-rule kinds read ``num_heads`` heads of ``head_dim`` keys and
+    # values, decay and output gates of rank ``head_dim``, ``d_conv`` taps)
 
     def __post_init__(self):
         for name in ("kinds", "published_layers"):
@@ -504,7 +529,9 @@ def _swiglu(cfg: StreamConfig, z, width: int, prefix: str = ""):
 
 class _LatentLayer(nn.Module):
     """One latent-attention decoder layer: ``h = x + W_o Attn(RMSNorm(x))``
-    with queries, keys and values rebuilt from their latents, then ``y = h +
+    with queries, keys and values rebuilt from their latents (queries
+    projected directly where ``q_lora_rank`` is 0; nothing rotated where
+    ``mla_rope`` is false), then ``y = h +
     SwiGLU(RMSNorm(h))`` (``dense``) or ``y = h + Experts(z) + Shared(z)``,
     ``z = RMSNorm(h)``: the held experts' part under the sigmoid router and
     a shared expert every token passes.  ``(x, seg) -> (y, aux)``; ``aux``
@@ -521,20 +548,22 @@ class _LatentLayer(nn.Module):
         cfg = self.cfg
         heads, nope, rot = cfg.num_heads, cfg.qk_nope_dim, cfg.qk_rope_dim
         b, t, _ = x.shape
-        f32 = jnp.float32
         norm = lambda name: nn.RMSNorm(epsilon=cfg.rms_eps, dtype=cfg.dtype,
                                        name=name)
         with jax.named_scope(self.label):
             u = norm("attn_norm")(x)
             with jax.named_scope("mla_latent"):
-                q = _dense(heads * (nope + rot), cfg, "wq_b")(
-                    norm("q_a_norm")(_dense(cfg.q_lora_rank, cfg, "wq_a")(u)))
+                if cfg.q_lora_rank:
+                    q = _dense(heads * (nope + rot), cfg, "wq_b")(norm(
+                        "q_a_norm")(_dense(cfg.q_lora_rank, cfg, "wq_a")(u)))
+                else:
+                    q = _dense(heads * (nope + rot), cfg, "wq")(u)
                 latent = _dense(cfg.kv_lora_rank + rot, cfg, "wkv_a")(u)
                 kv = _dense(heads * (nope + cfg.v_head_dim), cfg, "wkv_b")(
                     norm("kv_a_norm")(latent[..., :cfg.kv_lora_rank]))
                 q, k, v = jax.vmap(lambda q, k_r, kv, seg: mla.assemble(
                     q, k_r, kv, dsa.doc_positions(seg), nope=nope,
-                    theta=cfg.rope_theta))(
+                    theta=cfg.rope_theta if cfg.mla_rope else None))(
                         q.reshape(b, t, heads, nope + rot),
                         latent[..., cfg.kv_lora_rank:],
                         kv.reshape(b, t, heads, nope + cfg.v_head_dim), seg)
@@ -547,13 +576,7 @@ class _LatentLayer(nn.Module):
             if self.dense:
                 with jax.named_scope("dense_mlp"):
                     return h + _swiglu(cfg, z, cfg.mlp_dim), {}
-            # a buffer in the parameters' tree: seeded, read under
-            # `stop_gradient`, frozen by the trainer (`make_stream_tx`)
-            bias = self.param("router_bias", nn.initializers.normal(0.1),
-                              (cfg.num_experts,), f32)
-            y, aux = _beside_shared(self, cfg, z.reshape(b * t, cfg.dim),
-                                    partial(moe.route_sigmoid, bias=bias,
-                                            scale=cfg.router_scale))
+            y, aux = _sigmoid_experts(self, cfg, z.reshape(b * t, cfg.dim))
             return h + y.reshape(b, t, cfg.dim), aux
 
 
@@ -581,6 +604,109 @@ def _beside_shared(module: nn.Module, cfg: StreamConfig, z, router):
     return y, {"held_assignments": jnp.sum(counts),
                "load_max_over_mean": jnp.max(counts) / jnp.maximum(
                    jnp.mean(counts), 1.0)}
+
+
+def _sigmoid_experts(module: nn.Module, cfg: StreamConfig, z):
+    """`_beside_shared` under the sigmoid router, whose correction bias is a
+    buffer in the parameters' tree: seeded, read under `stop_gradient`,
+    frozen by the trainer (`make_stream_tx`)."""
+    bias = module.param("router_bias", nn.initializers.normal(0.1),
+                        (cfg.num_experts,), jnp.float32)
+    return _beside_shared(module, cfg, z, partial(
+        moe.route_sigmoid, bias=bias, scale=cfg.router_scale))
+
+
+def _a_log_init(key, shape, dtype=jnp.float32):
+    """log U(1, 16): a head's decay rate."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
+def _dt_bias_init(key, shape, dtype=jnp.float32):
+    """softplus^-1 of a log-uniform draw from [0.001, 0.1] (Mamba's)."""
+    dt = jnp.exp(jax.random.uniform(key, shape, dtype, math.log(1e-3),
+                                    math.log(1e-1)))
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
+class _DeltaLayer(nn.Module):
+    """One channel-gated delta-rule decoder layer (Kimi Delta Attention),
+    ``num_heads`` heads of ``head_dim`` keys and values: with ``u =
+    RMSNorm(x)``, ``q, k, v = SiLU(conv(u W))`` (causal depthwise
+    convolutions of ``d_conv`` taps, no bias, cut at each document's
+    start), ``q``, ``k`` L2-normalised a head (eps 1e-6) and ``q`` scaled by
+    ``d_k ** -0.5``; the decay ``g = -exp(A_log_h) softplus(u W_f_down
+    W_f_up + dt_bias)`` a channel (both gates' factors of rank
+    ``head_dim``); ``beta = sigmoid(u W_b)`` a head; ``o``
+    the delta rule (`ops/kda.py`); ``h = x + W_o(RMSNorm_head(o) *
+    sigmoid(u W_g_down W_g_up))``.  Then ``y = h + SwiGLU(RMSNorm(h))``
+    (``kda_dense``) or the sigmoid router's held experts beside a shared
+    expert (``kda_moe``, as `_LatentLayer`).  ``(x, seg) -> (y, aux)``:
+    ``aux`` holds the real tokens the delta rule ran (``delta_tokens``) and
+    the documents' starts its state was zeroed at (``delta_resets``),
+    float32 scalars, and after experts `_beside_shared`'s counts.
+    Everything but the core is under the scope ``kda_proj``."""
+
+    cfg: StreamConfig
+    kind: str
+    label: str
+
+    @nn.compact
+    def __call__(self, x, seg):
+        cfg = self.cfg
+        heads, dk, dt = cfg.num_heads, cfg.head_dim, cfg.dtype
+        width = heads * dk
+        b, t, _ = x.shape
+        f32 = jnp.float32
+        norm = lambda name: nn.RMSNorm(epsilon=cfg.rms_eps, dtype=dt,
+                                       name=name)
+        split = lambda z: z.reshape(b, t, heads, -1)
+        with jax.named_scope(self.label):
+            u = norm("attn_norm")(x)
+            with jax.named_scope("kda_proj"):
+                def short(name):
+                    w = self.param(f"{name}_conv", nn.initializers.normal(
+                        1.0 / math.sqrt(cfg.d_conv)), (cfg.d_conv, width),
+                        f32)
+                    return nn.silu(jax.vmap(
+                        causal_conv1d, in_axes=(0, None, None, 0))(
+                            _dense(width, cfg, f"w{name}")(u), w.astype(dt),
+                            jnp.zeros((width,), dt), seg))
+
+                def unit(z):
+                    z = split(z).astype(f32)
+                    return z * jax.lax.rsqrt(
+                        jnp.sum(z * z, axis=-1, keepdims=True) + 1e-6)
+
+                q = (unit(short("q")) * dk ** -0.5).astype(dt)
+                k = unit(short("k")).astype(dt)
+                v = split(short("v"))
+                a_log = self.param("A_log", _a_log_init, (heads,), f32)
+                bias = self.param("dt_bias", _dt_bias_init, (width,), f32)
+                rate = _dense(width, cfg, "f_b")(_dense(dk, cfg, "f_a")(u))
+                g = -jnp.exp(a_log)[:, None] * jax.nn.softplus(
+                    split(rate.astype(f32) + bias))
+                beta = jax.nn.sigmoid(_dense(heads, cfg, "wb")(u).astype(f32))
+            o = jax.vmap(kda.chunk_delta)(q, k, v, g, beta, seg)
+            with jax.named_scope("kda_proj"):
+                o = o.astype(f32)
+                o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
+                                      + cfg.rms_eps) * self.param(
+                    "o_norm", nn.initializers.ones, (dk,), f32)
+                gate = jax.nn.sigmoid(_dense(width, cfg, "g_b")(
+                    _dense(dk, cfg, "g_a")(u)).astype(f32))
+                h = x + _dense(cfg.dim, cfg, "wo")(
+                    (o.reshape(b, t, width) * gate).astype(dt))
+            first = jnp.concatenate(
+                [jnp.ones_like(seg[:, :1], bool), seg[:, 1:] != seg[:, :-1]],
+                1)
+            aux = {"delta_tokens": jnp.sum(seg > 0).astype(f32),
+                   "delta_resets": jnp.sum(first & (seg > 0)).astype(f32)}
+            z = norm("mlp_norm")(h)
+            if self.kind.endswith("_dense"):
+                with jax.named_scope("dense_mlp"):
+                    return h + _swiglu(cfg, z, cfg.mlp_dim), aux
+            y, routed = _sigmoid_experts(self, cfg, z.reshape(b * t, cfg.dim))
+            return h + y.reshape(b, t, cfg.dim), {**aux, **routed}
 
 
 class _GroupedLayer(nn.Module):
@@ -703,9 +829,16 @@ class StreamNet(nn.Module):
             _GroupedLayer,
             policy=jax.checkpoint_policies.save_only_these_names(mla.SAVED))
             if cfg.remat else _GroupedLayer)
+        # its remat keeps what `ops/kda.py` names: the chunk-start states
+        # and the core's output
+        delta_cls = (nn.remat(
+            _DeltaLayer,
+            policy=jax.checkpoint_policies.save_only_these_names(kda.SAVED))
+            if cfg.remat else _DeltaLayer)
         m = kv = None
         sparse_aux = []
-        # the pairs each attention kind attended, summed over its layers
+        # the pairs each attention kind attended and the delta rule's
+        # tokens and resets, summed over their layers
         pairs = {}
         for i, kind in enumerate(cfg.stack):
             if kind == "block":
@@ -730,6 +863,13 @@ class StreamNet(nn.Module):
                     "attention_pairs")
                 if aux:
                     sparse_aux.append(aux)
+            elif kind in DELTA_KINDS:
+                x, aux = delta_cls(cfg, kind, f"stream_layer_{i}",
+                                   name=f"layer_{i}")(x, seg)
+                for name in ("delta_tokens", "delta_resets"):
+                    pairs[name] = pairs.get(name, 0.0) + aux.pop(name)
+                if aux:
+                    sparse_aux.append(aux)
             else:
                 x, m, kv = layer_cls(cfg, kind, i, name=f"layer_{i}")(
                     x, seg, m, kv)
@@ -747,7 +887,8 @@ class StreamNet(nn.Module):
                                 name="mtp_block")(y, seg)
             sparse_aux.append(aux)
             out["mtp_hidden"] = rms("mtp_norm")(y)
-        if sparse_aux or cfg.stack[-1] in LATENT_KINDS + GQA_KINDS:
+        if sparse_aux or cfg.stack[-1] in (LATENT_KINDS + GQA_KINDS
+                                           + DELTA_KINDS):
             x = nn.RMSNorm(epsilon=cfg.rms_eps, dtype=dt, name="final_norm")(x)
         else:
             x = nn.LayerNorm(epsilon=1e-5 if cfg.vocab_size else 1e-6,

@@ -51,6 +51,7 @@ operations of a transposed `lax.switch` carry no scope of their own.
 from __future__ import annotations
 
 from functools import partial
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -74,18 +75,23 @@ KEY_SPAN = 2048
 SAVED = "mla_saved"
 
 
-def assemble(q, k_r, kv, pos, *, nope: int, theta: float):
+def assemble(q, k_r, kv, pos, *, nope: int, theta: Optional[float]):
     """One packed sequence.  ``q`` [T, H, nope + rope] (a head's un-rotated
     part, then its rotary part, not yet rotated), ``k_r`` [T, rope] (the one
     rotary key a token, not yet rotated), ``kv`` [T, H, nope + dv] (a head's
     un-rotated key, then its value), ``pos`` [T] the positions inside the
     document -> (q [T, H, nope + rope], k [T, H, nope + rope], v [T, H, dv]):
     both rotary parts rotated over all their dimensions, the one rotated
-    ``k_r`` behind every head's un-rotated key."""
+    ``k_r`` behind every head's un-rotated key.  ``theta`` None: nothing is
+    rotated (position-free latent attention; ``pos`` is not read), the
+    "rotary" columns stay plain columns."""
     t, heads, _ = q.shape
-    k_r = dsa.rope(k_r[:, None, :], pos, theta)
-    q = jnp.concatenate([q[..., :nope],
-                         dsa.rope(q[..., nope:], pos, theta)], -1)
+    if theta is None:
+        k_r = k_r[:, None, :]
+    else:
+        k_r = dsa.rope(k_r[:, None, :], pos, theta)
+        q = jnp.concatenate([q[..., :nope],
+                             dsa.rope(q[..., nope:], pos, theta)], -1)
     k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(
         k_r, (t, heads, k_r.shape[-1]))], -1)
     return q, k, kv[..., nope:]

@@ -22,7 +22,8 @@ from flax.training import train_state
 
 import optax
 
-from nerrf_tpu.models.stream import (GQA_KINDS, LATENT_KINDS, WINDOW_KINDS,
+from nerrf_tpu.models.stream import (GQA_KINDS, LATENT_KINDS,
+                                     SIGMOID_ROUTED_KINDS, WINDOW_KINDS,
                                      StreamConfig, StreamNet, mtp_loss,
                                      next_token_loss, stream_loss)
 from nerrf_tpu.tracing import DEFAULT_TRACER
@@ -76,7 +77,9 @@ def count_sparse(aux: dict, scfg: StreamConfig, steps: int = 1) -> None:
     """The ``aux`` of a step with routed layers -> the program's registry:
     the routing of every such stack, the selection where the stack has an
     indexer, the multi-token-prediction term where it has that module, the
-    pairs its grouped-query layers attended where it has those.  It
+    pairs its grouped-query layers attended where it has those, the tokens
+    its delta-rule layers ran and the document starts their state was
+    zeroed at where it has those.  It
     floats device scalars, so the loop calls it only where it already
     syncs; ``steps`` is how many steps that sync stands for (the counters
     then assume they routed alike)."""
@@ -123,6 +126,16 @@ def count_sparse(aux: dict, scfg: StreamConfig, steps: int = 1) -> None:
                 help="query-key pairs the grouped-query attention layers "
                      "attended (real queries), by kind: inside the window, "
                      "or the whole document")
+    if "delta_tokens" in aux:
+        reg.counter_inc("kda_tokens_total",
+                        float(aux["delta_tokens"]) * steps,
+                        help="real tokens the delta-rule layers ran, summed "
+                             "over those layers")
+        reg.counter_inc("kda_resets_total",
+                        float(aux["delta_resets"]) * steps,
+                        help="document starts at which the delta-rule "
+                             "layers zeroed their state, summed over those "
+                             "layers")
 
 
 def make_stream_tx(cfg: loop.TrainConfig, scfg: StreamConfig):
@@ -131,7 +144,8 @@ def make_stream_tx(cfg: loop.TrainConfig, scfg: StreamConfig):
     behind it, weight decay included: the bias is a buffer, held fixed (the
     rule that would move it between steps is not here)."""
     # nerrflint: ok[recompile-hazard] scfg is the model's STATIC configuration (a frozen dataclass that rides the AOT key, `stream_key_extra`), never a traced value
-    if "mla_moe" not in scfg.stack and not scfg.mtp_layers:
+    if (not set(scfg.stack) & set(SIGMOID_ROUTED_KINDS)
+            and not scfg.mtp_layers):
         return loop.make_tx(cfg)
     frozen = lambda tree: jax.tree_util.tree_map_with_path(
         lambda path, _: getattr(path[-1], "key", None) == "router_bias", tree)

@@ -22,6 +22,7 @@ def test_registry_matches_baseline_configs():
         "mcts-lockbit", "multihost-online", "stream-phi4-mini-flash",
         "stream-keye-vl2-30b-a3b",
         "stream-glm-4.7-flash", "stream-laguna-s-2.1",
+        "stream-kimi-linear-48b-a3b",
     }
 
 

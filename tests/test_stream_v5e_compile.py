@@ -257,3 +257,24 @@ def test_grouped_cores_fused_route_compiles_at_the_published_widths(
     assert text.count('custom_call_target="tpu_custom_call"') == 2
     assert "mla_flash_fwd" in text and "mla_flash_bwd" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 3 << 29
+
+
+def test_delta_rule_compiles_at_the_published_widths(one_chip):
+    """`ops/kda.py::chunk_delta` as the ``kda_*`` kinds call it (under
+    `vmap`, batch 1): 8192 tokens, 32 heads with 128 x 128 states, forward +
+    backward.  The chip's compiler accepts the chunk walk; the plan (0.40
+    GB) holds the chunk-start states (268 MB) and one chunk's work at a
+    time, never every step's state (17 GB in float32) nor every chunk's
+    pairwise decays (8.6 GB)."""
+    from nerrf_tpu.ops import kda
+
+    def loss(q, k, v, g, beta, seg):
+        o = jax.vmap(kda.chunk_delta)(q, k, v, g, beta, seg)
+        return jnp.sum(o.astype(jnp.float32) ** 2)
+
+    qk = shape(one_chip, (1, T, 32, 128), jnp.bfloat16)
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        qk, qk, qk, shape(one_chip, (1, T, 32, 128)),
+        shape(one_chip, (1, T, 32)), shape(one_chip, (1, T), jnp.int32)
+    ).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
