@@ -22,7 +22,7 @@ from flax.training import train_state
 
 import optax
 
-from nerrf_tpu.models.stream import (GQA_KINDS, LATENT_KINDS,
+from nerrf_tpu.models.stream import (DELTA_KINDS, GQA_KINDS, LATENT_KINDS,
                                      SIGMOID_ROUTED_KINDS, WINDOW_KINDS,
                                      StreamConfig, StreamNet, mtp_loss,
                                      next_token_loss, stream_loss)
@@ -161,25 +161,30 @@ def stream_kernel_path(scfg: StreamConfig,
     has a ``dsa_moe`` layer, the latent attention core
     (`ops/mla.py::attention_route`) where the stack or its
     multi-token-prediction module has a latent layer, the same core under
-    each grouped-query kind's scope where the stack has that kind, and the
+    each grouped-query kind's scope where the stack has that kind, the
     selective scan (`ops/ssm.py::scan_route`) where it has a ``mamba``
-    layer."""
+    layer, and the delta rule (`ops/kda.py::delta_route`) where it has a
+    ``kda_*`` layer."""
     sparse = "dsa_moe" in scfg.stack
     latent = bool(set(scfg.stack) & set(LATENT_KINDS) or scfg.mtp_layers)
     # whether each grouped-query kind of the stack attends inside a window
     grouped = {k in WINDOW_KINDS for k in scfg.stack if k in GQA_KINDS}
     mamba = "mamba" in scfg.stack
-    if not (sparse or latent or grouped or mamba):
+    delta = bool(set(scfg.stack) & set(DELTA_KINDS))
+    if not (sparse or latent or grouped or mamba or delta):
         return {}
     if seq_len is None:
         raise ValueError("an attention core's or the scan's route depends on "
                          "the sequence length: pass seq_len")
-    from nerrf_tpu.ops import dsa, mla, ssm
+    from nerrf_tpu.ops import dsa, kda, mla, ssm
 
     path = {}
     if mamba:
         path["ssm_scan"] = ssm.scan_route(seq_len, scfg.expand * scfg.dim,
                                           scfg.d_state)
+    if delta:
+        path["kda_core"] = kda.delta_route(seq_len, scfg.head_dim,
+                                           scfg.head_dim)
     if sparse:
         path["dsa_attention"] = dsa.attention_route(
             seq_len, scfg.num_heads, scfg.num_kv_heads, scfg.head_dim)

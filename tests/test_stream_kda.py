@@ -127,18 +127,171 @@ def test_a_remat_that_keeps_the_residuals_walks_the_chunks_once_forward():
 
 
 def test_a_step_reports_the_latent_cores_route_and_keys_the_delta_fields():
-    """The delta rule has one form; the latent core of Kimi-Linear's widths
+    """The step reports both routes of the stack: the delta rule's and the
+    latent core's (here the CPU's); the latent core of Kimi-Linear's widths
     (192-wide keys, 128-wide values) takes the blocked XLA form on a TPU."""
     from nerrf_tpu.ops import mla
     from nerrf_tpu.train.stream import stream_key_extra, stream_kernel_path
 
-    assert stream_kernel_path(TOY, 64) == {"mla_attention": "xla_blocked"}
+    assert stream_kernel_path(TOY, 64) == {"kda_core": "xla_chunked",
+                                           "mla_attention": "xla_blocked"}
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(jax, "default_backend", lambda: "tpu")
         assert mla.attention_route(8192, 192, 128) == "xla_blocked"
     # an edit to a delta-rule field changes the AOT key
     other = dataclasses.replace(TOY, head_dim=4)
     assert stream_key_extra(other, 64) != stream_key_extra(TOY, 64)
+
+
+@pytest.mark.parametrize("backend, t, d_k, d_v, route", [
+    ("tpu", 8192, 128, 128, "pallas"),        # Kimi-Linear's heads
+    ("tpu", 8192 + 5, 128, 128, "pallas"),    # padded to whole chunks
+    ("tpu", 8192, 96, 128, "xla_chunked"),    # keys not whole lanes
+    ("tpu", 8192, 128, 64, "xla_chunked"),    # values not whole lanes
+    ("tpu", 48, 8, 6, "xla_chunked"),         # this file's toy
+    ("cpu", 8192, 128, 128, "xla_chunked"),
+])
+def test_delta_route_is_a_function_of_backend_and_shapes(
+        monkeypatch, backend, t, d_k, d_v, route):
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert kda.delta_route(t, d_k, d_v) == route
+
+
+def test_only_a_delta_stack_reports_the_delta_rules_route(monkeypatch):
+    """`stream_kernel_path` on a TPU for every published stream stack at
+    8192 tokens: Kimi-Linear's reports ``kda_core: pallas`` beside its
+    latent core; the others report what they reported before the fused
+    route existed (their AOT keys do not move)."""
+    from nerrf_tpu.config import EXPERIMENTS
+    from nerrf_tpu.train.stream import stream_kernel_path
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    want = {
+        "stream-phi4-mini-flash": {"ssm_scan": "pallas"},
+        "stream-keye-vl2-30b-a3b": {"dsa_attention": "pallas_flash"},
+        "stream-glm-4.7-flash": {"mla_attention": "pallas_flash"},
+        "stream-laguna-s-2.1": {"gqa_full_attention": "pallas_flash",
+                                "gqa_window_attention": "pallas_flash"},
+        "stream-kimi-linear-48b-a3b": {"kda_core": "pallas",
+                                       "mla_attention": "xla_blocked"},
+    }
+    for name, path in want.items():
+        assert stream_kernel_path(EXPERIMENTS[name].stream, 8192) == path
+    for cfg in (GLM, LAGUNA):
+        assert "kda_core" not in stream_kernel_path(cfg, 64)
+
+
+@pytest.fixture
+def fused_delta(monkeypatch):
+    """What a TPU traces, on the CPU, and run: the kernels in Pallas'
+    interpreter."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pltpu.force_tpu_interpret_mode():
+        yield kda
+
+
+# 150 steps (padded to three kernel chunks of 64): documents start on a
+# chunk's first step (64, 128) and inside a chunk (29, 100, 140)
+WIDE_DOCS = ([1] * 29 + [2] * 35 + [3] * 36 + [4] * 28 + [5] * 12
+             + [6] * 10)
+
+
+def delta_value_and_grads(args, cot):
+    """``o`` and the five gradients of ``sum(o * cot)``, the rule under
+    `vmap` as the ``kda_*`` kinds call it (a batch of one)."""
+    *ins, seg = (x[None] for x in args)
+
+    def loss(*ins):
+        o = jax.vmap(kda.chunk_delta)(*ins, seg)
+        return jnp.sum(o * cot[None]), o[0]
+
+    (_, o), grads = jax.value_and_grad(loss, argnums=range(5),
+                                       has_aux=True)(*ins)
+    return [o] + [x[0] for x in grads]
+
+
+@pytest.mark.parametrize("case", ["documents", "extreme_decays"])
+def test_the_fused_kernels_are_the_chunked_rule(fused_delta, case):
+    """``o`` and the gradients of ``q``, ``k``, ``v``, ``g``, ``beta`` on
+    the fused route (two heads of 128 x 128, three chunks, the last padded)
+    against the XLA route: documents that start on a chunk's first step
+    and inside chunks; and the extreme decays of
+    `test_extreme_decays_stay_finite_and_exact` (-320 a step), at that
+    test's tolerances, every gradient finite."""
+    hard = lambda x: -16.0 * jax.nn.softplus(20.0 + x)
+    t = len(WIDE_DOCS)
+    args = _inputs(7, t, WIDE_DOCS, dk=128, dv=128,
+                   decay=hard if case == "extreme_decays" else None)
+    cot = jnp.asarray(np.random.default_rng(8).standard_normal(
+        (t, 2, 128)).astype(np.float32))
+    assert kda.delta_route(t, 128, 128) == "pallas"
+    with jax.default_matmul_precision("highest"):
+        got = delta_value_and_grads(args, cot)
+        with pytest.MonkeyPatch.context() as m:
+            m.setattr(jax, "default_backend", lambda: "cpu")
+            want = delta_value_and_grads(args, cot)
+    atol = 1e-6 if case == "extreme_decays" else 1e-5
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5, atol=atol)
+    for name, a, b in zip(("q", "k", "v", "g", "beta"), got[1:], want[1:]):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        assert bool(jnp.all(jnp.isfinite(a))), name
+        scale = 1.0 if case == "extreme_decays" else float(
+            jnp.max(jnp.abs(b)))
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5 * scale,
+                                   err_msg=name)
+
+
+def test_a_remat_that_keeps_the_residuals_runs_the_forward_kernel_once(
+        monkeypatch):
+    """The fused route under `jax.checkpoint` with the policy the layer's
+    remat uses: the gradient holds the forward kernel once and the backward
+    kernel once (it reads the kept chunk-start states), and no scan."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    args = _inputs(4, 128, [1] * 128, dk=128, dv=128)
+    policy = jax.checkpoint_policies.save_only_these_names(kda.SAVED)
+    f = jax.checkpoint(lambda *a: kda.chunk_delta(*a, args[-1]),
+                       policy=policy)
+    text = str(jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(f(*a) ** 2),
+                                       argnums=range(5)))(*args[:-1]))
+    # one forward kernel, one backward kernel: no recomputed forward
+    assert text.count("pallas_call[") == 2
+    assert text.count("kda_fwd") == 1 and text.count("kda_bwd") == 1
+    assert "scan[" not in text
+
+
+def test_the_toy_stack_trains_alike_on_both_delta_routes(fused_delta):
+    """A toy ``kda_dense`` + ``kda_moe`` stack with one head of 128 x 128
+    over 128 steps (two kernel chunks, three documents) through the
+    layer's `vmap`: the loss and every parameter's gradient on the fused
+    route are the XLA route's.  Without the layers' remat: the interpreter
+    runs a kernel as a host callback, which remat cannot replay (the test
+    above counts the kernels under remat)."""
+    from nerrf_tpu.models.stream import next_token_loss
+
+    cfg = dataclasses.replace(TOY, num_heads=1, head_dim=128, num_layers=2,
+                              kinds=("kda_dense", "kda_moe"), remat=False)
+    model = StreamNet(cfg)
+    rng = np.random.default_rng(9)
+    tok = jnp.asarray(rng.integers(0, 64, (1, 128)), jnp.int32)
+    seg = jnp.asarray([[1] * 50 + [2] * 40 + [3] * 38], jnp.int32)
+    params = model.init(jax.random.PRNGKey(0), tok, seg)["params"]
+    assert kda.delta_route(128, 128, 128) == "pallas"
+
+    def loss(p):
+        return next_token_loss(
+            cfg, p, model.apply({"params": p}, tok, seg)["hidden"], tok, seg)
+
+    with jax.default_matmul_precision("highest"):
+        got = jax.value_and_grad(loss)(params)
+        with pytest.MonkeyPatch.context() as m:
+            m.setattr(jax, "default_backend", lambda: "cpu")
+            want = jax.value_and_grad(loss)(params)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4,
+                                   atol=1e-6)
 
 
 def test_the_latent_layer_projects_the_query_directly_and_rotates_nothing():

@@ -278,3 +278,31 @@ def test_delta_rule_compiles_at_the_published_widths(one_chip):
         shape(one_chip, (1, T, 32)), shape(one_chip, (1, T), jnp.int32)
     ).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+def test_delta_rule_fused_route_compiles_at_the_published_widths(
+        one_chip, monkeypatch):
+    """The same rule on the route a TPU traces (`kda.delta_route` ->
+    ``pallas``), under `vmap` as the ``kda_*`` kinds call it, forward +
+    backward: the chip's compiler accepts both kernels at the module's
+    chunk and head block, the program holds one custom call a pass, and
+    the plan (the kept chunk-start states, the kernels' outputs) stays
+    under the XLA route's bound."""
+    from nerrf_tpu.ops import kda
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert kda.delta_route(T, 128, 128) == "pallas"
+
+    def loss(q, k, v, g, beta, seg):
+        o = jax.vmap(kda.chunk_delta)(q, k, v, g, beta, seg)
+        return jnp.sum(o.astype(jnp.float32) ** 2)
+
+    qk = shape(one_chip, (1, T, 32, 128), jnp.bfloat16)
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        qk, qk, qk, shape(one_chip, (1, T, 32, 128)),
+        shape(one_chip, (1, T, 32)), shape(one_chip, (1, T), jnp.int32)
+    ).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "kda_fwd" in text and "kda_bwd" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
